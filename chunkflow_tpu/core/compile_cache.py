@@ -3,11 +3,12 @@
 Two tiers, attacking two different retrace costs:
 
 1. **Persistent compilation cache** (:func:`enable_persistent_cache`):
-   points ``jax.config`` at an on-disk cache directory so a process
-   restart (or the driver's bench invocation after tools/tpu_validation.py
-   warmed the cache) skips the multi-minute UNet compile. Directory comes
-   from ``CHUNKFLOW_JAX_CACHE`` (``0``/``off`` disables); default
-   ``~/.cache/chunkflow_tpu/jax_cache``. Entries below
+   jax's on-disk cache, so a process restart skips the UNet compile.
+   Where it lives is decided outside the program: with
+   ``JAX_COMPILATION_CACHE_DIR`` set, jax reads the variable itself and
+   this module sets no directory at all; without it the cache is
+   ``<checkout>/.jax_cache`` — one fixed path, because the directory is
+   part of what makes an entry hit. Entries below
    ``min_compile_time_secs`` are not persisted, so CPU test-suite
    micro-programs never churn the disk.
 
@@ -54,53 +55,43 @@ class RetraceWarning(UserWarning):
 
 
 def persistent_cache_dir() -> Optional[str]:
-    """The on-disk XLA cache directory in effect, or None when the
-    persistent cache is disabled/unavailable (CLI end-of-run summary)."""
+    """The on-disk XLA cache directory in effect, or None before the
+    first :func:`enable_persistent_cache` (CLI end-of-run summary)."""
     return _PERSISTENT_DIR
 
 
-def default_cache_dir() -> str:
-    return os.path.join(
-        os.path.expanduser("~"), ".cache", "chunkflow_tpu", "jax_cache"
-    )
+#: ``<checkout>/.jax_cache`` (gitignored): the cache's place when
+#: ``JAX_COMPILATION_CACHE_DIR`` does not give one
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
 
-def enable_persistent_cache(cache_dir: Optional[str] = None) -> Optional[str]:
+def enable_persistent_cache() -> Optional[str]:
     """Enable jax's on-disk compilation cache; returns the directory in
-    effect, or None when disabled/unavailable.
+    effect. Idempotent. A failure to enable raises: a worker that
+    recompiles the UNet on every start must not look healthy.
 
-    Idempotent and never raises: the cache is an optimization, not a
-    dependency. Precedence: explicit ``cache_dir`` argument, then
-    ``CHUNKFLOW_JAX_CACHE`` (``0``/``off``/``false`` disables), then
-    :func:`default_cache_dir`.
+    With ``JAX_COMPILATION_CACHE_DIR`` in the environment the directory
+    is whatever jax made of that variable — no directory is set here.
+    Otherwise it is :data:`CHECKOUT_CACHE_DIR`.
     """
     global _PERSISTENT_DIR
-    env = os.environ.get("CHUNKFLOW_JAX_CACHE", "")
-    if cache_dir is None:
-        if env.lower() in ("0", "off", "false"):
-            return None
-        cache_dir = env or default_cache_dir()
+    import jax
+
     with _LOCK:
-        if _PERSISTENT_DIR == cache_dir:
+        if _PERSISTENT_DIR is not None:
             return _PERSISTENT_DIR
-        try:
-            import jax
-
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            # persist everything that took real compile time; tiny CPU
-            # test programs stay in-memory only
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", -1
-            )
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 1.0
-            )
-            _PERSISTENT_DIR = cache_dir
-        except Exception as e:
-            import sys
-
-            print(f"compilation cache unavailable: {e}", file=sys.stderr)
-            return None
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update("jax_compilation_cache_dir",
+                              CHECKOUT_CACHE_DIR)
+        # persist everything that took real compile time; tiny CPU
+        # test programs stay in-memory only
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+        _PERSISTENT_DIR = jax.config.jax_compilation_cache_dir
     return _PERSISTENT_DIR
 
 

@@ -20,16 +20,15 @@ no ``/profile`` route — nothing):
 
 2. **Roofline accounting.** At catalog time each program's cost is
    scored against a small peak-FLOPs/HBM-bandwidth table keyed on
-   ``jax.devices()[0].device_kind`` (env-overridable via
-   ``CHUNKFLOW_PEAK_FLOPS`` / ``CHUNKFLOW_PEAK_BW``; a conservative CPU
-   fallback keeps the math defined on the test mesh):
+   ``jax.devices()[0].device_kind`` (a kind with no row is an error;
+   the labelled ``cpu`` row keeps the math defined on the test mesh):
    ``roofline_s = max(flops/peak_flops, bytes/peak_bw)`` and
    ``roofline_util = roofline_s / exec_s``. ``exec_s`` is the mean
    post-compile *dispatch wall* — under async dispatch that is a lower
    bound on device time, so the utilisation figure is an upper bound;
    it answers "which program family is worth a kernel" (the Pallas
    blend / multi-chip question), not "publishable MXU utilisation"
-   (that stays tools/tpu_validation.py's ``profile_flagship``).
+   (not measured).
 
 3. **Bounded profiler capture.** The whole-run ``--profile-dir`` trace
    is replaced by a task window (:func:`start_task_window`: first N
@@ -96,10 +95,9 @@ def _env_int(name: str, default: int) -> int:
 # ---------------------------------------------------------------------------
 #: (device_kind substring, (peak FLOP/s, peak HBM bytes/s)) — matched
 #: case-insensitively, first hit wins, most specific first. Values are
-#: published bf16 peaks per chip (the inference dtype of record); the
-#: ``cpu`` row is a deliberately conservative host fallback so the
-#: roofline math stays defined on the CI mesh (override with
-#: CHUNKFLOW_PEAK_FLOPS / CHUNKFLOW_PEAK_BW for a calibrated host).
+#: published bf16 peaks per chip (the inference dtype of record). The
+#: ``cpu`` row is a labelled stand-in, not a measurement: it keeps the
+#: roofline arithmetic defined for the CPU test suite.
 DEVICE_PEAKS = (
     ("tpu v6", (918e12, 1640e9)),   # Trillium
     ("tpu v5p", (459e12, 2765e9)),
@@ -110,28 +108,21 @@ DEVICE_PEAKS = (
     ("cpu", (1e11, 5e10)),
 )
 
-_CPU_FALLBACK = (1e11, 5e10)
-
 
 def device_peaks(device_kind: str) -> dict:
-    """Peak FLOP/s + bytes/s for a device kind: env overrides first
-    (``CHUNKFLOW_PEAK_FLOPS`` / ``CHUNKFLOW_PEAK_BW``), then the
-    substring table, then the CPU fallback. ``source`` says which."""
-    env_flops = _env_float("CHUNKFLOW_PEAK_FLOPS", 0.0)
-    env_bw = _env_float("CHUNKFLOW_PEAK_BW", 0.0)
+    """Peak FLOP/s + bytes/s for a device kind from :data:`DEVICE_PEAKS`.
+    A kind with no row raises: a roofline scored against another
+    device's peaks is a wrong number, not an estimate."""
     kind = (device_kind or "").lower()
-    flops, bw, source = None, None, "fallback"
-    for needle, (f, b) in DEVICE_PEAKS:
+    for needle, (flops, bw) in DEVICE_PEAKS:
         if needle in kind:
-            flops, bw, source = f, b, f"table:{needle}"
-            break
-    if flops is None:
-        flops, bw = _CPU_FALLBACK
-    if env_flops > 0:
-        flops, source = env_flops, "env"
-    if env_bw > 0:
-        bw, source = env_bw, "env"
-    return {"flops_per_s": flops, "bytes_per_s": bw, "source": source}
+            return {"flops_per_s": flops, "bytes_per_s": bw,
+                    "source": f"table:{needle}"}
+    raise KeyError(
+        f"device_kind {device_kind!r} has no row in "
+        f"chunkflow_tpu.core.profiling.DEVICE_PEAKS; add its published "
+        f"peaks there"
+    )
 
 
 def estimate_collective_split(flops: float, collective_bytes: float,
@@ -196,13 +187,10 @@ _LEDGER: dict = {}  # (family, key) -> _ProgramRecord
 
 
 def _device_identity() -> Tuple[str, str]:
-    try:
-        import jax
+    import jax
 
-        dev = jax.devices()[0]
-        return dev.platform, dev.device_kind
-    except Exception:
-        return "unknown", "unknown"
+    dev = jax.devices()[0]
+    return dev.platform, dev.device_kind
 
 
 def _cost_analysis(program, args, kwargs) -> dict:
@@ -213,8 +201,6 @@ def _cost_analysis(program, args, kwargs) -> dict:
         cost = program.lower(*args, **kwargs).cost_analysis()
     except Exception:
         return {}
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0] if cost else {}
     return cost if isinstance(cost, dict) else {}
 
 
@@ -515,12 +501,14 @@ def catalog() -> list:
             calls, dispatch_s = rec.calls, rec.dispatch_s
             flops, nbytes = rec.flops, rec.bytes_accessed
             kind = rec.device_kind
-        peaks = device_peaks(kind)
+        # a program that never ran has met no device: no peaks, no roofline
+        peaks = device_peaks(kind) if kind else dict.fromkeys(
+            ("flops_per_s", "bytes_per_s", "source"))
         entry["peak_flops_per_s"] = peaks["flops_per_s"]
         entry["peak_bytes_per_s"] = peaks["bytes_per_s"]
         entry["peak_source"] = peaks["source"]
         roofline_s = None
-        if flops is not None or nbytes is not None:
+        if kind and (flops is not None or nbytes is not None):
             roofline_s = max(
                 (flops or 0.0) / peaks["flops_per_s"],
                 (nbytes or 0.0) / peaks["bytes_per_s"],
@@ -681,9 +669,9 @@ def capture(seconds: float, reason: str, force: bool = False,
 
     ``force=True`` (operator request, the ``/profile`` route) bypasses
     the automatic-capture cooldown but never the one-session-at-a-time
-    exclusion. ``background=True`` runs the window in a daemon thread
+    exclusion. ``background=True`` runs the window in its own thread
     (anomaly triggers must not stall the pipeline for the window's
-    duration). Disabled telemetry or no capture dir ⇒ ``(None, why)``.
+    duration; process exit waits for it). Disabled telemetry or no capture dir ⇒ ``(None, why)``.
     """
     global _TRACE_ACTIVE, _LAST_CAPTURE_T, _CAPTURE_SEQ
     if not telemetry.enabled():
@@ -707,9 +695,13 @@ def capture(seconds: float, reason: str, force: bool = False,
         seq = _CAPTURE_SEQ
     target = os.path.join(base, f"profile-{_safe_name(reason)}-{seq}")
     if background:
+        # NOT a daemon: the window is bounded, and a process that exits
+        # with the profiler session open aborts in interpreter shutdown
+        # ("FATAL: exception not rethrown", exit 134, on a v5e) — so
+        # exit waits for the window to close
         thread = threading.Thread(
             target=_run_capture, args=(target, seconds, reason),
-            name=f"chunkflow-profile-{seq}", daemon=True,
+            name=f"chunkflow-profile-{seq}",
         )
         _CAPTURE_THREADS.append(thread)
         thread.start()

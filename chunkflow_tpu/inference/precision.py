@@ -374,10 +374,7 @@ def _int8_graph_apply(apply: Callable, params, batch, integer: bool):
     ``preferred_element_type=int32``."""
     import jax
 
-    try:
-        from jax.extend.core import Literal
-    except ImportError:  # older jax layouts
-        from jax.core import Literal
+    from jax.extend.core import Literal
 
     closed, out_shape = jax.make_jaxpr(apply, return_shape=True)(
         params, batch)

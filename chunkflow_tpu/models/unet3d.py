@@ -87,17 +87,16 @@ class MxuConv(nn.Module):
     """Drop-in for ``nn.Conv(features, kernel_size, padding='SAME')`` with
     an identical parameter tree, lowered as z-decomposed 2D convolutions.
 
-    XLA's native Conv3D lowering on TPU underuses the MXU (~3-4% of bf16
-    peak, an arithmetic bound from the measured 28.5 Mvoxel/s raw forward
-    in tools/tpu_validation_oldblend.json `fwd_tpu_bf16` vs the 197
-    TFLOP/s v5e peak); a (kz, ky, kx) conv is mathematically the sum of
+    XLA's native Conv3D lowering on TPU is expected to underuse the MXU
+    at these channel counts (device number: not measured); a
+    (kz, ky, kx) conv is mathematically the sum of
     kz z-shifted (ky, kx) 2D convs, and 2D convs with depth merged into
     batch hit the battle-tested conv2d path. Same FLOPs, same parameters
     (kernel [kz,ky,kx,Cin,F] + bias); partials are accumulated in float32
     (preferred_element_type) and rounded to the compute dtype once, so
     bf16 numerics track native Conv3D's single-rounding accumulation —
-    asserted by tests/inference/test_mxu_conv.py; A/B'd on chip by
-    fwd_tpu_mxu."""
+    asserted by tests/inference/test_mxu_conv.py; on-chip A/B: not
+    measured."""
 
     features: int
     kernel_size: Triple
@@ -291,8 +290,8 @@ def create_tpu_optimized_model(
 
     ``conv_impl='mxu'`` additionally lowers every conv as z-decomposed 2D
     convs / GEMM upsampling (MxuConv / MxuConvTranspose) — identical
-    parameters and numerics, different XLA lowering; selected per the
-    measured-winner rule once the fwd_tpu_mxu battery step has a number.
+    parameters and numerics, different XLA lowering; on-chip A/B: not
+    measured.
     """
     scale = int(round(float(np.prod(s2d_factor)) ** 0.5))
     return UNet3D(
@@ -309,7 +308,11 @@ def create_tpu_optimized_model(
 def init_params(model: nn.Module, input_patch_size, num_input_channels: int,
                 seed: int = 0):
     shape = (1,) + tuple(input_patch_size) + (num_input_channels,)
-    variables = model.init(jax.random.PRNGKey(seed), jnp.zeros(shape, jnp.float32))
+    # jitted: the initializers need shapes only, so XLA drops the forward
+    # pass an eager init would run op by op (77 s for the RSUNet at
+    # 20x256x256 on a v5e, my chip run, PR 21)
+    variables = jax.jit(model.init)(
+        jax.random.PRNGKey(seed), jnp.zeros(shape, jnp.float32))
     return variables["params"]
 
 

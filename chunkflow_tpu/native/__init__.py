@@ -1,14 +1,19 @@
 """Native C++ kernels (cc3d / waterz / zmesh equivalents) via ctypes.
 
-The shared library builds on first import with g++ -O3 and is cached next
-to the sources; set CHUNKFLOW_NATIVE_REBUILD=1 to force a rebuild. All
-entry points are plain C ABI over numpy buffers — no pybind11 dependency
-(not in this image).
+The shared library builds on first use with g++ -O3 into ``lib/`` under a
+name keyed on the source bytes, the compiler flags and — because of
+``-march=native`` — the host CPU, so a library built from other sources
+or on another machine (a copied checkout keeps no mtimes) is never
+loaded. All entry points are plain C ABI over numpy buffers — no pybind11
+dependency (not in this image).
 """
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
+import platform
 import subprocess
 from typing import Optional, Tuple
 
@@ -16,44 +21,74 @@ import numpy as np
 
 _SRC_DIR = os.path.join(os.path.dirname(__file__), "src")
 _LIB_DIR = os.path.join(os.path.dirname(__file__), "lib")
-_LIB_PATH = os.path.join(_LIB_DIR, "libchunkflow_native.so")
 _SOURCES = ("cc3d.cpp", "watershed.cpp", "surface_nets.cpp", "remap.cpp")
 _HEADERS = ("zslab.h",)
+_FLAGS = ("-O3", "-std=c++17", "-shared", "-fPIC", "-march=native",
+          "-pthread")
 
 _lib: Optional[ctypes.CDLL] = None
 
 
-def _needs_build() -> bool:
-    if os.environ.get("CHUNKFLOW_NATIVE_REBUILD"):
-        return True
-    if not os.path.exists(_LIB_PATH):
-        return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    return any(
-        os.path.getmtime(os.path.join(_SRC_DIR, s)) > lib_mtime
-        for s in _SOURCES + _HEADERS
-    )
+def _host_cpu() -> str:
+    """What ``-march=native`` resolves against: the CPU's model and
+    feature flags (Linux), else the platform's own description."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            lines = f.read().splitlines()
+    except OSError:
+        return f"{platform.machine()} {platform.processor()}"
+    picked = {}
+    for line in lines:
+        name = line.split(":", 1)[0].strip()
+        if name in ("model name", "flags", "Features"):
+            picked.setdefault(name, line)
+    return "\n".join(picked.values())
+
+
+def lib_path() -> str:
+    """Where the library for THESE sources, flags and host CPU lives."""
+    digest = hashlib.sha256()
+    for name in _SOURCES + _HEADERS:
+        with open(os.path.join(_SRC_DIR, name), "rb") as f:
+            digest.update(f.read())
+    digest.update(" ".join(_FLAGS).encode())
+    digest.update(_host_cpu().encode())
+    return os.path.join(
+        _LIB_DIR, f"libchunkflow_native-{digest.hexdigest()[:16]}.so")
 
 
 def build() -> str:
+    """Compile the library from source; returns its path."""
+    path = lib_path()
     os.makedirs(_LIB_DIR, exist_ok=True)
+    # compile beside the target and rename: two workers starting at
+    # once must never load a half-written file
+    tmp = f"{path}.{os.getpid()}.tmp"
     cmd = [
-        "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-march=native",
-        "-pthread",
+        "g++", *_FLAGS,
         *(os.path.join(_SRC_DIR, s) for s in _SOURCES),
-        "-o", _LIB_PATH,
+        "-o", tmp,
     ]
-    subprocess.run(cmd, check=True, capture_output=True, text=True)
-    return _LIB_PATH
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, text=True)
+        os.replace(tmp, path)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+    for stale in glob.glob(os.path.join(_LIB_DIR, "libchunkflow_native*.so")):
+        if stale != path:
+            os.remove(stale)
+    return path
 
 
 def load() -> ctypes.CDLL:
     global _lib
     if _lib is not None:
         return _lib
-    if _needs_build():
+    path = lib_path()
+    if not os.path.exists(path):
         build()
-    lib = ctypes.CDLL(_LIB_PATH)
+    lib = ctypes.CDLL(path)
 
     i64 = ctypes.c_int64
     lib.cc3d_label_u8.restype = ctypes.c_uint32
@@ -309,8 +344,5 @@ def available() -> bool:
     try:
         load()
         return True
-    except (subprocess.CalledProcessError, OSError, AttributeError):
-        # AttributeError: a stale cached .so missing newly added symbols
-        # (e.g. left behind across a package upgrade) must degrade to the
-        # numpy fallbacks, not break every native entry point
+    except (subprocess.CalledProcessError, OSError):
         return False

@@ -67,9 +67,9 @@ def fused_pipeline_mode() -> str:
     between stages. ``on`` compiles both Mosaic kernels (hardware);
     ``interpret`` runs them under the Pallas interpreter (+kernelcheck)
     on CPU — it IS the parity leg, not a throughput proxy. Default OFF
-    per the measured-winner rule (docs/performance.md): the pending
-    on-chip row is ``tools/tpu_validation.py bench_fused_pipeline``;
-    the CPU structure gate is ``bench.py fused_pipeline``.
+    per the measured-winner rule (docs/performance.md): its on-chip
+    speed against the separate programs is not measured; the CPU
+    structure gate is ``bench.py fused_pipeline``.
 
     Resolution shares :func:`core.envmode.resolve` (warn-once; a typo
     must not force-select Mosaic kernels on a CPU box)."""

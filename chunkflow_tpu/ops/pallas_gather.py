@@ -43,9 +43,10 @@ CHUNKFLOW_MESH convention):
                        identical to the host front, strictly less H2D)
     off/host           the pre-ISSUE-15 host front half, bit-identically
                        (the kill switch; serving gathers on the host)
-    pallas             the compiled Mosaic gather kernel (opt-in until
-                       tools/tpu_validation.py bench_front_half banks an
-                       on-chip win — the measured-winner rule)
+    pallas             the compiled Mosaic gather kernel: compiles and
+                       matches the XLA leg bitwise on a v5e for float32
+                       and uint8 chunks (chip_smoke.py); opt-in, its
+                       speed against the XLA leg is not measured
     interpret          the kernel in interpret mode (CPU tests)
 
 Unrecognized values warn ONCE on stderr and resolve to the default
@@ -269,6 +270,8 @@ def gather_patches(chunk, in_starts, input_patch_size: Triple,
     sub = _sublane(dtype)
     wy, wx = gather_window(py, px, dtype)
     scale = _int_scale(dtype)
+    narrow_unsigned = (np.dtype(dtype).kind == "u"
+                       and np.dtype(dtype).itemsize < 4)
 
     z0 = in_starts[:, 0]
     y0a = (in_starts[:, 1] // sub) * sub
@@ -295,14 +298,24 @@ def gather_patches(chunk, in_starts, input_patch_size: Triple,
         load = pltpu.make_async_copy(window, scratch, sem)
         load.start()
         load.wait()
-        tile = scratch[pl.ds(dy, py), pl.ds(dx, px)]
+        win = scratch[...]
+        if narrow_unsigned:
+            # Mosaic lowers no unsigned -> float cast ("Unsupported cast:
+            # uint8 -> float32", jax 0.9.0); zero-extending to int32
+            # first is exact
+            win = win.astype(jnp.int32)
         # the same IEEE expression convert_chunk applies chunk-wide:
         # exact int->f32, then one f32 multiply — bitwise equal to
         # convert-then-slice on the XLA leg
+        win = win.astype(jnp.float32)
+        # Mosaic has no vector load at a dynamic unaligned (dy, dx)
+        # ("cannot statically prove that index in dimension 0 is a
+        # multiple of 8", v5e), so the window is rotated until the
+        # patch sits at its origin and sliced statically
+        win = pltpu.roll(pltpu.roll(win, wy - dy, 0), wx - dx, 1)
+        tile = win[:py, :px]
         if scale is not None:
-            tile = tile.astype(jnp.float32) * scale
-        elif tile.dtype != jnp.float32:
-            tile = tile.astype(jnp.float32)
+            tile = tile * scale
         out_ref[0, 0, 0] = tile
 
     grid_spec = pltpu.PrefetchScalarGridSpec(

@@ -55,7 +55,7 @@ def build_sharded_program(
     """
     import jax
     from jax import lax
-    from chunkflow_tpu.parallel._shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from chunkflow_tpu.ops.blend import build_local_blend, normalize_blend
@@ -82,7 +82,7 @@ def build_sharded_program(
         mesh=mesh,
         in_specs=(P(), P("data"), P("data"), P("data"), P()),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
 
     # chunk is donated (GL005): dead after the call, may be aliased into

@@ -4,7 +4,7 @@ family for streaming AND serving across a pod slice.
 This module subsumes the four divergent parallel variants that grew up
 around the fused inference program — ``distributed.py`` (patch-parallel
 psum), ``spatial.py`` (1D y-slab ring), ``spatial2d.py`` (2D mesh with
-two-phase halo/spill), and the ``_shard_map.py`` shim's call sites — into
+two-phase halo/spill) — into
 a single :class:`ShardedEngine` driven by a mesh spec:
 
     CHUNKFLOW_MESH=1           kill switch: the single-device reference
@@ -878,7 +878,7 @@ class ShardedEngine:
         from jax import lax
         from jax.sharding import PartitionSpec as P
 
-        from chunkflow_tpu.parallel._shard_map import shard_map
+        from jax import shard_map
 
         mesh = self.mesh()
         n_dev = mesh.devices.size
@@ -939,7 +939,7 @@ class ShardedEngine:
             mesh=mesh,
             in_specs=in_specs,
             out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
 
         # chunk is donated (GL005): dead after the call, may be aliased
@@ -973,7 +973,7 @@ class ShardedEngine:
         from jax import lax
         from jax.sharding import PartitionSpec as P
 
-        from chunkflow_tpu.parallel._shard_map import shard_map
+        from jax import shard_map
 
         mesh = self.mesh()
         ny, nx = self.spec.shape
@@ -1099,7 +1099,7 @@ class ShardedEngine:
             mesh=mesh,
             in_specs=in_specs,
             out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
 
         # chunk is donated (GL005): dead after the call, may be aliased
@@ -1134,7 +1134,7 @@ class ShardedEngine:
         from jax.sharding import PartitionSpec as P
 
         from chunkflow_tpu.parallel import pipeline as pipe_mod
-        from chunkflow_tpu.parallel._shard_map import shard_map
+        from jax import shard_map
 
         pipe_mod.require_stages(self.stage_bodies, self.stage_tail,
                                 "CHUNKFLOW_MESH=" + self.spec.describe())
@@ -1232,7 +1232,7 @@ class ShardedEngine:
             mesh=mesh,
             in_specs=in_specs,
             out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
 
         # chunk is donated (GL005): dead after the call, may be aliased
@@ -1260,7 +1260,7 @@ class ShardedEngine:
         import jax
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from chunkflow_tpu.parallel._shard_map import shard_map
+        from jax import shard_map
 
         n_chips = self.spec.n_devices
         forward = self.forward
@@ -1290,7 +1290,7 @@ class ShardedEngine:
                 mesh=mesh,
                 in_specs=(P("data"), P("data"), P()),
                 out_specs=P("data"),
-                check_rep=False,
+                check_vma=False,
             )
 
             # the packed batch buffer is packer-owned and dead after the
@@ -1363,7 +1363,7 @@ class ShardedEngine:
                 mesh=mesh,
                 in_specs=(P(), P(), P()),
                 out_specs=P(),
-                check_rep=False,
+                check_vma=False,
             )
 
             # no donation here: the replicated input cannot alias the

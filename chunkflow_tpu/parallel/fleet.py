@@ -55,11 +55,20 @@ those signals:
   and ``CHUNKFLOW_FLEET=0`` is the kill switch: a static-size fleet
   that bypasses the controller entirely while keeping
   replace-the-dead liveness.
+* **One process per chip**: a TPU chip belongs to one process, and a
+  worker is given no chip of its own — the first worker to start takes
+  every chip of the host, and each later one dies at backend start-up
+  ("The TPU is already in use by process with pid N", measured on a
+  v5e with jax 0.9.0 / libtpu 0.0.34). So the supervisor refuses to
+  run more than one chip-using worker on a TPU host
+  (:func:`host_tpu_chips`); one worker drives all of a host's chips
+  through ``inference --mesh data=N``.
 
 See docs/fault_tolerance.md "Running a fleet" for the runbook.
 """
 from __future__ import annotations
 
+import glob
 import json
 import os
 import random
@@ -77,7 +86,8 @@ from chunkflow_tpu.parallel.restapi import scrape_worker
 
 __all__ = [
     "WorkerHandle", "FleetSupervisor", "fleet_disabled",
-    "host_available_gb", "COMPUTE_BOUND_PHASES", "STORAGE_BOUND_PHASES",
+    "host_available_gb", "host_tpu_chips", "COMPUTE_BOUND_PHASES",
+    "STORAGE_BOUND_PHASES",
 ]
 
 _OFF_VALUES = ("0", "off", "false", "no")
@@ -209,6 +219,15 @@ class WorkerHandle:
         }
 
 
+def host_tpu_chips() -> List[str]:
+    """Device nodes of the TPU chips a process on this host could take:
+    libtpu opens ``/dev/accel<n>`` up to v4 and ``/dev/vfio/<n>`` from
+    v5 on. Read without jax — a supervisor that touched the backend
+    would hold the chips its workers need."""
+    return sorted(glob.glob("/dev/accel[0-9]*")
+                  + glob.glob("/dev/vfio/[0-9]*"))
+
+
 class FleetSupervisor:
     """Spawn, monitor, scale and evict a fleet of queue-fed workers.
 
@@ -295,6 +314,21 @@ class FleetSupervisor:
         self.host = host
         self.python = python or sys.executable
         self.worker_env = dict(worker_env or {})
+        platforms = self.worker_env.get(
+            "JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", ""))
+        chips = host_tpu_chips()
+        if (max_workers > 1 and chips
+                and (not platforms or "tpu" in platforms.split(","))):
+            raise ValueError(
+                f"fleet: this host has TPU chip(s) {chips} and workers "
+                f"would run jax on them (JAX_PLATFORMS={platforms!r}). "
+                f"A chip belongs to one process: the first worker takes "
+                f"every chip and each further one dies at start-up with "
+                f"'The TPU is already in use'. Run one worker "
+                f"(--max-workers 1) and give it the host's chips with "
+                f"inference --mesh data=N, or set JAX_PLATFORMS=cpu for "
+                f"workers that need no chip"
+            )
         self.static = fleet_disabled() if static is None else bool(static)
         self.launcher = launcher or self._spawn_process
         self.scraper = scraper or scrape_worker

@@ -58,7 +58,7 @@ def test_program_cache_build_records_cost_ledger_entry(clean_plane,
     # roofline derivation against the peak table (CPU fallback row)
     assert entry["roofline_s"] > 0
     assert entry["roofline_util"] is not None
-    assert entry["peak_source"].startswith(("table:", "env"))
+    assert entry["peak_source"] == "table:cpu"
 
     counters = telemetry.snapshot()["counters"]
     assert counters["program/builds"] == 1
@@ -118,19 +118,29 @@ def test_instrumented_program_forwards_attributes(clean_plane):
     assert entry["key"] == "(8, 16, 16)"
 
 
-def test_device_peaks_env_override_and_table(monkeypatch):
-    monkeypatch.delenv("CHUNKFLOW_PEAK_FLOPS", raising=False)
-    monkeypatch.delenv("CHUNKFLOW_PEAK_BW", raising=False)
+@pytest.mark.parametrize("kind, row", [
+    ("TPU v5 lite", "tpu v5 lite"),   # what a v5e reports (chip run, PR 21)
+    ("TPU v5e", "tpu v5e"),
+    ("TPU v4", "tpu v4"),
+    ("cpu", "cpu"),
+])
+def test_device_peaks_come_from_the_table(kind, row):
+    flops, bw = dict(profiling.DEVICE_PEAKS)[row]
+    assert profiling.device_peaks(kind) == {
+        "flops_per_s": flops, "bytes_per_s": bw, "source": f"table:{row}"}
+
+
+def test_device_peaks_v5e_values():
     v5e = profiling.device_peaks("TPU v5 lite")
     assert v5e["flops_per_s"] == 197e12 and v5e["bytes_per_s"] == 819e9
-    assert v5e["source"] == "table:tpu v5 lite"
-    assert profiling.device_peaks("weird accelerator")["source"] \
-        == "fallback"
-    monkeypatch.setenv("CHUNKFLOW_PEAK_FLOPS", "1e12")
-    monkeypatch.setenv("CHUNKFLOW_PEAK_BW", "2e11")
-    got = profiling.device_peaks("TPU v5 lite")
-    assert got == {"flops_per_s": 1e12, "bytes_per_s": 2e11,
-                   "source": "env"}
+
+
+@pytest.mark.parametrize("kind", ["weird accelerator", "", None])
+def test_unknown_device_kind_raises_naming_the_kind(kind):
+    """A device that is not in the table is an error, not the CPU's
+    made-up row."""
+    with pytest.raises(KeyError, match=repr(kind).replace("'", ".")):
+        profiling.device_peaks(kind)
 
 
 # ---------------------------------------------------------------------------
@@ -416,3 +426,24 @@ def test_task_window_stops_after_n_tasks(clean_plane, tmp_path):
     assert profiling._TRACE_ACTIVE is False
     profiling.note_task_done()  # past-budget tasks are a no-op
     window.close()  # idempotent
+
+
+def test_background_capture_thread_is_joined_at_exit(clean_plane, tmp_path,
+                                                     monkeypatch):
+    """A capture must not be a daemon thread: a process that exits with
+    the profiler session still open aborted in interpreter shutdown on
+    the chip (exit 134 after all work was done, PR 21). Non-daemon
+    threads are joined before shutdown begins."""
+    import threading
+
+    telemetry.configure(str(tmp_path))
+    started = []
+    monkeypatch.setattr(
+        profiling, "_run_capture",
+        lambda target, seconds, reason: started.append(
+            threading.current_thread().daemon) or profiling._release_trace())
+    target, err = profiling.capture(0.05, "exit-test", force=True,
+                                    background=True)
+    assert err is None and target
+    profiling.wait_for_captures(10)
+    assert started == [False]

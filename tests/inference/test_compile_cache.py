@@ -6,10 +6,7 @@ import numpy as np
 import pytest
 
 from chunkflow_tpu.chunk.base import Chunk
-from chunkflow_tpu.core.compile_cache import (
-    ProgramCache,
-    enable_persistent_cache,
-)
+from chunkflow_tpu.core.compile_cache import ProgramCache
 from chunkflow_tpu.inference import Inferencer
 from chunkflow_tpu.inference.engines import Engine, create_identity_engine
 
@@ -144,12 +141,74 @@ def test_fold_family_shares_program_cache():
     assert inferencer._programs.hits == 2
 
 
-def test_persistent_cache_enable_idempotent(tmp_path, monkeypatch):
-    target = str(tmp_path / "xla_cache")
-    assert enable_persistent_cache(target) == target
-    assert enable_persistent_cache(target) == target  # idempotent
+# ---------------------------------------------------------------------------
+# the persistent cache is placed from outside (ISSUE 21)
+# ---------------------------------------------------------------------------
+@pytest.fixture()
+def fresh_cache_state(monkeypatch):
+    """enable_persistent_cache() as a new process would see it, with
+    jax's cache directory restored afterwards."""
     import jax
 
-    assert jax.config.jax_compilation_cache_dir == target
-    monkeypatch.setenv("CHUNKFLOW_JAX_CACHE", "0")
-    assert enable_persistent_cache() is None  # env kill switch
+    from chunkflow_tpu.core import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    restore = jax.config.update  # tests below replace it
+    monkeypatch.setattr(compile_cache, "_PERSISTENT_DIR", None)
+    yield compile_cache
+    restore("jax_compilation_cache_dir", before)
+
+
+def test_cache_dir_from_environment_is_not_set_in_code(
+        fresh_cache_state, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set (tests/conftest.py sets it before jax
+    loads): the program sets no cache directory at all and reports the
+    one jax made of the variable."""
+    import os
+
+    import jax
+
+    placed = os.environ["JAX_COMPILATION_CACHE_DIR"]
+    updated = []
+    real_update = jax.config.update
+
+    def recording_update(name, value):
+        updated.append(name)
+        real_update(name, value)
+
+    monkeypatch.setattr(jax.config, "update", recording_update)
+    assert fresh_cache_state.enable_persistent_cache() == placed
+    assert fresh_cache_state.enable_persistent_cache() == placed  # idempotent
+    assert "jax_compilation_cache_dir" not in updated
+    assert jax.config.jax_compilation_cache_dir == placed
+    assert fresh_cache_state.persistent_cache_dir() == placed
+
+
+def test_cache_dir_defaults_to_the_checkout(fresh_cache_state, monkeypatch):
+    """Variable unset: <checkout>/.jax_cache exactly — no $HOME, no temp
+    name, no pid, no time in the path, so a second process finds the
+    first one's entries."""
+    import os
+
+    import jax
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    want = os.path.join(repo, ".jax_cache")
+    assert fresh_cache_state.CHECKOUT_CACHE_DIR == want
+    assert fresh_cache_state.enable_persistent_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+
+
+def test_cache_enable_failure_is_not_swallowed(fresh_cache_state,
+                                               monkeypatch):
+    import jax
+
+    def broken_update(name, value):
+        raise RuntimeError("no cache for you")
+
+    monkeypatch.setattr(jax.config, "update", broken_update)
+    with pytest.raises(RuntimeError, match="no cache for you"):
+        fresh_cache_state.enable_persistent_cache()
+    assert fresh_cache_state.persistent_cache_dir() is None

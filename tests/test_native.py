@@ -681,3 +681,56 @@ class TestQuantileScoring:
         aff = np.ones((3, 2, 4, 4), np.float32)
         with pytest.raises(ValueError, match="scoring"):
             native.watershed_agglomerate(aff, scoring="quantile101")
+
+
+# ---------------------------------------------------------------------------
+# the built library is keyed on what it was built from (ISSUE 21)
+# ---------------------------------------------------------------------------
+def _copy_sources(tmp_path, monkeypatch):
+    import shutil
+
+    src = tmp_path / "src"
+    shutil.copytree(native._SRC_DIR, src)
+    monkeypatch.setattr(native, "_SRC_DIR", str(src))
+    return src
+
+
+def test_library_name_changes_with_the_sources(tmp_path, monkeypatch):
+    src = _copy_sources(tmp_path, monkeypatch)
+    before = native.lib_path()
+    assert before == native.lib_path()  # no mtime, pid or clock in it
+    with open(src / "cc3d.cpp", "a") as f:
+        f.write("\n// another tree's edit\n")
+    assert native.lib_path() != before
+
+
+def test_library_name_changes_with_the_host_cpu(monkeypatch):
+    """-march=native code built on one CPU must not load on another: the
+    chip tool copies the tree, built library included, to a different
+    machine."""
+    here = native.lib_path()
+    monkeypatch.setattr(native, "_host_cpu", lambda: "model name: other")
+    assert native.lib_path() != here
+
+
+def test_foreign_library_is_rebuilt_not_loaded(tmp_path, monkeypatch):
+    """A library left in lib/ by another machine or another tree — under
+    the old fixed name or under another key — is never loaded: load()
+    builds the one keyed on these sources and this CPU and drops the
+    rest."""
+    import os
+
+    lib_dir = tmp_path / "lib"
+    lib_dir.mkdir()
+    foreign = [lib_dir / "libchunkflow_native.so",
+               lib_dir / "libchunkflow_native-0123456789abcdef.so"]
+    for path in foreign:
+        path.write_bytes(b"not an ELF file: built somewhere else")
+    monkeypatch.setattr(native, "_LIB_DIR", str(lib_dir))
+    monkeypatch.setattr(native, "_lib", None)
+    lib = native.load()
+    assert os.listdir(lib_dir) == [os.path.basename(native.lib_path())]
+    out = np.empty((2, 2, 2), np.uint32)
+    ones = np.ones((2, 2, 2), np.uint8)
+    assert lib.cc3d_label_u8(ones.ctypes.data, out.ctypes.data,
+                             2, 2, 2, 26) == 1

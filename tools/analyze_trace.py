@@ -1,12 +1,11 @@
 """Offline op-level analysis of a jax.profiler trace.
 
-The battery's ``profile_flagship`` step writes a perfetto trace under
-``tools/profile_r03/`` on the real chip; tensorboard's profile plugin is
-not installed in this image, so this parser extracts the op-level story
-directly from the ``*.trace.json.gz`` event files: top ops by total
-device time, grouped by XLA op category (convolution / fusion / copy /
-all-reduce / ...), with per-category totals. That attribution is what
-decides the next forward-pass lever (VERDICT r2 item 3).
+tensorboard's profile plugin is not installed in this image, so this
+parser extracts the op-level story directly from the
+``*.trace.json.gz`` event files a ``jax.profiler`` capture writes: top
+ops by total device time, grouped by XLA op category (convolution /
+fusion / copy / all-reduce / ...), with per-category totals. That
+attribution is what decides the next forward-pass lever.
 
 Since PR 8 this is also the summarizer for the device-performance
 plane's bounded captures (core/profiling.py: windowed ``--profile-dir``
@@ -16,7 +15,7 @@ empty or missing trace dir is a warning, not a crash — ``log-summary``
 calls through here for every ``profile-*`` dir it finds under a
 metrics dir.
 
-Usage: python tools/analyze_trace.py [trace_dir] [--top N] [--json]
+Usage: python tools/analyze_trace.py trace_dir [--top N] [--json]
 """
 from __future__ import annotations
 
@@ -146,10 +145,7 @@ def print_summary(summary: dict) -> None:
 
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser()
-    parser.add_argument(
-        "trace_dir", nargs="?",
-        default=os.path.join(os.path.dirname(__file__), "profile_r03"),
-    )
+    parser.add_argument("trace_dir")
     parser.add_argument("--top", type=int, default=25)
     parser.add_argument(
         "--json", action="store_true",
