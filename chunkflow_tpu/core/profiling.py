@@ -16,7 +16,11 @@ no ``/profile`` route — nothing):
    best-effort *without compiling twice* (``Lowered.cost_analysis``
    runs on the unoptimized HLO). Results land in ``program/*``
    counters, one ``compile``-kind telemetry event per program, and a
-   per-run ``programs.json`` catalog written at flush time.
+   per-run ``programs.json`` catalog written at flush time. With a
+   sink configured the entry also carries ``op_scopes``: which of the
+   program's compiled ops lie under which :data:`DEVICE_SCOPES` name,
+   so a device trace (whose events keep only the op's name) can be
+   split by the program's own parts.
 
 2. **Roofline accounting.** At catalog time each program's cost is
    scored against a small peak-FLOPs/HBM-bandwidth table keyed on
@@ -43,7 +47,9 @@ no ``/profile`` route — nothing):
    window from a live worker: ``POST /profile?seconds=N``
    (parallel/restapi.py). Captures land under the metrics dir
    (``profile-<reason>-<n>/``) and are summarised offline by
-   ``tools/analyze_trace.py`` through ``log-summary``.
+   ``tools/analyze_trace.py`` through ``log-summary``. An automatic
+   capture yields to any profiler session already running in the
+   process, whoever started it (``profile/capture_skipped``).
 
 Design rules inherited from core/telemetry.py: never inside jit
 (GL007 — every clock here wraps the program from the host side), zero
@@ -56,6 +62,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import threading
 import time
 from typing import Optional, Tuple
@@ -64,7 +71,7 @@ from chunkflow_tpu.core import telemetry
 
 __all__ = [
     "instrument_program", "stamp_cost", "catalog", "write_catalog",
-    "device_peaks", "estimate_collective_split", "note_h2d",
+    "device_peaks", "DEVICE_SCOPES", "op_scopes", "note_h2d",
     "h2d_by_family",
     "note_hbm_intermediate", "hbm_intermediate_by_family",
     "note_collective", "collective_by_family",
@@ -125,30 +132,93 @@ def device_peaks(device_kind: str) -> dict:
     )
 
 
-def estimate_collective_split(flops: float, collective_bytes: float,
-                              device_kind: Optional[str] = None) -> dict:
-    """Analytic collective-vs-compute split of one sharded dispatch
-    against the roofline peak table: ``compute_s = flops / peak_flops``
-    and ``collective_s = collective_bytes / peak_bytes`` for the mesh's
-    device kind. The bytes/s figure is the chip's HBM row — a proxy that
-    flatters the interconnect (ICI/DCN are slower than HBM), so the
-    returned ``collective_share`` is a *lower bound* on how
-    communication-dominated the mesh shape is; a shape that already
-    looks collective-bound here is definitely not worth scaling.
-    ``device_kind=None`` probes ``jax.devices()[0]``."""
-    if device_kind is None:
-        _, device_kind = _device_identity()
-    peaks = device_peaks(device_kind)
-    compute_s = max(0.0, float(flops)) / peaks["flops_per_s"]
-    collective_s = max(0.0, float(collective_bytes)) / peaks["bytes_per_s"]
-    total = compute_s + collective_s
-    return {
-        "compute_s": compute_s,
-        "collective_s": collective_s,
-        "collective_share": (collective_s / total) if total > 0 else 0.0,
-        "device_kind": device_kind,
-        "peak_source": peaks["source"],
-    }
+# ---------------------------------------------------------------------------
+# device scopes: the program's own parts, readable from a device trace
+# ---------------------------------------------------------------------------
+#: The ``jax.named_scope`` names every patch program traces its parts
+#: under (ops/blend.py, ops/pallas_gather.py, ops/fold_blend.py,
+#: parallel/engine.py, serve/packer.py): patch gather, model forward,
+#: bump-weighted accumulation, weight normalization, and the mesh
+#: engine's cross-chip exchanges. Scopes are metadata: the compiled
+#: code is the same with and without them.
+DEVICE_SCOPES = ("gather", "forward", "accumulate", "normalize",
+                 "collective")
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = (.*)$")
+_HLO_OPCODE = re.compile(r"\s([a-z][a-z0-9\-]*)\(")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLED = re.compile(
+    r"(?:body|condition|to_apply|calls|true_computation|false_computation)"
+    r"=(%?[\w.\-]+)|branch_computations=\{([^}]*)\}")
+# a scope on an op's path in a lowered module's locations:
+# loc("jit(program)/while/body/forward/conv_general_dilated")
+_LOWERED_SCOPE = re.compile(
+    r'loc\("[^"]*/(?:%s)/' % "|".join(DEVICE_SCOPES))
+# never a device-trace event of their own
+_HLO_FREE = frozenset(("parameter", "get-tuple-element", "tuple", "bitcast",
+                       "constant"))
+
+
+def _scope_of(op_name: str) -> Optional[str]:
+    """The outermost :data:`DEVICE_SCOPES` name on a ``/``-separated
+    ``op_name`` path (``jit(program)/while/body/forward/RSUNet/conv``).
+    The last component is the primitive, not a scope: a ``lax.gather``
+    outside every scope ends in ``/gather`` and is under none."""
+    for part in op_name.split("/")[:-1]:
+        if part in DEVICE_SCOPES:
+            return part
+    return None
+
+
+def op_scopes(hlo_text: str) -> dict:
+    """``{scope: [op names]}`` of one compiled module's text
+    (``Compiled.as_text()``), the ops under none of
+    :data:`DEVICE_SCOPES` listed under ``""``. An op's scope is the
+    outermost scope name in its own ``op_name`` metadata; an op that has
+    none takes the scope of the instruction that calls its computation
+    (XLA expands a ``scatter-add`` into a ``while`` whose body ops carry
+    no metadata, the ``while`` does). Ops inside fusions and ops that
+    never run as an event of their own are left out."""
+    computations: dict = {}   # name -> [(op, opcode, scope, called)]
+    current = None
+    entry = None
+    for line in hlo_text.splitlines():
+        head = _HLO_COMPUTATION.match(line)
+        if head and not line.startswith(" "):
+            current = computations.setdefault(head.group(1), [])
+            if line.startswith("ENTRY"):
+                entry = head.group(1)
+            continue
+        match = _HLO_INSTRUCTION.match(line)
+        if match is None or current is None:
+            continue
+        name, rest = match.group(1), match.group(2)
+        opcode = _HLO_OPCODE.search(" " + rest)
+        opcode = opcode.group(1) if opcode else ""
+        op_name = _HLO_OP_NAME.search(rest)
+        called = []
+        if opcode != "fusion":
+            for one, several in _HLO_CALLED.findall(rest):
+                called += [c.strip().lstrip("%")
+                           for c in (one or several).split(",")]
+        current.append((name, opcode,
+                        _scope_of(op_name.group(1)) if op_name else None,
+                        called))
+    out: dict = {}
+    seen = set()
+    stack = [(entry, None)]
+    while stack:
+        computation, inherited = stack.pop()
+        if computation in seen or computation not in computations:
+            continue
+        seen.add(computation)
+        for name, opcode, scope, called in computations[computation]:
+            scope = scope or inherited
+            if opcode not in _HLO_FREE:
+                out.setdefault(scope or "", []).append(name)
+            stack += [(c, scope) for c in called]
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -161,7 +231,8 @@ class _ProgramRecord:
     __slots__ = (
         "family", "key", "label", "build_s", "compile_s", "flops",
         "bytes_accessed", "vmem_bytes", "hbm_intermediate", "optimal_s",
-        "calls", "dispatch_s", "platform", "device_kind", "lock",
+        "calls", "dispatch_s", "platform", "device_kind", "op_scopes",
+        "lock",
     )
 
     def __init__(self, family: str, key: str, label: str, build_s: float):
@@ -179,6 +250,7 @@ class _ProgramRecord:
         self.dispatch_s = 0.0  # post-compile dispatch wall, cumulative
         self.platform = ""
         self.device_kind = ""
+        self.op_scopes: Optional[dict] = None
         self.lock = threading.Lock()
 
 
@@ -193,15 +265,81 @@ def _device_identity() -> Tuple[str, str]:
     return dev.platform, dev.device_kind
 
 
-def _cost_analysis(program, args, kwargs) -> dict:
-    """Best-effort XLA cost analysis of the program at these argument
-    shapes, via ``Lowered.cost_analysis()`` (no second compile). Returns
-    {} when the backend / program doesn't expose it."""
+def _lower(program, args, kwargs):
+    """The program lowered at these argument shapes, or None."""
     try:
-        cost = program.lower(*args, **kwargs).cost_analysis()
+        return program.lower(*args, **kwargs)
+    except Exception:
+        return None
+
+
+def _cost_analysis(lowered) -> dict:
+    """Best-effort XLA cost analysis via ``Lowered.cost_analysis()`` (no
+    second compile). Returns {} when the backend / program doesn't
+    expose it."""
+    try:
+        cost = lowered.cost_analysis()
     except Exception:
         return {}
     return cost if isinstance(cost, dict) else {}
+
+
+def _arg_specs(args, kwargs):
+    """``(args, kwargs)`` with every array replaced by its shape, dtype
+    and sharding: enough to lower the program again after the call has
+    consumed a donated buffer."""
+    import jax
+
+    def spec(leaf):
+        if isinstance(leaf, jax.Array):
+            return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                        sharding=leaf.sharding)
+        return leaf
+
+    return jax.tree_util.tree_map(spec, (args, kwargs))
+
+
+def _compile_past_the_cache(lowered):
+    """``lowered.compile()`` with the persistent compile cache off for
+    the duration (the switch is process-wide and remembered: it takes a
+    ``reset_cache()`` on either side to be read again). ``lowered`` is
+    fresh: a ``Lowered`` keeps the executable of its first compile."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+
+
+def _compiled_op_scopes(lowered, lower_again) -> Optional[dict]:
+    """:func:`op_scopes` of the compiled module. ``Lowered.compile()``
+    after the program's first call finds the executable in the
+    persistent compile cache where that is on; otherwise it compiles a
+    second time, which is why only runs with a sink pay for it.
+
+    JAX leaves metadata out of the cache key, so the cache may hand back
+    an executable that was compiled from the same program before it had
+    scopes (another checkout's, an older release's): its module names no
+    scope although the lowered one does. The op names are the same, the
+    optimized code being the same but for metadata, so the map is then
+    read from one compile past the cache, of the program lowered again
+    (``lower_again()``), once per process as long as that entry lives."""
+    try:
+        scopes = op_scopes(lowered.compile().as_text())
+        if not set(scopes) & set(DEVICE_SCOPES) and _LOWERED_SCOPE.search(
+                lowered.as_text(debug_info=True)):
+            telemetry.inc("program/stale_cache_entries")
+            scopes = op_scopes(
+                _compile_past_the_cache(lower_again()).as_text())
+        return scopes
+    except Exception:
+        return None
 
 
 class _InstrumentedProgram:
@@ -234,13 +372,22 @@ class _InstrumentedProgram:
         # calls (the fused Pallas kernel) or loop bodies are opaque or
         # miscounted by the unoptimized-HLO analysis
         cost = getattr(self._fn, "_chunkflow_cost", None)
+        want_scopes = telemetry.configured_path() is not None
+        lowered = None
+        if not isinstance(cost, dict) or want_scopes:
+            # lower BEFORE dispatch: afterwards a donated input buffer
+            # is dead, and lowering only needs shapes anyway
+            lowered = _lower(self._fn, args, kwargs)
         if not isinstance(cost, dict):
-            # cost analysis BEFORE dispatch: afterwards a donated input
-            # buffer is dead, and lowering only needs shapes anyway
-            cost = _cost_analysis(self._fn, args, kwargs)
+            cost = _cost_analysis(lowered) if lowered is not None else {}
+        specs = _arg_specs(args, kwargs) if want_scopes else None
         t0 = time.perf_counter()
         out = self._fn(*args, **kwargs)
         dt = time.perf_counter() - t0
+        scopes = None
+        if want_scopes and lowered is not None:
+            scopes = _compiled_op_scopes(
+                lowered, lambda: self._fn.lower(*specs[0], **specs[1]))
         first = False
         with rec.lock:
             if rec.compile_s is None:
@@ -263,6 +410,7 @@ class _InstrumentedProgram:
                 rec.optimal_s = (
                     float(optimal) if optimal is not None else None
                 )
+                rec.op_scopes = scopes
             else:  # raced: the other thread's call was the compile
                 rec.calls += 1
                 rec.dispatch_s += dt
@@ -497,6 +645,7 @@ def catalog() -> list:
                 "dispatch_total_s": round(rec.dispatch_s, 4),
                 "platform": rec.platform,
                 "device_kind": rec.device_kind,
+                "op_scopes": rec.op_scopes,
             }
             calls, dispatch_s = rec.calls, rec.dispatch_s
             flops, nbytes = rec.flops, rec.bytes_accessed
@@ -570,7 +719,10 @@ def write_catalog(metrics_dir: Optional[str] = None) -> Optional[str]:
         metrics_dir = os.path.dirname(path) if path else None
     if metrics_dir is None:
         return None
-    telemetry.event("programs", "program/catalog", programs=entries)
+    # the JSONL stream gets the ledger without the per-op scope lists,
+    # which only a trace reducer needs and which programs.json keeps
+    telemetry.event("programs", "program/catalog", programs=[
+        {k: v for k, v in e.items() if k != "op_scopes"} for e in entries])
     payload = {
         "worker": telemetry.worker_id(),
         "t": time.time(),
@@ -616,10 +768,23 @@ def _anomaly_capture_enabled() -> bool:
     ).lower() not in ("0", "off", "false", "no")
 
 
+_SESSION_ACTIVE = "a profiler session is already active"
+
+
+def _foreign_session() -> bool:
+    """Whether a ``jax.profiler`` session that this module did not start
+    is tracing now: a harness's ``start_trace``, ``jax.profiler.trace``
+    in user code, a profiler server's client."""
+    try:
+        return telemetry.profiler_session_active()
+    except Exception:
+        return False
+
+
 def _acquire_trace() -> bool:
     global _TRACE_ACTIVE
     with _STATE_LOCK:
-        if _TRACE_ACTIVE:
+        if _TRACE_ACTIVE or _foreign_session():
             return False
         _TRACE_ACTIVE = True
         return True
@@ -651,6 +816,12 @@ def _run_capture(target: str, seconds: float, reason: str) -> bool:
         finally:
             jax.profiler.stop_trace()
     except Exception as exc:
+        if "already been started" in str(exc):
+            # someone else's session began between the check in
+            # capture() and here: yielding to it is not a failure
+            telemetry.event("profile", "profile/capture_skipped",
+                            reason=reason, why=_SESSION_ACTIVE)
+            return False
         telemetry.inc("profile/capture_errors")
         telemetry.event("profile", "profile/capture_error",
                         reason=reason, error=str(exc)[:300])
@@ -669,7 +840,8 @@ def capture(seconds: float, reason: str, force: bool = False,
 
     ``force=True`` (operator request, the ``/profile`` route) bypasses
     the automatic-capture cooldown but never the one-session-at-a-time
-    exclusion. ``background=True`` runs the window in its own thread
+    exclusion, which holds against sessions this module did not start
+    too (:func:`_foreign_session`). ``background=True`` runs the window in its own thread
     (anomaly triggers must not stall the pipeline for the window's
     duration; process exit waits for it). Disabled telemetry or no capture dir ⇒ ``(None, why)``.
     """
@@ -684,8 +856,8 @@ def capture(seconds: float, reason: str, force: bool = False,
                   _env_float("CHUNKFLOW_PROFILE_MAX_SECONDS", 60.0))
     cooldown = _env_float("CHUNKFLOW_PROFILE_COOLDOWN", 300.0)
     with _STATE_LOCK:
-        if _TRACE_ACTIVE:
-            return None, "a profiler session is already active"
+        if _TRACE_ACTIVE or _foreign_session():
+            return None, _SESSION_ACTIVE
         if not force and _LAST_CAPTURE_T is not None \
                 and time.monotonic() - _LAST_CAPTURE_T < cooldown:
             return None, "capture cooldown in effect"
@@ -745,18 +917,27 @@ def note_slo_page(objective: str) -> None:
     maybe_capture(f"slo-{_safe_name(objective)}")
 
 
+#: Phases in which the host waits for the device. Their dominating a
+#: worker's stall time is the healthy state of a device-bound pipeline
+#: (with async dispatch the wait lands in ``pipeline/dispatch`` once the
+#: device queue is full), so it is never an anomaly worth a capture.
+DEVICE_PACED_PHASES = ("pipeline/dispatch", "pipeline/compute")
+
+
 def note_stall(phase: str, share: float) -> None:
     """One depth-controller tick's dominant stall sample
     (flow/scheduler.py). A share at or above
     ``CHUNKFLOW_PROFILE_STALL_SHARE`` (default 0.8) for
     ``CHUNKFLOW_PROFILE_STALL_TICKS`` (default 3) *consecutive* ticks
     on the SAME phase triggers one bounded capture — a persistent
-    bottleneck the depth controller could not widen away."""
+    bottleneck the depth controller could not widen away. A host that
+    waits on the device (:data:`DEVICE_PACED_PHASES`) is no such
+    bottleneck: it breaks the streak like a low share does."""
     global _STALL_PHASE, _STALL_TICKS
     threshold = _env_float("CHUNKFLOW_PROFILE_STALL_SHARE", 0.8)
     need = _env_int("CHUNKFLOW_PROFILE_STALL_TICKS", 3)
     with _STATE_LOCK:
-        if share < threshold:
+        if share < threshold or phase in DEVICE_PACED_PHASES:
             _STALL_PHASE, _STALL_TICKS = None, 0
             return
         if phase != _STALL_PHASE:

@@ -27,15 +27,20 @@ Design rules, in priority order:
 2. **Near-zero overhead, zero when off.** ``CHUNKFLOW_TELEMETRY=0``
    turns every entry point into an early-out: no locks, no allocation,
    no file IO, nothing emitted. Enabled-path span cost is two
-   ``perf_counter`` calls plus one locked dict update.
+   ``perf_counter`` calls plus one locked dict update; ids, the start
+   time and the thread's name are made only while a sink is configured
+   or a profiler session runs.
 3. **Zero dependencies.** Events are plain JSON lines; aggregation
    needs nothing beyond the stdlib (pandas enters only in
-   ``log_summary``'s optional pretty printing).
+   ``log_summary``'s optional pretty printing). ``jax`` is never
+   imported from here: a span finds ``jax.profiler.TraceAnnotation``
+   only in a process that has already imported jax.
 
 Event schema (one JSON object per line; see docs/observability.md):
 
     {"kind": "span",    "name": "...", "t": <epoch end>, "dur_s": ...,
-     "pid": ..., ...attrs}
+     "pid": ..., "t0": <epoch start>, "span_id": ..., "parent_id": ...,
+     "thread": "...", ...attrs}
     {"kind": "gauge",   "name": "...", "t": <epoch>, "value": ...}
     {"kind": "snapshot", "t": <epoch>, "counters": {...}, "gauges": {...},
      "hists": {name: {count,total,min,max}}}
@@ -48,6 +53,21 @@ Span naming convention: ``<layer>/<phase>`` — ``pipeline/stage``,
 *consumes* this stream (per-phase stall totals via :func:`hist_totals`
 drive its depth controller) and *feeds* it: ``scheduler/depth/<knob>``
 gauges and ``depth_change`` events record every widening decision.
+
+Span trees (docs/observability.md "Span schema"): ``span_id`` is unique
+in a worker's stream, ``parent_id`` is the span that was open on the
+same context when this one started (``None`` for a task's top-level
+spans: the task, its ``trace_id``, is the root). Both ride
+``contextvars``, which do not follow work into pool threads: hand work
+over with ``pool.submit(contextvars.copy_context().run, fn, ...)`` and
+the spans it opens there keep the submitting span as parent and the
+task's ``trace_id``. A span that waits for a task not yet known (a
+queue fetch, the scheduler's load wait) takes the id once the item is
+in hand: :meth:`_Span.bind`. While a ``jax.profiler`` session runs
+(the benchmark's, an operator's ``/profile`` capture, an anomaly
+capture) every span is also a ``TraceAnnotation`` of the same name on
+its thread's line of the ``/host:CPU`` plane, on the profiler's clock:
+a device-idle gap can be read against what the host was doing in it.
 
 Fleet correlation (docs/observability.md "Fleet view"): every emitted
 line is stamped with this process's :func:`worker_id` (stable host+pid
@@ -77,10 +97,12 @@ thread, no rings, no events.
 from __future__ import annotations
 
 import contextvars
+import itertools
 import json
 import os
 import re
 import socket
+import sys
 import threading
 import time
 from collections import deque
@@ -88,8 +110,10 @@ from typing import Dict, List, Optional, Tuple
 
 __all__ = [
     "enabled", "configure", "configured_path", "inc", "gauge", "observe",
-    "span", "event", "snapshot", "flush", "reset", "summary_table",
+    "span", "record_span", "event", "snapshot", "flush", "reset",
+    "summary_table",
     "hist_totals", "worker_id", "task_context", "current_trace_id",
+    "profiler_session_active",
     "snapshot_interval", "add_flush_hook", "add_reset_hook",
     "observe_quantile", "quantile", "quantile_from_buckets",
     "QUANTILE_BOUNDS", "timeseries", "start_timeseries",
@@ -151,10 +175,20 @@ _TASK_CTX: contextvars.ContextVar = contextvars.ContextVar(
 )
 
 
+# the innermost open span of this context that has an id (spans make
+# ids only while someone can read them: _Span.__enter__)
+_SPAN_CTX: contextvars.ContextVar = contextvars.ContextVar(
+    "chunkflow_span_id", default=None
+)
+_SPAN_IDS = itertools.count(1)  # next() is atomic under the GIL
+
+
 def current_trace_id() -> Optional[str]:
     """The trace id of the task currently in flight on this
     thread/context, or None outside any :func:`task_context`."""
     return _TASK_CTX.get()
+
+
 
 
 class _TaskContext:
@@ -510,6 +544,12 @@ class _NullSpan:
     __slots__ = ()
     duration = 0.0
 
+    def bind(self, trace_id) -> None:
+        pass
+
+    def cancel(self) -> None:
+        pass
+
     def __enter__(self):
         return self
 
@@ -520,38 +560,135 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax is imported
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` if this process has imported
+    jax, else None: looked up in ``sys.modules``, never imported (design
+    rule 3), and kept once found."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _ANNOTATION = getattr(profiler, "TraceAnnotation", None)
+    return _ANNOTATION
+
+
+def profiler_session_active() -> bool:
+    """Whether a ``jax.profiler`` session is tracing in this process
+    now, whoever started it (the flag every ``TraceAnnotation`` tests).
+    False in a process that has not imported jax."""
+    annotation = _trace_annotation()
+    return annotation is not None and annotation.is_enabled()
+
+
+def _emit_span(name: str, t0: float, dur_s: float, span_id: int,
+               parent_id, trace_id, attrs) -> None:
+    """One span record to the sink (the caller checked there is one)."""
+    payload = {"kind": "span", "name": name, "t": time.time(),
+               "dur_s": dur_s, "pid": os.getpid(), "t0": t0,
+               "span_id": span_id, "parent_id": parent_id,
+               "thread": threading.current_thread().name}
+    if attrs:
+        payload.update(attrs)
+    _stamp(payload)
+    if trace_id is not None:
+        payload["trace_id"] = trace_id
+    _REG.emit(payload)
+
+
 class _Span:
-    __slots__ = ("name", "attrs", "t0", "duration")
+    __slots__ = ("name", "attrs", "t0", "duration", "trace_id", "span_id",
+                 "parent_id", "wall0", "cancelled", "_token", "_annotation")
 
     def __init__(self, name: str, attrs):
         self.name = name
         self.attrs = attrs
         self.duration = 0.0
+        self.trace_id = None
+        self.span_id = None
+        self.cancelled = False
+        self._annotation = None
+
+    def cancel(self) -> None:
+        """Record nothing when the block ends: for a span that turned
+        out to time no work (a queue poll that found no task)."""
+        self.cancelled = True
+
+    def bind(self, trace_id: Optional[str]) -> None:
+        """Give the span the task it turned out to work for: a span
+        that waits for a task not yet known (a queue fetch, the
+        scheduler's load wait) calls this once the item is in hand.
+        ``None`` leaves the enclosing :func:`task_context` in force."""
+        if trace_id is not None:
+            self.trace_id = trace_id
+            if self._annotation is not None:
+                self._annotation.set_metadata(trace_id=trace_id)
 
     def __enter__(self):
+        tracing = profiler_session_active()
+        if tracing or _REG.sink is not None:
+            self.span_id = next(_SPAN_IDS)
+            self.parent_id = _SPAN_CTX.get()
+            self._token = _SPAN_CTX.set(self.span_id)
+            self.wall0 = time.time()
+            if tracing:
+                # the same name, letter for letter, on this thread's
+                # line of the profiler's /host:CPU plane
+                self._annotation = _ANNOTATION(
+                    self.name, span_id=self.span_id,
+                    trace_id=_TASK_CTX.get() or "")
+                self._annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self.duration = time.perf_counter() - self.t0
-        _REG.add_hist(self.name, self.duration)
-        if _REG.sink is not None:
-            payload = {"kind": "span", "name": self.name, "t": time.time(),
-                       "dur_s": self.duration, "pid": os.getpid()}
-            if self.attrs:
-                payload.update(self.attrs)
-            _REG.emit(_stamp(payload))
+        if not self.cancelled:
+            _REG.add_hist(self.name, self.duration)
+        if self.span_id is None:
+            return False
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
+        try:
+            _SPAN_CTX.reset(self._token)
+        except ValueError:
+            # closed on another context than it was opened on (a span
+            # object carried across threads): nothing to restore there
+            pass
+        if _REG.sink is not None and not self.cancelled:
+            _emit_span(self.name, self.wall0, self.duration, self.span_id,
+                       self.parent_id, self.trace_id, self.attrs)
         return False
 
 
 def span(name: str, **attrs):
     """Time a block: ``with span("pipeline/drain"): ...``. Feeds the
-    histogram registry and (sink configured) emits one JSONL event. The
+    histogram registry and (sink configured) emits one JSONL event with
+    the span's start, id, parent and thread; while a ``jax.profiler``
+    session runs it is a ``TraceAnnotation`` of the same name too. The
     span object exposes ``.duration`` after exit for callers that keep a
-    legacy timer view."""
+    legacy timer view, and :meth:`~_Span.bind` for a task known late."""
     if not enabled():
         return _NULL_SPAN
     return _Span(name, attrs)
+
+
+def record_span(name: str, t0: float, trace_id: Optional[str] = None,
+                **attrs) -> None:
+    """Record a span that began at ``t0`` (``time.time()``) and ends
+    now, for an interval no ``with`` block can cover because it starts
+    on one thread and ends on another (a request's wait from admission
+    to its first device batch). Same histogram and, with a sink, the
+    same JSONL record as :func:`span`, top-level (``parent_id`` None);
+    not a profiler annotation, which cannot be made after the fact."""
+    if not enabled():
+        return
+    dur_s = time.time() - t0
+    _REG.add_hist(name, dur_s)
+    if _REG.sink is not None:
+        _emit_span(name, t0, dur_s, next(_SPAN_IDS), None, trace_id, attrs)
 
 
 def hist_totals(names) -> Dict[str, float]:

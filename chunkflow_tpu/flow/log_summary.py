@@ -444,9 +444,9 @@ def print_mesh_block(agg: dict, indent: str = "") -> bool:
     """The MESH block (docs/multichip.md "Reading chip skew",
     docs/observability.md "Timeline view"): mesh shape, a per-chip table
     folding the ``shard/chip/<i>/*`` load/readiness gauges with the
-    ``device/chip/<i>/*`` HBM watermarks, the dispatch skew, the
-    analytic halo/gather byte planes, and the collective-vs-compute
-    split estimate — the evidence for choosing a scaling shape. Quiet
+    ``device/chip/<i>/*`` HBM watermarks, the dispatch skew and the
+    analytic halo/gather byte planes — the evidence for choosing a
+    scaling shape, short of the device trace's collective time. Quiet
     (returns False) for runs that never built a sharded engine."""
     from chunkflow_tpu.core import telemetry as _telemetry
 
@@ -503,42 +503,33 @@ def print_mesh_block(agg: dict, indent: str = "") -> bool:
             parts.append(f"stage handoffs {handoff / 2**20:.2f} MiB")
         print(f"{indent}  analytic collective traffic: "
               f"{', '.join(parts)} (cumulative)")
-    share = gauges.get("shard/collective_share_est")
-    if share:
-        compute = gauges.get("shard/compute_s_est", {}).get("last", 0.0)
-        coll = gauges.get("shard/collective_s_est", {}).get("last", 0.0)
-        verdict = ("collective-bound" if share["last"] > 0.5
-                   else "compute-bound")
-        print(f"{indent}  split estimate per dispatch: compute "
-              f"{compute:.6f}s vs collective {coll:.6f}s "
-              f"(share {share['last']:.0%} — {verdict}; HBM-bandwidth "
-              f"proxy, a lower bound on interconnect pressure)")
-        # collective verdict -> shape hint (docs/multichip.md "Choosing
-        # a scaling shape"): collective-bound meshes should trade the
-        # interconnect plane that dominates; a compute-bound mesh is
-        # already using the right shape, scale it instead
-        if share["last"] > 0.5:
-            if gather and not strips:
-                hint = ("replicated replay dominates — flip "
-                        "CHUNKFLOW_SHARD_REPLAY=sharded (the default) "
-                        "to drop the weighted-stack all_gather")
-            elif handoff:
-                hint = ("stage handoffs dominate — fewer pipeline "
-                        "stages, or a data/spatial mesh if the model "
-                        "fits per chip")
-            else:
-                hint = ("halo/fringe exchange dominates — coarser "
-                        "slabs (fewer chips per axis) or a data mesh")
-            print(f"{indent}  shape hint: {hint}")
-        elif tight_chips := [
-            chip for chip, m in chips.items()
-            if m.get("hbm_headroom", {}).get("last", float("inf"))
-            < 2**30
-        ]:
-            print(f"{indent}  shape hint: compute-bound but chip(s) "
-                  f"{tight_chips} have <1 GiB HBM headroom — a spatial "
-                  f"mesh (sharded replay) shrinks per-chip blend "
-                  f"buffers; pipeline=N shrinks per-chip parameters")
+    # bytes say which exchange is the largest, not what it costs: time
+    # on the interconnect is the device trace's to say (the ops under
+    # the `collective` named scope), so the remedy is offered for the
+    # case that the trace shows collectives dominating
+    planes = {"weighted-stack gather": gather, "stage handoffs": handoff,
+              "halo/fringe exchange": halo + strips}
+    if any(planes.values()):
+        largest = max(planes, key=planes.get)
+        if largest == "stage handoffs":
+            remedy = ("fewer pipeline stages, or a data/spatial mesh if "
+                      "the model fits per chip")
+        elif largest == "weighted-stack gather" and not strips:
+            remedy = ("flip CHUNKFLOW_SHARD_REPLAY=sharded (the default) "
+                      "to drop the weighted-stack all_gather")
+        else:
+            remedy = "coarser slabs (fewer chips per axis) or a data mesh"
+        print(f"{indent}  largest collective plane by bytes: {largest} "
+              f"(its time: a device trace, ops under the `collective` "
+              f"scope); if it dominates there: {remedy}")
+    if tight_chips := [
+        chip for chip, m in chips.items()
+        if m.get("hbm_headroom", {}).get("last", float("inf")) < 2**30
+    ]:
+        print(f"{indent}  shape hint: chip(s) {tight_chips} have <1 GiB "
+              f"HBM headroom — a spatial mesh (sharded replay) shrinks "
+              f"per-chip blend buffers; pipeline=N shrinks per-chip "
+              f"parameters")
     return True
 
 

@@ -218,14 +218,16 @@ def pipelined_inference_stage(
             task, out, t0 = entry
             # crop already applied on device; _drain_host splits the wait
             # into pipeline/compute + pipeline/drain spans
-            task[output_name] = _drain_host(out)
+            with telemetry.task_context(task.get("trace_id")):
+                task[output_name] = _drain_host(out)
             task["log"]["timer"][op_name] = time.time() - t0
             task["log"]["compute_device"] = inferencer.compute_device
             return task
 
         def dispatch_one():
             task, slot, owned, t0 = staged.popleft()
-            with telemetry.span("pipeline/dispatch"):
+            with telemetry.task_context(task.get("trace_id")), \
+                    telemetry.span("pipeline/dispatch"):
                 out = inferencer.infer_async(slot, crop=crop, consume=owned)
             pending.append((task, out, t0))
             telemetry.gauge("pipeline/inflight", len(pending))
@@ -244,7 +246,8 @@ def pipelined_inference_stage(
                 chunk = task[input_name]
                 if check is not None:
                     check(chunk)
-                with telemetry.span("pipeline/stage"):
+                with telemetry.task_context(task.get("trace_id")), \
+                        telemetry.span("pipeline/stage"):
                     slot = inferencer.stage(chunk)
                 # donate only pipeline-staged buffers: a chunk that was
                 # already device-resident stays valid in the task dict

@@ -751,8 +751,12 @@ def scheduled_inference_stage(
         try:
             try:
                 while True:
-                    with telemetry.span("scheduler/load"):
+                    with telemetry.span("scheduler/load") as load:
                         item = q.get()
+                        # the wait was for a task not yet known: it
+                        # takes the task's id now that it is in hand
+                        if isinstance(item, dict):
+                            load.bind(item.get("trace_id"))
                     if _is_end(item):
                         if item[1] is not None:
                             raise item[1]
@@ -773,7 +777,8 @@ def scheduled_inference_stage(
                     if check is not None:
                         check(chunk)
                     ctl.note_slot_bytes(_chunk_nbytes(chunk))
-                    with telemetry.span("pipeline/stage"):
+                    with telemetry.task_context(task.get("trace_id")), \
+                            telemetry.span("pipeline/stage"):
                         slot = inferencer.stage(chunk)
                     staged.append(
                         (task, slot, slot is not chunk, time.time()))

@@ -695,7 +695,13 @@ class Inferencer:
         import jax
         import jax.numpy as jnp
 
-        if self.dry_run or chunk.all_zero():
+        # on a staged (device-resident) chunk the check is a reduction
+        # queued behind the programs in flight, and its answer is waited
+        # for: in a device-bound pipeline the host spends most of a task
+        # here, and its own span says so
+        with telemetry.span("inference/blank_check"):
+            blank = self.dry_run or chunk.all_zero()
+        if blank:
             return self._blank_output(chunk)
 
         orig_zyx = tuple(chunk.shape[-3:])

@@ -251,7 +251,12 @@ def make_accumulate(output_patch_size: Tuple[int, int, int], bump):
     accumulation the fused per-chunk program runs — same kernel
     selection, same weighting expressions, same per-batch grouping —
     which is what makes packed-vs-per-chunk and mesh-vs-single outputs
-    bit-identical."""
+    bit-identical.
+
+    Both flavors trace under ``jax.named_scope("accumulate")`` (metadata
+    only: core/profiling.py reads it back out of the compiled program,
+    see :data:`~chunkflow_tpu.core.profiling.DEVICE_SCOPES`)."""
+    import jax
     import jax.numpy as jnp
     from jax import lax
 
@@ -268,16 +273,18 @@ def make_accumulate(output_patch_size: Tuple[int, int, int], bump):
         interp = mode == "interpret"
 
         def accumulate(out, weight, preds, valid, starts):
-            return pallas_blend.fused_accumulate_patches(
-                out, weight, preds, valid, bump, starts,
-                pre_weighted=False, interpret=interp,
-            )
+            with jax.named_scope("accumulate"):
+                return pallas_blend.fused_accumulate_patches(
+                    out, weight, preds, valid, bump, starts,
+                    pre_weighted=False, interpret=interp,
+                )
 
         def accumulate_weighted(out, weight, weighted, valid, starts):
-            return pallas_blend.fused_accumulate_patches(
-                out, weight, weighted, valid, bump, starts,
-                pre_weighted=True, interpret=interp,
-            )
+            with jax.named_scope("accumulate"):
+                return pallas_blend.fused_accumulate_patches(
+                    out, weight, weighted, valid, bump, starts,
+                    pre_weighted=True, interpret=interp,
+                )
 
         return accumulate, accumulate_weighted, pad_y, pad_x
 
@@ -300,14 +307,16 @@ def make_accumulate(output_patch_size: Tuple[int, int, int], bump):
     def accumulate(out, weight, preds, valid, starts):
         # the same weighting expression, in the same order, the fused
         # kernel computes in VMEM — (preds * bump) * valid
-        weighted = preds * bump[None, None] \
-            * valid[:, None, None, None, None]
-        wpatch = bump[None] * valid[:, None, None, None]
-        return _scatter(out, weight, weighted, wpatch, starts)
+        with jax.named_scope("accumulate"):
+            weighted = preds * bump[None, None] \
+                * valid[:, None, None, None, None]
+            wpatch = bump[None] * valid[:, None, None, None]
+            return _scatter(out, weight, weighted, wpatch, starts)
 
     def accumulate_weighted(out, weight, weighted, valid, starts):
-        wpatch = bump[None] * valid[:, None, None, None]
-        return _scatter(out, weight, weighted, wpatch, starts)
+        with jax.named_scope("accumulate"):
+            wpatch = bump[None] * valid[:, None, None, None]
+            return _scatter(out, weight, weighted, wpatch, starts)
 
     return accumulate, accumulate_weighted, pad_y, pad_x
 
@@ -378,8 +387,9 @@ def build_local_blend(
         zyx_buf = (zyx[0], zyx[1] + pad_y, zyx[2] + pad_x)
         n = in_starts.shape[0]
         num_batches = n // batch_size
-        out0 = jnp.zeros((co,) + zyx_buf, dtype=jnp.float32)
-        w0 = jnp.zeros(zyx_buf, dtype=jnp.float32)
+        with jax.named_scope("accumulate"):
+            out0 = jnp.zeros((co,) + zyx_buf, dtype=jnp.float32)
+            w0 = jnp.zeros(zyx_buf, dtype=jnp.float32)
         chunk_like = prepare_chunk(chunk)
 
         def forward_batch(b):
@@ -389,7 +399,8 @@ def build_local_blend(
             # RAW predictions: the bump*valid weighting lives inside the
             # accumulation step (fused into the kernel's VMEM pass on
             # the Pallas leg)
-            return forward(params, patches)
+            with jax.named_scope("forward"):
+                return forward(params, patches)
 
         if use_stacked and n * patch_bytes <= stack_max_bytes:
             _, all_preds = lax.scan(
@@ -415,8 +426,9 @@ def build_local_blend(
                 step, (out0, w0), jnp.arange(num_batches)
             )
         if pad_y or pad_x:
-            out = out[:, :, : zyx[1], : zyx[2]]
-            weight = weight[:, : zyx[1], : zyx[2]]
+            with jax.named_scope("accumulate"):
+                out = out[:, :, : zyx[1], : zyx[2]]
+                weight = weight[:, : zyx[1], : zyx[2]]
         return out, weight
 
     return local_blend
@@ -433,11 +445,13 @@ def normalize_blend(out, weight, dtype="float32"):
     program builder. ``uint8`` quantizes [0,1] maps exactly like the
     reference's save-time conversion (save_precomputed.py:90-92:
     ``chunk *= 255`` then truncating astype)."""
+    import jax
     import jax.numpy as jnp
 
-    result = jnp.where(
-        weight[None] > 0, out / jnp.maximum(weight[None], 1e-20), 0.0
-    )
-    if jnp.dtype(dtype) == jnp.uint8:
-        return (jnp.clip(result, 0.0, 1.0) * 255.0).astype(jnp.uint8)
-    return result.astype(jnp.dtype(dtype))
+    with jax.named_scope("normalize"):
+        result = jnp.where(
+            weight[None] > 0, out / jnp.maximum(weight[None], 1e-20), 0.0
+        )
+        if jnp.dtype(dtype) == jnp.uint8:
+            return (jnp.clip(result, 0.0, 1.0) * 255.0).astype(jnp.uint8)
+        return result.astype(jnp.dtype(dtype))

@@ -175,27 +175,34 @@ def build_fold_program(
     bump = jnp.asarray(bump, jnp.float32)
 
     def program(chunk, params):
-        patches = jnp.stack([
-            lax.slice(
-                chunk, (0,) + s, (ci,) + tuple(a + b for a, b in zip(s, pin))
+        # the four parts under the names every patch program uses
+        # (core/profiling.py DEVICE_SCOPES; metadata only)
+        with jax.named_scope("gather"):
+            patches = jnp.stack([
+                lax.slice(
+                    chunk, (0,) + s,
+                    (ci,) + tuple(a + b for a, b in zip(s, pin))
+                )
+                for s in starts
+            ])
+            if n_pad:
+                patches = jnp.concatenate(
+                    [patches, jnp.zeros((n_pad, ci) + pin, patches.dtype)]
+                )
+        with jax.named_scope("forward"):
+            preds = lax.map(
+                lambda xb: forward(params, xb),
+                # split patch axis n -> (nb, batch)
+                patches.reshape((nb, batch_size, ci) + pin),
             )
-            for s in starts
-        ])
-        if n_pad:
-            patches = jnp.concatenate(
-                [patches, jnp.zeros((n_pad, ci) + pin, patches.dtype)]
-            )
-        preds = lax.map(
-            lambda xb: forward(params, xb),
-            # split patch axis n -> (nb, batch)
-            patches.reshape((nb, batch_size, ci) + pin),
-        )
-        # merge (nb, batch) -> flat patch axis, drop padding
-        preds = preds.reshape((nb * batch_size, co) + pout)[:n]
-        weighted = preds.astype(jnp.float32) * bump[None, None]
-        out = fold_accumulate(weighted, grid, stride, pout, margin, zyx)
-        wstack = jnp.broadcast_to(bump[None, None], (n, 1) + pout)
-        weight = fold_accumulate(wstack, grid, stride, pout, margin, zyx)[0]
+        with jax.named_scope("accumulate"):
+            # merge (nb, batch) -> flat patch axis, drop padding
+            preds = preds.reshape((nb * batch_size, co) + pout)[:n]
+            weighted = preds.astype(jnp.float32) * bump[None, None]
+            out = fold_accumulate(weighted, grid, stride, pout, margin, zyx)
+            wstack = jnp.broadcast_to(bump[None, None], (n, 1) + pout)
+            weight = fold_accumulate(
+                wstack, grid, stride, pout, margin, zyx)[0]
         return normalize_blend(out, weight, out_dtype)
 
     # the chunk buffer is dead after the call (GL005): XLA may reuse it

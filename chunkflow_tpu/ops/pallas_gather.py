@@ -374,7 +374,9 @@ def make_gather(num_input_channels: int, input_patch_size: Triple):
 
     Both legs produce bitwise-identical float32 patches (conversion and
     slicing commute exactly), which is what keeps every downstream
-    parity contract intact no matter the selection."""
+    parity contract intact no matter the selection. Both trace under
+    ``jax.named_scope("gather")`` (metadata only; core/profiling.py
+    ``DEVICE_SCOPES``)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -386,14 +388,16 @@ def make_gather(num_input_channels: int, input_patch_size: Triple):
     if mode in ("device", "host"):
 
         def prepare(chunk):
-            return convert_chunk(chunk)
+            with jax.named_scope("gather"):
+                return convert_chunk(chunk)
 
         def gather(chunk_f32, s_in):
-            return jax.vmap(
-                lambda s: lax.dynamic_slice(
-                    chunk_f32, (0, s[0], s[1], s[2]), (ci,) + pin
-                )
-            )(s_in)
+            with jax.named_scope("gather"):
+                return jax.vmap(
+                    lambda s: lax.dynamic_slice(
+                        chunk_f32, (0, s[0], s[1], s[2]), (ci,) + pin
+                    )
+                )(s_in)
 
         return prepare, gather
 
@@ -404,12 +408,14 @@ def make_gather(num_input_channels: int, input_patch_size: Triple):
         if pad_y or pad_x:
             # constant pad: the aligned DMA windows may cover these
             # cells but no patch ever reads them
-            chunk = jnp.pad(
-                chunk, [(0, 0), (0, 0), (0, pad_y), (0, pad_x)]
-            )
+            with jax.named_scope("gather"):
+                chunk = jnp.pad(
+                    chunk, [(0, 0), (0, 0), (0, pad_y), (0, pad_x)]
+                )
         return chunk
 
     def gather(chunk_raw, s_in):
-        return gather_patches(chunk_raw, s_in, pin, interpret=interp)
+        with jax.named_scope("gather"):
+            return gather_patches(chunk_raw, s_in, pin, interpret=interp)
 
     return prepare, gather

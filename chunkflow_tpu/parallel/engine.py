@@ -110,11 +110,10 @@ Per-chip attribution (ISSUE 18, docs/observability.md "Timeline view"):
   ``all_gather``), ``shard/replay_strip_bytes`` (the sharded replay's
   fringe-window ``ppermute`` strips) and ``shard/handoff_bytes`` (the
   pipeline ring's stage handoffs) — all folded per program family via
-  ``profiling.note_collective``; the derived ``shard/compute_s_est`` /
-  ``shard/collective_s_est`` / ``shard/collective_share_est`` split per
-  mesh shape (``profiling.estimate_collective_split`` against the
-  roofline peaks, over the SUM of all four byte families so the new
-  shapes don't understate ICI traffic); and the analytic
+  ``profiling.note_collective`` (what the exchanges cost in time is
+  the device trace's to say: every exchange traces under the
+  ``collective`` named scope, core/profiling.py ``DEVICE_SCOPES``); and
+  the analytic
   ``shard/replay_buffer_bytes`` (+ per-chip
   ``shard/chip/<i>/replay_buffer_bytes``) blend-buffer footprint — the
   slab+margin vs full-chunk HBM claim, asserted in-suite next to the
@@ -304,15 +303,6 @@ def _pad_chunk(arr, padded_y: int, padded_x: int):
     import jax.numpy as jnp
 
     return jnp.pad(arr, pad)
-
-
-def _program_flops(program):
-    """The dispatch's cost-analysis FLOPs, read back from the profiling
-    ledger record the ProgramCache wrapper attached (None when telemetry
-    is off, the program is uninstrumented, or XLA exposed no figure) —
-    the compute side of the collective-vs-compute split."""
-    rec = getattr(program, "_rec", None)
-    return getattr(rec, "flops", None)
 
 
 class _Partition(NamedTuple):
@@ -731,6 +721,7 @@ class ShardedEngine:
         single-device program's ``forward_batch``. ``chunk_like`` is the
         RAW chip-local chunk: ``prepare`` runs here, AFTER any halo
         exchange, so exchanges ship the narrow dtype."""
+        import jax
         import jax.numpy as jnp
         from jax import lax
 
@@ -748,9 +739,11 @@ class ShardedEngine:
                 s_in = lax.dynamic_slice(in_starts, (i0, 0), (B, 3))
                 v = lax.dynamic_slice(valid, (i0,), (B,))
                 patches = gather(chunk_like, s_in)
-                preds = forward(params, patches)
-                return (preds * bump[None, None]
-                        * v[:, None, None, None, None])
+                with jax.named_scope("forward"):
+                    preds = forward(params, patches)
+                with jax.named_scope("accumulate"):
+                    return (preds * bump[None, None]
+                            * v[:, None, None, None, None])
 
             _, stack = lax.scan(
                 lambda c, b: (c, fwd_batch(b)), None,
@@ -771,6 +764,7 @@ class ShardedEngine:
         Pallas kernel, whichever ``make_accumulate`` selected — then
         normalize. Runs replicated on every chip (outputs are identical
         by construction)."""
+        import jax
         import jax.numpy as jnp
         from jax import lax
 
@@ -782,8 +776,9 @@ class ShardedEngine:
         out_dtype = self.out_dtype
 
         def replay(weighted, valid, out_starts):
-            out0 = jnp.zeros((co,) + zyx_buf, dtype=jnp.float32)
-            w0 = jnp.zeros(zyx_buf, dtype=jnp.float32)
+            with jax.named_scope("accumulate"):
+                out0 = jnp.zeros((co,) + zyx_buf, dtype=jnp.float32)
+                w0 = jnp.zeros(zyx_buf, dtype=jnp.float32)
 
             def step(carry, b):
                 out, weight = carry
@@ -799,8 +794,9 @@ class ShardedEngine:
                 step, (out0, w0), jnp.arange(num_batches)
             )
             if pad_y or pad_x:
-                out = out[:, :, : zyx[1], : zyx[2]]
-                weight = weight[:, : zyx[1], : zyx[2]]
+                with jax.named_scope("accumulate"):
+                    out = out[:, :, : zyx[1], : zyx[2]]
+                    weight = weight[:, : zyx[1], : zyx[2]]
             return normalize_blend(out, weight, out_dtype)
 
         return replay
@@ -818,6 +814,7 @@ class ShardedEngine:
         back to the bare slab drops the margins and the Pallas
         alignment pad together, then normalizes per slab (elementwise —
         exact)."""
+        import jax
         import jax.numpy as jnp
         from jax import lax
 
@@ -829,8 +826,9 @@ class ShardedEngine:
         out_dtype = self.out_dtype
 
         def replay(weighted, valid, starts):
-            out0 = jnp.zeros((co,) + buf, dtype=jnp.float32)
-            w0 = jnp.zeros(buf, dtype=jnp.float32)
+            with jax.named_scope("accumulate"):
+                out0 = jnp.zeros((co,) + buf, dtype=jnp.float32)
+                w0 = jnp.zeros(buf, dtype=jnp.float32)
 
             def step(carry, b):
                 out, weight = carry
@@ -845,8 +843,9 @@ class ShardedEngine:
             (out, weight), _ = lax.scan(
                 step, (out0, w0), jnp.arange(num_batches)
             )
-            out = out[:, :, m_y:m_y + slab_y, m_x:m_x + slab_x]
-            weight = weight[:, m_y:m_y + slab_y, m_x:m_x + slab_x]
+            with jax.named_scope("accumulate"):
+                out = out[:, :, m_y:m_y + slab_y, m_x:m_x + slab_x]
+                weight = weight[:, m_y:m_y + slab_y, m_x:m_x + slab_x]
             return normalize(out, weight, out_dtype)
 
         return replay
@@ -910,7 +909,8 @@ class ShardedEngine:
             stack = scan_stack(chunk, in_starts, local_valid, params)
             # exact data movement: tiled all_gather reassembles the
             # stacks in mesh-axis order == global patch order
-            return lax.all_gather(stack, "data", axis=0, tiled=True)
+            with jax.named_scope("collective"):
+                return lax.all_gather(stack, "data", axis=0, tiled=True)
 
         if plan is None:
             def device_fn(chunk, in_starts, out_starts, valid, params):
@@ -926,8 +926,9 @@ class ShardedEngine:
                 import jax.numpy as jnp
 
                 gathered = stack_global(chunk, in_starts, valid, params)
-                pool = self._append_zero_row(gathered)
-                weighted = jnp.take(pool, rp_index[0], axis=0)
+                with jax.named_scope("collective"):
+                    pool = self._append_zero_row(gathered)
+                    weighted = jnp.take(pool, rp_index[0], axis=0)
                 return replay(weighted, rp_valid[0], rp_starts[0])
 
             in_specs = (P(), P("data"), P(),
@@ -995,9 +996,8 @@ class ShardedEngine:
         fwd_x = [(i, i + 1) for i in range(nx - 1)]
         bwd_x = [(i + 1, i) for i in range(nx - 1)]
 
-        def local_stack(chunk_slab, in_starts, local_valid, params):
+        def halo_exchange(ext):
             # ---- 1a. y halo exchange (skipped statically at ny=1) ----
-            ext = chunk_slab
             if ny > 1:
                 pieces = []
                 if hl_y:
@@ -1019,6 +1019,11 @@ class ShardedEngine:
                     pieces.append(lax.ppermute(
                         ext[:, :, :, :hr_x], "x", bwd_x))
                 ext = lax.concatenate(pieces, dimension=3)
+            return ext
+
+        def local_stack(chunk_slab, in_starts, local_valid, params):
+            with jax.named_scope("collective"):
+                ext = halo_exchange(chunk_slab)
 
             # ---- 2. local gather + forward over the extended slab ----
             return scan_stack(ext, in_starts, local_valid, params)
@@ -1034,14 +1039,15 @@ class ShardedEngine:
                 # ---- 3. global reassembly: x-major then y-major gather
                 # matches the row-major device layout; take() restores
                 # global patch order (exact data movement) ----
-                gathered = stack
-                if nx > 1:
-                    gathered = lax.all_gather(gathered, "x", axis=0,
-                                              tiled=True)
-                if ny > 1:
-                    gathered = lax.all_gather(gathered, "y", axis=0,
-                                              tiled=True)
-                weighted = jnp.take(gathered, src_index, axis=0)
+                with jax.named_scope("collective"):
+                    gathered = stack
+                    if nx > 1:
+                        gathered = lax.all_gather(gathered, "x", axis=0,
+                                                  tiled=True)
+                    if ny > 1:
+                        gathered = lax.all_gather(gathered, "y", axis=0,
+                                                  tiled=True)
+                    weighted = jnp.take(gathered, src_index, axis=0)
                 return replay(weighted, valid, out_starts)
 
             in_specs = (
@@ -1066,19 +1072,22 @@ class ShardedEngine:
                 # cross the +y (then +x) slab boundary ride ppermute;
                 # the pool order own ++ recv_y ++ recv_x ++ zeros-row
                 # matches the host plan's index space exactly ----
-                pool = stack
-                if ny > 1 and fy:
-                    recv_y = lax.ppermute(
-                        jnp.take(stack, fr_y[0, 0], axis=0), "y", fwd_y)
-                    pool = jnp.concatenate([pool, recv_y], axis=0)
-                if nx > 1 and fx:
-                    recv_x = lax.ppermute(
-                        jnp.take(pool, fr_x[0, 0], axis=0), "x", fwd_x)
-                    pool = jnp.concatenate([pool, recv_x], axis=0)
-                pool = self._append_zero_row(pool)
+                with jax.named_scope("collective"):
+                    pool = stack
+                    if ny > 1 and fy:
+                        recv_y = lax.ppermute(
+                            jnp.take(stack, fr_y[0, 0], axis=0), "y",
+                            fwd_y)
+                        pool = jnp.concatenate([pool, recv_y], axis=0)
+                    if nx > 1 and fx:
+                        recv_x = lax.ppermute(
+                            jnp.take(pool, fr_x[0, 0], axis=0), "x",
+                            fwd_x)
+                        pool = jnp.concatenate([pool, recv_x], axis=0)
+                    pool = self._append_zero_row(pool)
 
-                # ---- 4. slab replay in global order ----
-                weighted = jnp.take(pool, rp_index[0, 0], axis=0)
+                    # ---- 4. slab replay in global order ----
+                    weighted = jnp.take(pool, rp_index[0, 0], axis=0)
                 return replay(weighted, rp_valid[0, 0], rp_starts[0, 0])
 
             in_specs = (
@@ -1177,36 +1186,41 @@ class ShardedEngine:
                 act, outstack = carry
                 # predecessor's activation from the PREVIOUS tick — the
                 # recv overlaps this tick's stage compute
-                recv = lax.ppermute(act, "pipe", fwd)
+                with jax.named_scope("collective"):
+                    recv = lax.ppermute(act, "pipe", fwd)
                 # stage 0 feeds the next micro-batch (clamped during
                 # drain: the repeats are masked out below)
                 i0 = jnp.clip(t, 0, T - 1) * B
                 s_in = lax.dynamic_slice(in_starts, (i0, 0), (B, 3))
-                x0 = entry(gather(chunk_like, s_in))
-                x = jnp.where(s == 0, x0, recv)
-                new_act = lax.switch(s, stage_fns, params, x)
-                # every chip runs the tail SPMD-uniformly; only the last
-                # stage's (post-warmup) result is kept
-                out = tail(params, new_act)
+                patches = gather(chunk_like, s_in)
+                with jax.named_scope("forward"):
+                    x0 = entry(patches)
+                    x = jnp.where(s == 0, x0, recv)
+                    new_act = lax.switch(s, stage_fns, params, x)
+                    # every chip runs the tail SPMD-uniformly; only the
+                    # last stage's (post-warmup) result is kept
+                    out = tail(params, new_act)
                 mb_out = jnp.clip(t - (S - 1), 0, T - 1)
                 o0 = mb_out * B
-                v = lax.dynamic_slice(valid, (o0,), (B,))
-                weighted = (out * bump[None, None]
-                            * v[:, None, None, None, None])
-                cur = lax.dynamic_slice(
-                    outstack, (o0, 0, 0, 0, 0), (B, co) + pout)
-                keep = jnp.logical_and(s == S - 1, t >= S - 1)
-                outstack = lax.dynamic_update_slice(
-                    outstack, jnp.where(keep, weighted, cur),
-                    (o0, 0, 0, 0, 0))
+                with jax.named_scope("accumulate"):
+                    v = lax.dynamic_slice(valid, (o0,), (B,))
+                    weighted = (out * bump[None, None]
+                                * v[:, None, None, None, None])
+                    cur = lax.dynamic_slice(
+                        outstack, (o0, 0, 0, 0, 0), (B, co) + pout)
+                    keep = jnp.logical_and(s == S - 1, t >= S - 1)
+                    outstack = lax.dynamic_update_slice(
+                        outstack, jnp.where(keep, weighted, cur),
+                        (o0, 0, 0, 0, 0))
                 return (new_act, outstack), None
 
             (_, outstack), _ = lax.scan(
                 tick, (act0, outstack0), jnp.arange(T + S - 1)
             )
             # drain collect: the last stage holds the only real stack
-            gathered = lax.all_gather(outstack, "pipe", axis=0)
-            return gathered[S - 1]
+            with jax.named_scope("collective"):
+                gathered = lax.all_gather(outstack, "pipe", axis=0)
+                return gathered[S - 1]
 
         if plan is None:
             def device_fn(chunk, in_starts, out_starts, valid, params):
@@ -1219,8 +1233,9 @@ class ShardedEngine:
             def device_fn(chunk, in_starts, valid,
                           rp_index, rp_starts, rp_valid, params):
                 stack = weighted_stack(chunk, in_starts, valid, params)
-                pool = self._append_zero_row(stack)
-                weighted = jnp.take(pool, rp_index[0], axis=0)
+                with jax.named_scope("collective"):
+                    pool = self._append_zero_row(stack)
+                    weighted = jnp.take(pool, rp_index[0], axis=0)
                 return replay(weighted, rp_valid[0], rp_starts[0])
 
             in_specs = (P(), P(), P(),
@@ -1281,9 +1296,11 @@ class ShardedEngine:
             def device_fn(patches, valid, params):
                 # the same weighting expression, in the same order, as
                 # the fused program's forward_batch (ops/blend.py)
-                preds = forward(params, patches)
-                return (preds * bump[None, None]
-                        * valid[:, None, None, None, None])
+                with jax.named_scope("forward"):
+                    preds = forward(params, patches)
+                with jax.named_scope("accumulate"):
+                    return (preds * bump[None, None]
+                            * valid[:, None, None, None, None])
 
             sharded = shard_map(
                 device_fn,
@@ -1334,29 +1351,34 @@ class ShardedEngine:
 
                 def tick(carry, t):
                     act, outstack = carry
-                    recv = lax.ppermute(act, "pipe", fwd)
+                    with jax.named_scope("collective"):
+                        recv = lax.ppermute(act, "pipe", fwd)
                     i0 = jnp.clip(t, 0, T - 1) * B
-                    x0 = entry(lax.dynamic_slice(
-                        patches, (i0, 0, 0, 0, 0), (B, ci) + pin))
-                    x = jnp.where(s == 0, x0, recv)
-                    new_act = lax.switch(s, stage_fns, params, x)
-                    out = tail(params, new_act)
+                    with jax.named_scope("forward"):
+                        x0 = entry(lax.dynamic_slice(
+                            patches, (i0, 0, 0, 0, 0), (B, ci) + pin))
+                        x = jnp.where(s == 0, x0, recv)
+                        new_act = lax.switch(s, stage_fns, params, x)
+                        out = tail(params, new_act)
                     o0 = jnp.clip(t - (S - 1), 0, T - 1) * B
-                    v = lax.dynamic_slice(valid, (o0,), (B,))
-                    weighted = (out * bump[None, None]
-                                * v[:, None, None, None, None])
-                    cur = lax.dynamic_slice(
-                        outstack, (o0, 0, 0, 0, 0), (B, co) + pout)
-                    keep = jnp.logical_and(s == S - 1, t >= S - 1)
-                    outstack = lax.dynamic_update_slice(
-                        outstack, jnp.where(keep, weighted, cur),
-                        (o0, 0, 0, 0, 0))
+                    with jax.named_scope("accumulate"):
+                        v = lax.dynamic_slice(valid, (o0,), (B,))
+                        weighted = (out * bump[None, None]
+                                    * v[:, None, None, None, None])
+                        cur = lax.dynamic_slice(
+                            outstack, (o0, 0, 0, 0, 0), (B, co) + pout)
+                        keep = jnp.logical_and(s == S - 1, t >= S - 1)
+                        outstack = lax.dynamic_update_slice(
+                            outstack, jnp.where(keep, weighted, cur),
+                            (o0, 0, 0, 0, 0))
                     return (new_act, outstack), None
 
                 (_, outstack), _ = lax.scan(
                     tick, (act0, outstack0), jnp.arange(T + S - 1)
                 )
-                return lax.all_gather(outstack, "pipe", axis=0)[S - 1]
+                with jax.named_scope("collective"):
+                    return lax.all_gather(outstack, "pipe",
+                                          axis=0)[S - 1]
 
             sharded = shard_map(
                 device_fn,
@@ -1425,17 +1447,15 @@ class ShardedEngine:
     def _note_collectives(self, key, halo_bytes: float,
                           gather_bytes: float,
                           replay_strip_bytes: float = 0.0,
-                          handoff_bytes: float = 0.0,
-                          flops=None) -> None:
+                          handoff_bytes: float = 0.0) -> None:
         """Stamp this dispatch's analytic cross-chip traffic (see module
-        docstring): counters + per-family ledger bucket + the derived
-        collective-vs-compute split gauges. Four analytic planes (ISSUE
-        19 extends the original two): input halos, weighted-stack
-        gathers, sharded-replay fringe strips (``ppermute`` of the
-        boundary-crossing windows) and pipeline stage handoffs (the
-        activation ring). ``flops`` is the program's cost-analysis
-        figure when the ledger has one — without it the split is
-        meaningless and only the byte planes are emitted."""
+        docstring): counters + per-family ledger bucket. Four analytic
+        planes (ISSUE 19 extends the original two): input halos,
+        weighted-stack gathers, sharded-replay fringe strips
+        (``ppermute`` of the boundary-crossing windows) and pipeline
+        stage handoffs (the activation ring). Bytes only: what the
+        exchanges cost in time is read from a device trace (the ops
+        under the ``collective`` scope), not reckoned from bytes."""
         if not telemetry.enabled():
             return
         if halo_bytes > 0:
@@ -1459,13 +1479,6 @@ class ShardedEngine:
                  + float(replay_strip_bytes) + float(handoff_bytes))
         if total > 0:
             profiling.note_collective(total, key=key, label="sharded")
-        if flops:
-            split = profiling.estimate_collective_split(flops, total)
-            telemetry.gauge("shard/compute_s_est", split["compute_s"])
-            telemetry.gauge("shard/collective_s_est",
-                            split["collective_s"])
-            telemetry.gauge("shard/collective_share_est",
-                            split["collective_share"])
 
     def _replay_buffer_gauges(self, z: int, buf_y: int, buf_x: int,
                               n_chips: int) -> None:
@@ -1621,7 +1634,6 @@ class ShardedEngine:
             shard_bytes = rows * self.num_output_channels * pvox * 4
             self._note_collectives(
                 program_key, 0.0, float(n_dev * (n_dev - 1) * shard_bytes),
-                flops=_program_flops(program),
             )
             if plan is not None:
                 self._replay_buffer_gauges(
@@ -1689,7 +1701,6 @@ class ShardedEngine:
             self._note_collectives(
                 program_key, 0.0, float(S * (S - 1) * stack_bytes),
                 handoff_bytes=handoff_bytes,
-                flops=_program_flops(program),
             )
             if plan is not None:
                 self._replay_buffer_gauges(
@@ -1778,7 +1789,6 @@ class ShardedEngine:
         self._note_collectives(
             program_key, halo_bytes, gather_bytes,
             replay_strip_bytes=strip_bytes,
-            flops=_program_flops(program),
         )
         if plan is not None:
             self._replay_buffer_gauges(

@@ -47,6 +47,7 @@ disk) still work: they unwrap to themselves with no trace id.
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import threading
@@ -89,9 +90,39 @@ def unpack_task(raw: str) -> Tuple[str, Optional[str]]:
     return raw, None
 
 
+def _fetch_span(receive):
+    """``queue/fetch`` around a backend's :meth:`QueueBase.receive`: the
+    span cannot know its task when it starts, so it takes the delivery's
+    trace id once the claim is in hand. A poll that finds the queue
+    empty fetched nothing and records nothing: an idle worker polls many
+    times a second."""
+    @functools.wraps(receive)
+    def spanned(self):
+        with telemetry.span("queue/fetch") as sp:
+            item = receive(self)
+            if item is None:
+                sp.cancel()
+            else:
+                sp.bind(self.trace_id(item[0]))
+        return item
+    return spanned
+
+
+def _ack_span(delete):
+    """``queue/ack`` around a backend's :meth:`QueueBase.delete`, under
+    the task the handle was delivered for."""
+    @functools.wraps(delete)
+    def spanned(self, handle):
+        with telemetry.task_context(self.trace_id(handle)), \
+                telemetry.span("queue/ack"):
+            return delete(self, handle)
+    return spanned
+
+
 class QueueBase:
     """handle/body iteration + ack/lease/dead-letter protocol shared by
-    all backends."""
+    all backends. A backend decorates its ``receive`` with
+    :func:`_fetch_span` and its ``delete`` with :func:`_ack_span`."""
 
     visibility_timeout: float = 1800.0
 
@@ -311,6 +342,7 @@ class MemoryQueue(QueueBase):
             body, _ = self.invisible.pop(h)
             self.pending[h] = body
 
+    @_fetch_span
     def receive(self) -> Optional[Tuple[str, str]]:
         with self._lock:
             self._requeue_expired()
@@ -326,6 +358,7 @@ class MemoryQueue(QueueBase):
         self._note_receive(handle, trace_id)
         return handle, body
 
+    @_ack_span
     def delete(self, handle: str) -> None:
         with self._lock:
             self.invisible.pop(handle, None)
@@ -488,6 +521,7 @@ class FileQueue(QueueBase):
         except (OSError, ValueError):
             return 0
 
+    @_fetch_span
     def receive(self) -> Optional[Tuple[str, str]]:
         self._requeue_expired()
         for name in sorted(os.listdir(self.pending_dir)):
@@ -505,6 +539,7 @@ class FileQueue(QueueBase):
             return name, body
         return None
 
+    @_ack_span
     def delete(self, handle: str) -> None:
         for path in (os.path.join(self.claimed_dir, handle),
                      os.path.join(self.counts_dir, handle)):
@@ -677,6 +712,7 @@ class SQSQueue(QueueBase):
             ]
             self._send_batch(entries)
 
+    @_fetch_span
     def receive(self) -> Optional[Tuple[str, str]]:
         resp = self.client.receive_message(
             QueueUrl=self.queue_url, MaxNumberOfMessages=1,
@@ -710,6 +746,7 @@ class SQSQueue(QueueBase):
         self._note_receive(handle, trace_id)
         return handle, body
 
+    @_ack_span
     def delete(self, handle: str) -> None:
         self.client.delete_message(QueueUrl=self.queue_url, ReceiptHandle=handle)
         self._receive_counts.pop(handle, None)

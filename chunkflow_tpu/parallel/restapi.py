@@ -28,6 +28,7 @@ all** under ``CHUNKFLOW_TELEMETRY=0``.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import re
@@ -230,6 +231,15 @@ class CoordinationService:
         self._claimed: dict = {}
 
     # ---- request handling (transport-independent) ----------------------
+    def exchange(self, method: str, path: str):
+        """Context manager around one HTTP exchange, from before the
+        body is read to after the response is written. Its ``phase(name)``
+        gives a context manager for the listener's own steps, ``encode``
+        (payload -> JSON bytes) and ``send`` (the socket write). Nothing
+        is recorded here; the serving front-end overrides this to time
+        ``POST /infer`` (chunkflow_tpu/serve/frontend.py)."""
+        return _PlainExchange()
+
     def handle(self, method: str, path: str, body: Optional[bytes] = None):
         """Returns (status, payload): a dict serves as JSON, a str as
         ``text/plain`` (the Prometheus exposition), None as empty.
@@ -325,6 +335,19 @@ class CoordinationService:
                      "worker": telemetry.worker_id()}
 
 
+class _PlainExchange:
+    """The exchange nobody times (:meth:`CoordinationService.exchange`)."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def phase(self, name: str):
+        return contextlib.nullcontext()
+
+
 def serve(
     service: CoordinationService,
     host: str = "0.0.0.0",
@@ -335,22 +358,28 @@ def serve(
     thread) for tests."""
 
     class Handler(BaseHTTPRequestHandler):
-        def _respond(self, body: Optional[bytes] = None):
-            status, payload = service.handle(self.command, self.path,
-                                             body)
-            self.send_response(status)
-            if isinstance(payload, str):
-                # raw text route (/metrics: Prometheus exposition 0.0.4)
-                self.send_header(
-                    "Content-Type", "text/plain; version=0.0.4"
-                )
+        def _respond(self, length: int = 0):
+            with service.exchange(self.command, self.path) as exchange:
+                body = self.rfile.read(length) if length else None
+                status, payload = service.handle(self.command, self.path,
+                                                 body)
+                self.send_response(status)
+                if isinstance(payload, str):
+                    # raw text route (/metrics: Prometheus exposition
+                    # 0.0.4)
+                    self.send_header(
+                        "Content-Type", "text/plain; version=0.0.4"
+                    )
+                    self.end_headers()
+                    self.wfile.write(payload.encode())
+                    return
+                self.send_header("Content-Type", "application/json")
                 self.end_headers()
-                self.wfile.write(payload.encode())
-                return
-            self.send_header("Content-Type", "application/json")
-            self.end_headers()
-            if payload is not None:
-                self.wfile.write(json.dumps(payload).encode())
+                if payload is not None:
+                    with exchange.phase("encode"):
+                        data = json.dumps(payload).encode()
+                    with exchange.phase("send"):
+                        self.wfile.write(data)
 
         def do_GET(self):
             self._respond()
@@ -360,7 +389,7 @@ def serve(
                 length = int(self.headers.get("Content-Length") or 0)
             except ValueError:
                 length = 0
-            self._respond(self.rfile.read(length) if length else None)
+            self._respond(length)
 
         def log_message(self, *args):  # quiet
             pass

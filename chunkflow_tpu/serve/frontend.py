@@ -49,6 +49,7 @@ from __future__ import annotations
 
 import base64
 import binascii
+import contextlib
 import json
 import os
 import threading
@@ -60,6 +61,7 @@ import numpy as np
 
 from chunkflow_tpu.chunk.base import Chunk
 from chunkflow_tpu.core import telemetry
+from chunkflow_tpu.parallel.queues import new_trace_id, pack_task
 from chunkflow_tpu.parallel.restapi import CoordinationService, serve
 from chunkflow_tpu.serve.packer import PatchPacker, RequestExpired
 from chunkflow_tpu.testing import chaos
@@ -159,11 +161,15 @@ class ServingRequest:
     once no matter how many parties race to report it."""
 
     def __init__(self, chunk: Chunk, deadline: float,
-                 req_id: Optional[str] = None):
+                 req_id: Optional[str] = None,
+                 trace_id: Optional[str] = None):
         self.chunk = chunk
         self.deadline = deadline
         self.req_id = req_id or uuid.uuid4().hex
-        self.trace_id: Optional[str] = None
+        # minted by the front-end when the request arrives and carried
+        # in the queue's envelope, so every span of the request, on the
+        # handler thread and on the worker that claims it, shares it
+        self.trace_id = trace_id
         self.submitted_t = time.time()
         self._event = threading.Event()
         self._lock = threading.Lock()
@@ -259,7 +265,8 @@ class LocalBackend:
     def submit(self, record: ServingRequest) -> None:
         with self._table_lock:
             self._table[record.req_id] = record
-        self.queue.send_messages([record.req_id])
+        self.queue.send_messages(
+            [pack_task(record.req_id, record.trace_id)])
 
     def wait(self, record: ServingRequest, timeout: float) -> Chunk:
         try:
@@ -306,6 +313,8 @@ class LocalBackend:
                 out = self.packer.infer(
                     record.chunk, deadline=record.deadline,
                     timeout=max(0.05, record.deadline - time.time()) + 5.0,
+                    trace_id=lc.trace_id,
+                    queued_since=record.submitted_t,
                 )
             except RequestExpired as exc:
                 # not a compute failure: drop the claim cleanly (ack —
@@ -382,7 +391,7 @@ class SpoolBackend:
             self._inflight[body] = record
         record.req_id = body
         record.chunk.to_h5(self.in_dir + os.sep)
-        self.queue.send_messages([body])
+        self.queue.send_messages([pack_task(body, record.trace_id)])
 
     def wait(self, record: ServingRequest, timeout: float) -> Chunk:
         body = record.req_id
@@ -422,6 +431,28 @@ class SpoolBackend:
 # ---------------------------------------------------------------------------
 # HTTP service
 # ---------------------------------------------------------------------------
+class _InferExchange:
+    """One ``POST /infer`` exchange on the listener's handler thread:
+    mints the request's ``trace_id`` and holds ``serving/http`` open from
+    before the body is read to after the response is written. Inside it
+    lie ``serving/decode`` (JSON + base64 -> array), ``serving/request``
+    (admission to result), ``serving/encode`` (array -> base64, then
+    payload -> JSON bytes: two spans of one name) and ``serving/send``
+    (the socket write); ``serving/queue`` is the worker side's."""
+
+    def __enter__(self):
+        self._stack = contextlib.ExitStack()
+        self._stack.enter_context(telemetry.task_context(new_trace_id()))
+        self._stack.enter_context(telemetry.span("serving/http"))
+        return self
+
+    def __exit__(self, *exc):
+        return self._stack.__exit__(*exc)
+
+    def phase(self, name: str):
+        return telemetry.span(f"serving/{name}")
+
+
 class ServingService(CoordinationService):
     """``POST /infer`` + ``GET /serving`` riding the coordination
     service's handler (so ``/metrics``, ``/healthz`` and ``/profile``
@@ -444,6 +475,11 @@ class ServingService(CoordinationService):
         # loads of overlapping regions hit host memory, not the store
         self._volumes: dict = {}
         self._volumes_lock = threading.Lock()
+
+    def exchange(self, method: str, path: str):
+        if method == "POST" and path == "/infer":
+            return _InferExchange()
+        return super().exchange(method, path)
 
     def handle(self, method: str, path: str, body: Optional[bytes] = None):
         if method == "POST" and path == "/infer":
@@ -618,11 +654,19 @@ class ServingService(CoordinationService):
         }
 
     def _handle_infer(self, body: Optional[bytes]):
+        # the listener's exchange made the request's id (_InferExchange);
+        # a caller that drives handle() directly gets one here
+        trace_id = telemetry.current_trace_id() or new_trace_id()
+        with telemetry.task_context(trace_id):
+            return self._infer(body, trace_id)
+
+    def _infer(self, body: Optional[bytes], trace_id: str):
         telemetry.inc("serving/requests")
         t0 = time.time()
         try:
-            payload = self._parse_request(body)
-            chunk = self._decode_chunk(payload)
+            with telemetry.span("serving/decode"):
+                payload = self._parse_request(body)
+                chunk = self._decode_chunk(payload)
         except ValueError as exc:
             telemetry.inc("serving/errors")
             return 400, {"error": str(exc)}
@@ -642,7 +686,8 @@ class ServingService(CoordinationService):
         except AdmissionRejected as exc:
             return 429, {"error": str(exc), "reason": exc.reason,
                          "retry_after_s": 0.5}
-        record = ServingRequest(chunk, deadline=t0 + deadline_s)
+        record = ServingRequest(chunk, deadline=t0 + deadline_s,
+                                trace_id=trace_id)
         try:
             with telemetry.span("serving/request"):
                 try:
@@ -662,7 +707,8 @@ class ServingService(CoordinationService):
                                  "trace_id": record.trace_id}
             latency = time.time() - t0
             telemetry.observe_quantile("serving/latency", latency)
-            response = self._encode_chunk(result)
+            with telemetry.span("serving/encode"):
+                response = self._encode_chunk(result)
             response["trace_id"] = record.trace_id
             response["latency_s"] = round(latency, 6)
             return 200, response

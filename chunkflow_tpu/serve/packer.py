@@ -148,15 +148,21 @@ class _Request:
         "chunk", "handle", "deadline", "trace_id", "orig_zyx", "run_zyx",
         "n", "n_pad", "in_starts", "out_starts", "valid", "patches",
         "device_chunk", "weighted", "remaining", "lock", "enqueued_t",
+        "queued_since",
     )
 
-    def __init__(self, chunk, handle, deadline, trace_id):
+    def __init__(self, chunk, handle, deadline, trace_id,
+                 queued_since=None):
         self.chunk = chunk
         self.handle = handle
         self.deadline = deadline
         self.trace_id = trace_id
         self.lock = threading.Lock()
         self.enqueued_t = time.time()
+        # start of the request's serving/queue span (its admission, when
+        # the front-end hands that in); None once the span is recorded
+        self.queued_since = (self.enqueued_t if queued_since is None
+                             else queued_since)
 
     @property
     def expired(self) -> bool:
@@ -238,11 +244,15 @@ class PatchPacker:
 
     # -- submission -----------------------------------------------------
     def submit(self, chunk: Chunk, deadline: Optional[float] = None,
-               trace_id: Optional[str] = None) -> PendingResult:
+               trace_id: Optional[str] = None,
+               queued_since: Optional[float] = None) -> PendingResult:
         """Queue one request's patches for packed execution; returns a
         :class:`PendingResult`. ``deadline`` is an absolute ``time.time``
         deadline: patches still queued past it are dropped and the
-        request fails with :class:`RequestExpired`. Ineligible requests
+        request fails with :class:`RequestExpired`. ``queued_since``
+        (``time.time()``, default now) is where the request's
+        ``serving/queue`` span starts; it ends at the first device batch
+        that holds one of the request's patches. Ineligible requests
         (kill switch, sharded, fold, dry-run) complete synchronously
         through the per-chunk path, bit-identically."""
         handle = PendingResult(trace_id)
@@ -261,7 +271,7 @@ class PatchPacker:
                 handle._fail(exc)
             return handle
 
-        req = _Request(chunk, handle, deadline, trace_id)
+        req = _Request(chunk, handle, deadline, trace_id, queued_since)
         try:
             self._prepare(req)
         except BaseException as exc:
@@ -293,9 +303,12 @@ class PatchPacker:
         return handle
 
     def infer(self, chunk: Chunk, deadline: Optional[float] = None,
-              timeout: Optional[float] = None) -> Chunk:
+              timeout: Optional[float] = None,
+              trace_id: Optional[str] = None,
+              queued_since: Optional[float] = None) -> Chunk:
         """Synchronous convenience wrapper around :meth:`submit`."""
-        return self.submit(chunk, deadline=deadline).result(timeout)
+        return self.submit(chunk, deadline=deadline, trace_id=trace_id,
+                           queued_since=queued_since).result(timeout)
 
     def _prepare(self, req: _Request) -> None:
         """Request prep: bucket padding, grid enumeration, provenance
@@ -439,11 +452,13 @@ class PatchPacker:
             bump = bump_const(tuple(inf.output_patch_size))
 
             def program(patches, valid, params):
-                preds = inf._forward(params, patches)
+                with jax.named_scope("forward"):
+                    preds = inf._forward(params, patches)
                 # the same weighting expression, in the same order, as
                 # the fused program's forward_batch (ops/blend.py)
-                return preds * bump[None, None] * \
-                    valid[:, None, None, None, None]
+                with jax.named_scope("accumulate"):
+                    return preds * bump[None, None] * \
+                        valid[:, None, None, None, None]
 
             # the packed batch buffer is packer-owned and dead after the
             # call (GL005): donate it into the program
@@ -478,8 +493,9 @@ class PatchPacker:
 
             def program(chunk_like, starts, rowmask, acc):
                 rows = gather(chunk_like, starts)
-                mask = rowmask[:, None, None, None, None]
-                return jnp.where(mask > 0, rows, acc)
+                with jax.named_scope("gather"):
+                    mask = rowmask[:, None, None, None, None]
+                    return jnp.where(mask > 0, rows, acc)
 
             # acc is packer-owned and dead after the call (GL005); the
             # resident chunk is NOT donated — later batches gather from it
@@ -510,7 +526,8 @@ class PatchPacker:
             import jax
 
             def program(weighted, rows, idx):
-                return weighted.at[idx].set(rows)
+                with jax.named_scope("accumulate"):
+                    return weighted.at[idx].set(rows)
 
             # the stack is packer-owned and replaced in place across
             # batches (GL005): donate it into each overlay. ``rows`` is
@@ -551,8 +568,9 @@ class PatchPacker:
             num_batches = n_pad // B
 
             def program(weighted, valid, out_starts):
-                out0 = jnp.zeros((co,) + zyx_buf, dtype=jnp.float32)
-                w0 = jnp.zeros(zyx_buf, dtype=jnp.float32)
+                with jax.named_scope("accumulate"):
+                    out0 = jnp.zeros((co,) + zyx_buf, dtype=jnp.float32)
+                    w0 = jnp.zeros(zyx_buf, dtype=jnp.float32)
 
                 def step(carry, b):
                     out, weight = carry
@@ -569,8 +587,9 @@ class PatchPacker:
                     step, (out0, w0), jnp.arange(num_batches)
                 )
                 if pad_y or pad_x:
-                    out = out[:, :, : run_zyx[1], : run_zyx[2]]
-                    weight = weight[:, : run_zyx[1], : run_zyx[2]]
+                    with jax.named_scope("accumulate"):
+                        out = out[:, :, : run_zyx[1], : run_zyx[2]]
+                        weight = weight[:, : run_zyx[1], : run_zyx[2]]
                 return normalize_blend(out, weight, out_dtype)
 
             # the assembled weighted stack is packer-owned and dead
@@ -617,6 +636,12 @@ class PatchPacker:
                     f"patches still queued"))
                 continue
             telemetry.observe("serving/queue_age", now - enq_t)
+            if req.queued_since is not None:
+                # the first batch that holds one of its patches: the
+                # request stops waiting for the device here
+                telemetry.record_span("serving/queue", req.queued_since,
+                                      trace_id=req.trace_id)
+                req.queued_since = None
             live.append(item)
         if not live:
             return

@@ -30,6 +30,7 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from chunkflow_tpu.chunk.base import Chunk, LayerType, as_native_dtype
+from chunkflow_tpu.core import telemetry
 from chunkflow_tpu.core.bbox import BoundingBox
 from chunkflow_tpu.core.cartesian import Cartesian, to_cartesian
 from chunkflow_tpu.volume.storage import (
@@ -212,8 +213,11 @@ class PrecomputedVolume:
             arr = serial_cutout(backend, lo, hi)
         else:
             arr = blockwise_cutout(backend, lo, hi, cache=shared_cache())
-        # xyzc -> czyx
-        arr = np.ascontiguousarray(np.transpose(arr, (3, 2, 1, 0)))
+        # the storage layout turned into the program's: a host copy of
+        # the whole cutout (the file system's part is storage/read)
+        with telemetry.span("storage/decode"):
+            # xyzc -> czyx
+            arr = np.ascontiguousarray(np.transpose(arr, (3, 2, 1, 0)))
         if arr.shape[0] == 1:
             arr = arr[0]
         return Chunk(

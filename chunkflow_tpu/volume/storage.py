@@ -60,6 +60,7 @@ stale zeros (docs/storage.md "Invalidation semantics").
 from __future__ import annotations
 
 import abc
+import contextvars
 import itertools
 import os
 import threading
@@ -724,14 +725,16 @@ class KVArrayBackend(StorageBackend):
                 tuple(h - l for l, h in zip(blo, bhi)),
                 self._fill, dtype=self._dtype,
             )
-        return np.load(io.BytesIO(data), allow_pickle=False)
+        with telemetry.span("storage/decode"):
+            return np.load(io.BytesIO(data), allow_pickle=False)
 
     def _write_block(self, blo, bhi, arr: np.ndarray) -> None:
         import io
 
-        buf = io.BytesIO()
-        np.save(buf, np.ascontiguousarray(arr, dtype=self._dtype),
-                allow_pickle=False)
+        with telemetry.span("storage/encode"):
+            buf = io.BytesIO()
+            np.save(buf, np.ascontiguousarray(arr, dtype=self._dtype),
+                    allow_pickle=False)
         self._kv.write_bytes(self._block_key(blo, bhi), buf.getvalue())
 
     def _read(self, lo, hi) -> np.ndarray:
@@ -769,11 +772,16 @@ class KVArrayBackend(StorageBackend):
             )] = arr[sel]
             self._write_block(blo, bhi, block)
 
+    # the pool threads run under a copy of the caller's context, so the
+    # storage/decode and storage/encode spans keep the caller's
+    # storage/read or storage/write span as parent, and its task
     def read_async(self, lo, hi):
-        return self._pool.submit(self._read, tuple(lo), tuple(hi))
+        return self._pool.submit(contextvars.copy_context().run,
+                                 self._read, tuple(lo), tuple(hi))
 
     def write_async(self, lo, hi, arr):
-        return self._pool.submit(self._write, tuple(lo), tuple(hi), arr)
+        return self._pool.submit(contextvars.copy_context().run,
+                                 self._write, tuple(lo), tuple(hi), arr)
 
     def close(self) -> None:
         self._pool.shutdown(wait=False)

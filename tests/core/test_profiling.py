@@ -447,3 +447,200 @@ def test_background_capture_thread_is_joined_at_exit(clean_plane, tmp_path,
     assert err is None and target
     profiling.wait_for_captures(10)
     assert started == [False]
+
+
+# ---------------------------------------------------------------------------
+# device scopes, and captures that yield (ISSUE 23)
+# ---------------------------------------------------------------------------
+_HLO = """\
+HloModule jit_program, is_scheduled=true
+
+%fused_computation (p: f32[4]) -> f32[4] {
+  %p = f32[4]{0} parameter(0)
+  ROOT %inside.1 = f32[4]{0} negate(f32[4]{0} %p), metadata={op_name="jit(program)/forward/neg"}
+}
+
+%scatter_body (arg: (s32[], f32[4])) -> (s32[], f32[4]) {
+  %arg = (s32[], f32[4]{0}) parameter(0)
+  %get-tuple-element.1 = f32[4]{0} get-tuple-element((s32[], f32[4]{0}) %arg), index=1
+  %dynamic-update-slice.1 = f32[4]{0} dynamic-update-slice(f32[4]{0} %get-tuple-element.1, f32[4]{0} %get-tuple-element.1, s32[] %c)
+  ROOT %tuple.1 = (s32[], f32[4]{0}) tuple(s32[] %c, f32[4]{0} %dynamic-update-slice.1)
+}
+
+%scatter_cond (arg: (s32[], f32[4])) -> pred[] {
+  ROOT %compare.1 = pred[] compare(s32[] %a, s32[] %b), direction=LT
+}
+
+%on_true (t: f32[4]) -> f32[4] {
+  ROOT %copy.9 = f32[4]{0} copy(f32[4]{0} %t)
+}
+
+ENTRY %main.1 (x: f32[4]) -> f32[4] {
+  %x = f32[4]{0} parameter(0)
+  %fusion.1 = f32[4]{0} fusion(f32[4]{0} %x), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(program)/while/body/forward/RSUNet/gather/conv" stack_frame_id=3}
+  %copy.1 = f32[4]{0:T(128)S(1)} copy(f32[4]{0} %fusion.1)
+  %while.1 = (s32[], f32[4]{0}) while((s32[], f32[4]{0}) %t), condition=%scatter_cond, body=%scatter_body, metadata={op_name="jit(program)/accumulate/scatter-add"}
+  %conditional.1 = f32[4]{0} conditional(s32[] %i, f32[4]{0} %x, f32[4]{0} %x), branch_computations={%on_true, %on_true}, metadata={op_name="jit(program)/normalize/cond"}
+  %gather.1 = f32[4]{0} gather(f32[4]{0} %x, s32[1]{0} %i), metadata={op_name="jit(program)/gather"}
+  ROOT %add.1 = f32[4]{0} add(f32[4]{0} %copy.1, f32[4]{0} %gather.1), metadata={op_name="jit(program)/add"}
+}
+"""
+
+
+def test_op_scopes_outermost_scope_and_inheritance_through_calls():
+    scopes = profiling.op_scopes(_HLO)
+    # the outermost scope name on the path wins: a flax module called
+    # `gather` inside the model does not move an op out of `forward`
+    assert scopes["forward"] == ["fusion.1"]
+    # a while's body and condition ops carry no metadata: they take the
+    # scope of the while that calls them, as a branch takes its
+    # conditional's; free ops (tuple, get-tuple-element) are left out
+    assert sorted(scopes["accumulate"]) == [
+        "compare.1", "dynamic-update-slice.1", "while.1"]
+    assert sorted(scopes["normalize"]) == ["conditional.1", "copy.9"]
+    # no metadata, or metadata under none of the scopes: listed under "";
+    # a path's last component is the primitive (lax.gather), not a scope
+    assert sorted(scopes[""]) == ["add.1", "copy.1", "gather.1"]
+    assert "gather" not in scopes
+    # ops inside a fusion are not events of their own
+    assert not any("inside.1" in ops for ops in scopes.values())
+    assert set(scopes) <= set(profiling.DEVICE_SCOPES) | {""}
+
+
+def test_programs_json_carries_op_scopes_only_with_a_sink(clean_plane,
+                                                          tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    def build():
+        def program(x):
+            with jax.named_scope("forward"):
+                y = jnp.tanh(x) * 2.0
+            with jax.named_scope("normalize"):
+                return y / jnp.maximum(y.sum(), 1.0)
+        return jax.jit(program)
+
+    x = jnp.ones((8, 128), jnp.float32)
+    ProgramCache(label="bare").get(("bare",), build)(x)
+    assert profiling.catalog()[0]["op_scopes"] is None   # nobody reads it
+    telemetry.reset()
+
+    telemetry.configure(str(tmp_path))
+    ProgramCache(label="scoped").get(("scoped",), build)(x)
+    (entry,) = profiling.catalog()
+    assert entry["op_scopes"]["forward"] and entry["op_scopes"]["normalize"]
+    telemetry.flush()
+    payload = json.loads((tmp_path / "programs.json").read_text())
+    assert payload["programs"][0]["op_scopes"] == entry["op_scopes"]
+    # the JSONL stream gets the ledger without the per-op lists
+    with open(telemetry.configured_path()) as f:
+        streamed = [json.loads(line) for line in f
+                    if '"kind": "programs"' in line]
+    assert streamed and "op_scopes" not in streamed[0]["programs"][0]
+
+
+def test_automatic_capture_yields_to_a_session_it_did_not_start(
+        clean_plane, tmp_path):
+    """A harness's (or an operator's) jax.profiler session is running:
+    an anomaly capture does not start, and says `capture_skipped`, not
+    `capture_error`; an operator's request is refused with a reason."""
+    import jax
+
+    # the suite's conftest turns anomaly captures off; this test is
+    # about them
+    clean_plane.setenv("CHUNKFLOW_PROFILE_ON_ANOMALY", "1")
+    telemetry.configure(str(tmp_path / "metrics"))
+    jax.profiler.start_trace(str(tmp_path / "theirs"))
+    try:
+        assert profiling.maybe_capture("stall-scheduler-load") is False
+        target, why = profiling.capture(0.05, "operator", force=True)
+        assert target is None and "already active" in why
+        assert profiling.start_task_window(str(tmp_path / "w")) is None
+    finally:
+        jax.profiler.stop_trace()
+    profiling.wait_for_captures()
+    telemetry.flush()
+    with open(telemetry.configured_path()) as f:
+        events = [json.loads(line) for line in f if line.strip()]
+    skipped = [e for e in events if e.get("name") == "profile/capture_skipped"]
+    assert len(skipped) == 1 and "already active" in skipped[0]["why"]
+    assert not [e for e in events
+                if e.get("name") == "profile/capture_error"]
+    assert "profile/capture_errors" not in telemetry.snapshot()["counters"]
+    assert not glob.glob(str(tmp_path / "metrics" / "profile-*"))
+    # with their session over, the next anomaly is captured
+    clean_plane.setenv("CHUNKFLOW_PROFILE_SECONDS", "0.05")
+    assert profiling.maybe_capture("stall-scheduler-load") is True
+    profiling.wait_for_captures()
+    assert glob.glob(str(tmp_path / "metrics" / "profile-stall-*"))
+
+
+@pytest.mark.parametrize("phase", profiling.DEVICE_PACED_PHASES)
+def test_a_host_that_waits_for_the_device_is_no_anomaly(clean_plane, phase):
+    """pipeline/dispatch and pipeline/compute dominating is the healthy
+    state of a device-bound worker: never a capture, and it breaks a
+    streak of a real stall like a dip does."""
+    captured = []
+    clean_plane.setattr(profiling, "maybe_capture",
+                        lambda reason: captured.append(reason) or True)
+    clean_plane.setenv("CHUNKFLOW_PROFILE_STALL_TICKS", "3")
+    for _ in range(6):
+        profiling.note_stall(phase, 0.99)
+    assert captured == []
+    profiling.note_stall("scheduler/load", 0.9)
+    profiling.note_stall("scheduler/load", 0.9)
+    profiling.note_stall(phase, 0.99)
+    profiling.note_stall("scheduler/load", 0.9)
+    assert captured == []
+
+
+def test_op_scopes_survive_a_cache_entry_from_before_the_scopes(
+        clean_plane, tmp_path):
+    """JAX leaves metadata out of the compile-cache key: a checkout
+    without the scopes fills the cache, and the scoped program is then
+    handed that executable. The ledger notices (the lowered module names
+    scopes, the executable's none) and reads the map from one compile
+    past the cache."""
+    import contextlib
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def build():
+        def program(x):
+            with jax.named_scope("forward"):
+                y = jnp.tanh(x) * 2.0
+            with jax.named_scope("normalize"):
+                return y / jnp.maximum(y.sum(), 1.0)
+        return jax.jit(program)
+
+    x = jnp.ones((8, 128), jnp.float32)
+    saved = {name: getattr(jax.config, name) for name in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    compilation_cache.reset_cache()
+    try:
+        # the other checkout: the same program, no scopes, fills the cache
+        with clean_plane.context() as patch:
+            patch.setattr(jax, "named_scope",
+                          lambda name: contextlib.nullcontext())
+            build()(x).block_until_ready()
+        assert list((tmp_path / "cache").iterdir())
+        telemetry.configure(str(tmp_path / "metrics"))
+        ProgramCache(label="scoped").get(("scoped",), build)(x)
+        (entry,) = profiling.catalog()
+        assert entry["op_scopes"]["forward"]
+        assert entry["op_scopes"]["normalize"]
+        counters = telemetry.snapshot()["counters"]
+        assert counters["program/stale_cache_entries"] == 1
+        # the cache is on again afterwards
+        assert jax.config.jax_enable_compilation_cache
+    finally:
+        for name, value in saved.items():
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()

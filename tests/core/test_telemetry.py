@@ -511,3 +511,178 @@ def test_flush_takes_a_final_sample(tmp_path):
     telemetry.flush()
     events = [json.loads(line) for line in open(path)]
     assert any(e["kind"] == "timeseries" for e in events)
+
+
+# ---------------------------------------------------------------------------
+# the span record: start, ids, parent, thread (ISSUE 23)
+# ---------------------------------------------------------------------------
+def _span_events(path):
+    with open(path) as f:
+        events = [json.loads(line) for line in f if line.strip()]
+    return [e for e in events if e["kind"] == "span"]
+
+
+def test_nested_spans_carry_parent_id_and_the_task(tmp_path):
+    path = telemetry.configure(str(tmp_path))
+    with telemetry.task_context("task-1"):
+        with telemetry.span("op/save") as outer:
+            assert telemetry._SPAN_CTX.get() == outer.span_id
+            with telemetry.span("storage/write") as inner:
+                with telemetry.span("storage/encode"):
+                    pass
+            assert telemetry._SPAN_CTX.get() == outer.span_id
+    assert telemetry._SPAN_CTX.get() is None
+    by_name = {e["name"]: e for e in _span_events(path)}
+    assert by_name["op/save"]["parent_id"] is None
+    assert by_name["storage/write"]["parent_id"] == outer.span_id
+    assert by_name["storage/encode"]["parent_id"] == inner.span_id
+    assert {e["trace_id"] for e in by_name.values()} == {"task-1"}
+    assert len({e["span_id"] for e in by_name.values()}) == 3
+
+
+def test_span_in_a_pool_thread_keeps_the_submitting_span_as_parent(tmp_path):
+    import contextvars
+    from concurrent.futures import ThreadPoolExecutor
+
+    path = telemetry.configure(str(tmp_path))
+
+    def work(name):
+        with telemetry.span(name):
+            return threading.current_thread().name
+
+    with ThreadPoolExecutor(max_workers=1,
+                            thread_name_prefix="pooled") as pool:
+        with telemetry.task_context("task-2"), \
+                telemetry.span("storage/write") as parent:
+            # rebound: the pool thread runs under a copy of this context
+            thread = pool.submit(contextvars.copy_context().run, work,
+                                 "storage/encode").result(timeout=10)
+            # not rebound: contextvars do not follow work into a pool
+            pool.submit(work, "storage/orphan").result(timeout=10)
+    by_name = {e["name"]: e for e in _span_events(path)}
+    assert thread.startswith("pooled")
+    assert by_name["storage/encode"]["thread"] == thread
+    assert by_name["storage/encode"]["parent_id"] == parent.span_id
+    assert by_name["storage/encode"]["trace_id"] == "task-2"
+    assert by_name["storage/orphan"]["parent_id"] is None
+    assert "trace_id" not in by_name["storage/orphan"]
+    assert by_name["storage/write"]["thread"] == \
+        threading.current_thread().name
+
+
+def test_span_start_plus_duration_is_its_end_and_old_fields_stay(tmp_path):
+    import time
+
+    path = telemetry.configure(str(tmp_path))
+    with telemetry.task_context("task-3"), \
+            telemetry.span("pipeline/drain", chunk=7):
+        time.sleep(0.02)
+    (event,) = _span_events(path)
+    assert event["t0"] + event["dur_s"] == pytest.approx(event["t"],
+                                                         abs=0.005)
+    assert event["dur_s"] >= 0.02
+    # what benchmarks/cfbench/run_record.py and flow/log_summary.py read
+    assert {"kind", "name", "t", "dur_s", "pid", "worker", "trace_id",
+            "chunk"} <= set(event)
+    assert event["name"] == "pipeline/drain" and event["chunk"] == 7
+    assert event["worker"] == telemetry.worker_id()
+
+
+def test_a_span_takes_the_task_it_waited_for(tmp_path):
+    path = telemetry.configure(str(tmp_path))
+    with telemetry.task_context("enclosing"):
+        with telemetry.span("scheduler/load") as load:
+            load.bind("late-task")
+        with telemetry.span("scheduler/load") as empty:
+            empty.bind(None)        # nothing in hand: the context stands
+    with telemetry.span("queue/fetch") as poll:
+        poll.cancel()               # an empty poll records nothing
+    events = _span_events(path)
+    assert [e["trace_id"] for e in events] == ["late-task", "enclosing"]
+    assert telemetry.snapshot()["hists"]["scheduler/load"]["count"] == 2
+    assert "queue/fetch" not in telemetry.snapshot()["hists"]
+
+
+def test_record_span_covers_an_interval_across_threads(tmp_path):
+    import time
+
+    path = telemetry.configure(str(tmp_path))
+    t0 = time.time() - 0.25
+    done = threading.Thread(
+        target=telemetry.record_span,
+        args=("serving/queue", t0), kwargs={"trace_id": "req-1"})
+    done.start()
+    done.join(timeout=10)
+    assert not done.is_alive()
+    (event,) = _span_events(path)
+    assert event["name"] == "serving/queue" and event["trace_id"] == "req-1"
+    assert event["t0"] == t0 and event["dur_s"] >= 0.25
+    assert event["parent_id"] is None and event["span_id"] > 0
+    assert telemetry.snapshot()["hists"]["serving/queue"]["count"] == 1
+
+
+def test_kill_switch_span_is_the_shared_null_span(monkeypatch):
+    monkeypatch.setenv("CHUNKFLOW_TELEMETRY", "0")
+    sp = telemetry.span("pipeline/stage")
+    assert sp is telemetry._NULL_SPAN
+    with sp as entered:
+        entered.bind("x")
+        entered.cancel()
+    telemetry.record_span("serving/queue", 0.0)
+    assert telemetry.snapshot()["hists"] == {}
+
+
+def test_span_without_sink_or_profiler_makes_no_id_and_imports_nothing():
+    """Run in a fresh interpreter: the module must not pull jax in, and
+    with no sink and no profiler session a span makes no id."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from chunkflow_tpu.core import telemetry\n"
+        "with telemetry.span('pipeline/stage') as sp:\n"
+        "    assert telemetry._SPAN_CTX.get() is None\n"
+        "assert sp.span_id is None and sp.duration >= 0\n"
+        "assert telemetry.snapshot()['hists']['pipeline/stage']['count'] == 1\n"
+        "assert 'jax' not in sys.modules\n"
+        "print('ok')\n"
+    )
+    done = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=120,
+                          cwd=os.path.dirname(os.path.dirname(
+                              os.path.dirname(os.path.abspath(__file__)))))
+    assert done.returncode == 0, done.stderr
+    assert done.stdout.strip() == "ok"
+
+
+def test_spans_are_profiler_annotations_of_the_same_name(tmp_path):
+    """In a running jax.profiler session every span lies on the host
+    plane under its JSONL name, with the task's id among its stats; and
+    it makes ids although no sink is configured."""
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with telemetry.task_context("task-9"), \
+                telemetry.span("pipeline/drain") as sp:
+            assert sp.span_id is not None
+            with telemetry.span("scheduler/load") as load:
+                load.bind("late-task")
+    finally:
+        jax.profiler.stop_trace()
+    with telemetry.span("pipeline/drain") as after:
+        pass
+    assert after.span_id is None        # the session is over
+    (xplane,) = list(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    seen = {}
+    for plane in ProfileData.from_file(str(xplane)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for event in line.events:
+                    if event.name in ("pipeline/drain", "scheduler/load"):
+                        seen[event.name] = dict(event.stats)
+    assert seen["pipeline/drain"]["trace_id"] == "task-9"
+    assert seen["pipeline/drain"]["span_id"] == sp.span_id
+    assert seen["scheduler/load"]["trace_id"] == "late-task"

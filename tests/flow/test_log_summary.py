@@ -157,7 +157,7 @@ def test_print_telemetry_summary(tmp_path, capsys):
 def test_print_mesh_block_renders_per_chip_table(tmp_path, capsys):
     """ISSUE 18: the MESH block folds shard/chip and device/chip gauges
     into one per-chip table with skew, analytic collective traffic, and
-    the compute-vs-collective split verdict."""
+    the largest collective plane by bytes."""
     _write_events(tmp_path / "telemetry-1.jsonl", [
         {"kind": "gauge", "name": "shard/mesh_devices", "value": 2},
         {"kind": "gauge", "name": "shard/chip/0/voxels", "value": 2048.0},
@@ -175,11 +175,6 @@ def test_print_mesh_block_renders_per_chip_table(tmp_path, capsys):
          "value": 14.0 * 2**20},
         {"kind": "gauge", "name": "device/bytes_in_use",
          "value": 2.0 * 2**20},
-        {"kind": "gauge", "name": "shard/collective_share_est",
-         "value": 0.93},
-        {"kind": "gauge", "name": "shard/compute_s_est", "value": 0.0001},
-        {"kind": "gauge", "name": "shard/collective_s_est",
-         "value": 0.0015},
         {"kind": "snapshot", "pid": 1,
          "counters": {"shard/chunks": 3, "shard/halo_bytes": 1048576.0,
                       "shard/gather_bytes": 2097152.0}},
@@ -194,7 +189,7 @@ def test_print_mesh_block_renders_per_chip_table(tmp_path, capsys):
     assert "2.0" in out and "14.0" in out
     assert "chip skew (last ready − first ready)" in out
     assert "halo 1.00 MiB, gather 2.00 MiB" in out
-    assert "share 93% — collective-bound" in out
+    assert "largest collective plane by bytes: weighted-stack gather" in out
     assert "headroom 14.0 MiB (worst chip)" in out
     assert agg["counters"]["shard/gather_bytes"] == 2097152.0
 
@@ -220,7 +215,7 @@ def test_print_mesh_block_spatial_shape_and_quiet_default(capsys):
 def test_print_mesh_block_pipeline_shape_and_traffic_planes(capsys):
     """ISSUE 19: a pipeline mesh labels itself pipeline=N (not data=N),
     the traffic line carries the replay-strip and stage-handoff planes,
-    and the collective verdict turns into a recommended-shape hint."""
+    and the largest plane is named with its remedy."""
     from chunkflow_tpu.flow.log_summary import print_mesh_block
 
     agg = {"gauges": {
@@ -228,9 +223,6 @@ def test_print_mesh_block_pipeline_shape_and_traffic_planes(capsys):
         "shard/mesh_y": {"last": 1.0, "mean": 1.0},
         "shard/mesh_x": {"last": 1.0, "mean": 1.0},
         "shard/mesh_pipeline": {"last": 4.0, "mean": 4.0},
-        "shard/collective_share_est": {"last": 0.8, "mean": 0.8},
-        "shard/compute_s_est": {"last": 0.0001, "mean": 0.0001},
-        "shard/collective_s_est": {"last": 0.0004, "mean": 0.0004},
     }, "counters": {"shard/chunks": 2,
                     "shard/halo_bytes": 1048576.0,
                     "shard/replay_strip_bytes": 524288.0,
@@ -240,36 +232,35 @@ def test_print_mesh_block_pipeline_shape_and_traffic_planes(capsys):
     assert "shape pipeline=4 (4 chip(s)), 2 sharded dispatch(es)" in out
     assert "replay strips 0.50 MiB" in out
     assert "stage handoffs 2.00 MiB" in out
-    # handoffs dominate a collective-bound pipeline: the hint says so
-    assert "shape hint: stage handoffs dominate" in out
+    # handoffs are the largest plane of this pipeline: the line names
+    # them, with the remedy for the case that a trace shows them dominate
+    assert "largest collective plane by bytes: stage handoffs" in out
+    assert "fewer pipeline stages" in out
 
 
 def test_print_mesh_block_hints_replicated_replay_and_tight_hbm(capsys):
-    """The two other hint arms: a collective-bound mesh whose gather
-    plane has no replay strips points at CHUNKFLOW_SHARD_REPLAY; a
-    compute-bound mesh with a tight chip points at the shapes that
-    shrink per-chip footprints."""
+    """The two other hint arms: a mesh whose gather plane has no replay
+    strips points at CHUNKFLOW_SHARD_REPLAY; a mesh with a tight chip
+    points at the shapes that shrink per-chip footprints."""
     from chunkflow_tpu.flow.log_summary import print_mesh_block
 
     agg = {"gauges": {
         "shard/mesh_devices": {"last": 2.0, "mean": 2.0},
-        "shard/collective_share_est": {"last": 0.9, "mean": 0.9},
     }, "counters": {"shard/chunks": 1,
                     "shard/gather_bytes": 2097152.0}}
     assert print_mesh_block(agg) is True
     out = capsys.readouterr().out
-    assert ("shape hint: replicated replay dominates — flip "
+    assert ("if it dominates there: flip "
             "CHUNKFLOW_SHARD_REPLAY=sharded") in out
 
     agg = {"gauges": {
         "shard/mesh_devices": {"last": 2.0, "mean": 2.0},
-        "shard/collective_share_est": {"last": 0.1, "mean": 0.1},
         "device/chip/1/hbm_headroom": {"last": 2.0 * 2**20,
                                        "mean": 2.0 * 2**20},
     }, "counters": {"shard/chunks": 1}}
     assert print_mesh_block(agg) is True
     out = capsys.readouterr().out
-    assert "compute-bound but chip(s) [1]" in out
+    assert "shape hint: chip(s) [1] have <1 GiB HBM headroom" in out
     assert "sharded replay" in out
 
 

@@ -154,3 +154,88 @@ def test_skip_by_blocks_resume(world):
     r2 = runner.invoke(main, args, catch_exceptions=False)
     # second run: task skipped before load
     assert "save-precomputed" not in r2.output
+
+
+def test_worker_stream_rebuilds_one_span_tree_per_task(world):
+    """ISSUE 23: the README worker chain over a queue, --async-depth 2,
+    with a sink: every span that worked for a task carries the task's
+    trace_id (also the ones that waited for a task not yet known:
+    queue/fetch, scheduler/load), every parent_id names a span of the
+    same stream, and the spans run from the claim to the ack."""
+    import json
+
+    from chunkflow_tpu.core import telemetry
+
+    tmp_path = world["tmp_path"]
+    qdir = str(tmp_path / "queue")
+    runner = CliRunner()
+    result = runner.invoke(main, [
+        "generate-tasks", "-c", "8", "16", "16",
+        "--roi-start", "8", "16", "16", "--grid-size", "1", "1", "2",
+        "--queue-name", qdir], catch_exceptions=False)
+    assert result.exit_code == 0
+    telemetry.reset()
+    try:
+        result = runner.invoke(main, [
+            "--metrics-dir", str(tmp_path / "metrics"),
+            "fetch-task-from-queue", "-q", qdir, "--retry-times", "1",
+            "--poll-interval", "0.05",
+            "load-precomputed", "-v", world["input_vol"].path,
+            "--expand-margin-size", "4", "8", "8",
+            "inference", "--framework", "identity",
+            "--input-patch-size", "12", "24", "24",
+            "--output-patch-size", "8", "16", "16",
+            "--output-patch-overlap", "4", "8", "8",
+            "--num-output-channels", "1", "--batch-size", "2",
+            "--async-depth", "2",
+            "crop-margin",
+            "save-precomputed", "-v", world["output_vol"].path,
+            "delete-task-in-queue"], catch_exceptions=False)
+        assert result.exit_code == 0, result.output
+    finally:
+        telemetry.reset()
+    (stream,) = (tmp_path / "metrics").glob("telemetry-*.jsonl")
+    with open(stream) as f:
+        spans = [e for e in map(json.loads, f) if e["kind"] == "span"]
+    ids = {e["span_id"] for e in spans}
+    assert len(ids) == len(spans)
+    assert all(e["parent_id"] is None or e["parent_id"] in ids
+               for e in spans)
+    tasks = {}
+    for event in spans:
+        if event.get("trace_id"):
+            tasks.setdefault(event["trace_id"], []).append(event)
+    assert len(tasks) == 2
+    by_id = {e["span_id"]: e for e in spans}
+    for events in tasks.values():
+        names = {e["name"] for e in events}
+        assert {"queue/fetch", "op/load-precomputed", "storage/read",
+                "storage/decode", "scheduler/load", "pipeline/stage",
+                "pipeline/dispatch", "inference/blank_check",
+                "pipeline/compute", "pipeline/drain", "op/crop-margin",
+                "op/save-precomputed", "storage/write",
+                "op/delete-task-in-queue", "queue/ack"} <= names
+        # scheduler/load is the consumer's wait FOR the task: it can
+        # begin before the task was claimed
+        first = min((e for e in events if e["name"] != "scheduler/load"),
+                    key=lambda e: e["t0"])
+        last = max(events, key=lambda e: e["t"])
+        assert first["name"] == "queue/fetch"
+        assert last["name"] in ("pipeline/ack_writes", "queue/ack",
+                                "op/delete-task-in-queue")
+        # a child lies inside its parent, on the parent's task
+        for event in events:
+            parent = by_id.get(event["parent_id"])
+            if parent is not None:
+                assert parent["trace_id"] == event["trace_id"]
+                assert parent["t0"] <= event["t0"] + 1e-3
+                assert event["t"] <= parent["t"] + 5e-3
+        nested = {e["name"]: by_id[e["parent_id"]]["name"]
+                  for e in events if e["parent_id"] is not None}
+        assert nested["storage/read"] == "op/load-precomputed"
+        assert nested["storage/write"] == "op/save-precomputed"
+        assert nested["inference/blank_check"] == "pipeline/dispatch"
+        assert nested["queue/ack"] == "op/delete-task-in-queue"
+    # what carries no task: the wait that ended with the stream's end
+    assert {e["name"] for e in spans if not e.get("trace_id")} <= {
+        "scheduler/load", "compile_cache/build"}

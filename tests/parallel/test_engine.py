@@ -461,11 +461,12 @@ def test_per_chip_attribution_gauges(id_engine, monkeypatch):
     assert gauges["shard/chip_skew_s"] == pytest.approx(
         readies[-1] - readies[0])
     # analytic collective plane: the data axis all-gathers the output
-    # rows, and the split estimate rides with it
+    # rows. Bytes only: no seconds are reckoned from them (a device
+    # trace times the ops under the `collective` scope)
     assert counters["shard/gather_bytes"] > 0
     assert gauges["shard/gather_bytes_per_chunk"] == pytest.approx(
         counters["shard/gather_bytes"])
-    assert 0.0 < gauges["shard/collective_share_est"] <= 1.0
+    assert not [name for name in gauges if name.endswith("_est")]
 
 
 def test_spatial_mesh_stamps_halo_bytes(id_engine, monkeypatch):

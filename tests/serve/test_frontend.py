@@ -122,6 +122,64 @@ def test_post_infer_end_to_end_http(clean):
         server.server_close()
 
 
+def test_one_request_is_one_span_tree(clean, tmp_path):
+    """ISSUE 23: from before the body is read to after the response is
+    written a request is `serving/http`; inside it lie decode, request,
+    two encodes (array -> base64, payload -> JSON) and send; the wait
+    from admission to the first device batch is `serving/queue`, closed
+    on the packer thread. All share the id the response reports."""
+    path = telemetry.configure(str(tmp_path))
+    backend = LocalBackend(make_inferencer(), workers=1)
+    service = ServingService(backend, default_deadline_s=30.0)
+    server = start_serving(service, host="127.0.0.1", port=0)
+    port = server.server_address[1]
+    try:
+        arr = np.random.default_rng(1).random((6, 20, 28)).astype(np.float32)
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{port}/infer", data=infer_body(arr),
+            method="POST")
+        with urllib.request.urlopen(req, timeout=60) as resp:
+            payload = json.loads(resp.read())
+        # a route nobody times rides the same handler
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{port}/serving", timeout=10) as resp:
+            assert resp.status == 200
+    finally:
+        backend.close()
+        server.shutdown()
+        server.server_close()
+    with open(path) as f:
+        events = [json.loads(line) for line in f if line.strip()]
+    mine = [e for e in events if e["kind"] == "span"
+            and e.get("trace_id") == payload["trace_id"]]
+    by_name = {}
+    for event in mine:
+        by_name.setdefault(event["name"], []).append(event)
+    assert {"serving/http", "serving/decode", "serving/request",
+            "serving/queue", "serving/encode", "serving/send",
+            "queue/fetch", "queue/ack", "lifecycle/claim",
+            "lifecycle/commit"} <= set(by_name)
+    (http,) = by_name["serving/http"]
+    assert http["parent_id"] is None
+    children = [e for name in ("serving/decode", "serving/request",
+                               "serving/encode", "serving/send")
+                for e in by_name[name]]
+    assert len(by_name["serving/encode"]) == 2
+    assert all(e["parent_id"] == http["span_id"] for e in children)
+    assert all(http["t0"] <= e["t0"] and e["t"] <= http["t"] + 0.005
+               for e in children)
+    # the queue wait starts at admission, inside the request, and ends
+    # on the packer's thread
+    (queued,) = by_name["serving/queue"]
+    (request,) = by_name["serving/request"]
+    assert queued["thread"] == "patch-packer"
+    assert request["t0"] - 0.05 <= queued["t0"] <= request["t"]
+    assert queued["t"] <= request["t"] + 0.005
+    ids = {e["span_id"] for e in events if e["kind"] == "span"}
+    assert all(e["parent_id"] is None or e["parent_id"] in ids
+               for e in mine)
+
+
 def test_uint8_request_round_trip(clean):
     inferencer = make_inferencer()
     backend = LocalBackend(inferencer, workers=1)
