@@ -71,7 +71,7 @@ from chunkflow_tpu.core import telemetry
 
 __all__ = [
     "instrument_program", "stamp_cost", "catalog", "write_catalog",
-    "device_peaks", "DEVICE_SCOPES", "op_scopes", "note_h2d",
+    "device_peaks", "DEVICE_SCOPES", "op_scopes", "trace_gauge", "note_h2d",
     "h2d_by_family",
     "note_hbm_intermediate", "hbm_intermediate_by_family",
     "note_collective", "collective_by_family",
@@ -232,7 +232,7 @@ class _ProgramRecord:
         "family", "key", "label", "build_s", "compile_s", "flops",
         "bytes_accessed", "vmem_bytes", "hbm_intermediate", "optimal_s",
         "calls", "dispatch_s", "platform", "device_kind", "op_scopes",
-        "lock",
+        "traced", "lock",
     )
 
     def __init__(self, family: str, key: str, label: str, build_s: float):
@@ -251,11 +251,25 @@ class _ProgramRecord:
         self.platform = ""
         self.device_kind = ""
         self.op_scopes: Optional[dict] = None
+        self.traced: dict = {}  # trace_gauge() values of the program's trace
         self.lock = threading.Lock()
 
 
 _LEDGER_LOCK = threading.Lock()
 _LEDGER: dict = {}  # (family, key) -> _ProgramRecord
+
+_TRACING = threading.local()  # .gauges: dict while a first call traces
+
+
+def trace_gauge(name: str, value: float) -> None:
+    """A gauge set by code that runs while a program is traced (a model
+    saying which lowering it chose from the shapes it was given): a
+    telemetry gauge, and a value on the entry of the program being built
+    in ``programs.json`` (there ``forward/x_fold`` is ``x_fold``)."""
+    telemetry.gauge(name, value)
+    gauges = getattr(_TRACING, "gauges", None)
+    if gauges is not None:
+        gauges[name] = value
 
 
 def _device_identity() -> Tuple[str, str]:
@@ -366,6 +380,15 @@ class _InstrumentedProgram:
         return out
 
     def _first_call(self, args, kwargs):
+        # the program's Python body runs, on this thread, inside the
+        # first of the lowering and the call: trace_gauge() lands here
+        _TRACING.gauges = traced = {}
+        try:
+            return self._traced_first_call(args, kwargs, traced)
+        finally:
+            _TRACING.gauges = None
+
+    def _traced_first_call(self, args, kwargs, traced):
         rec = self._rec
         # an analytic cost stamp (stamp_cost) wins over XLA's
         # cost_analysis: programs whose HLO hides traffic behind custom
@@ -411,6 +434,7 @@ class _InstrumentedProgram:
                     float(optimal) if optimal is not None else None
                 )
                 rec.op_scopes = scopes
+                rec.traced = traced
             else:  # raced: the other thread's call was the compile
                 rec.calls += 1
                 rec.dispatch_s += dt
@@ -646,6 +670,7 @@ def catalog() -> list:
                 "platform": rec.platform,
                 "device_kind": rec.device_kind,
                 "op_scopes": rec.op_scopes,
+                "x_fold": rec.traced.get("forward/x_fold"),
             }
             calls, dispatch_s = rec.calls, rec.dispatch_s
             flops, nbytes = rec.flops, rec.bytes_accessed

@@ -12,21 +12,189 @@ so ``models.converter.torch_to_flax_by_name`` can pair parameters BY NAME
 — independent of torch module *definition order* — and fold BatchNorm
 running statistics into the inference-affine ``bn*`` scale/bias.
 
-TPU-first choices: channels-last NDHWC (MXU-tiled convs), norm folded to a
-per-channel affine (no batch statistics at inference — one fused
-multiply-add instead of a reduction), optional bfloat16 compute with
-float32 params.
+Layout: channels-last NDHWC, so the channel count is the minor
+dimension, which the chip pads to its 128 lanes.  At the full-resolution
+level that is 28 (or 16) channels in 128 lanes: every level-0 array took
+4.6x (8x) its bytes in HBM and every level-0 convolution filled 28 (16) of
+the MXU's 128 columns (optimized HLO for a v5e, PERF.md, PR 24).  So level 0
+runs *x-folded*: F adjacent x positions move into the channels
+(``[B,z,y,X,C] -> [B,z,y,X/F,F*C]``, a row-major reshape) and each level-0
+convolution runs on the folded array with a block-banded kernel built at
+trace time from the published ``[kz,ky,kx,Cin,Cout]`` one
+(:func:`fold_kernel`).  Same parameters, same products, same rounding
+points; only exact zeros are added.  F is the largest power of two with
+``F * width[0] <= 128`` that divides the x extent (:func:`x_fold`: 28 -> 4,
+16 -> 8); at F = 1 the folded kernel is the published kernel and the call
+is the plain convolution, which is how levels 1-3 run.  The two
+transitions are emitted folded as well (:class:`XFoldUp`,
+:func:`max_pool_folded`): behind a plain reshape the pool alone took a
+quarter of the chip's time.  Norm is folded to a per-channel affine (no
+batch statistics at inference), compute is optionally bfloat16 with
+float32 params; the final activation is computed in the output's dtype.
 """
 from __future__ import annotations
 
+import functools
 from typing import Sequence, Tuple
 
 import flax.linen as nn
 import jax.numpy as jnp
+import numpy as np
+from flax.linen.dtypes import promote_dtype
+from jax import lax
 
-from chunkflow_tpu.models.unet3d import MxuConvTranspose, _make_conv
+from chunkflow_tpu.core import profiling
+from chunkflow_tpu.models.unet3d import MxuConv, MxuConvTranspose
 
 Triple = Tuple[int, int, int]
+
+LANES = 128  # the minor dimension of a TPU vreg, VMEM tile and MXU pass
+
+
+def x_fold(width0: int, x_extent: int) -> int:
+    """The fold factor of the full-resolution level: the largest power of
+    two F with ``F * width0 <= LANES`` and ``x_extent % F == 0``."""
+    fold = 1
+    while 2 * fold * width0 <= LANES and x_extent % (2 * fold) == 0:
+        fold *= 2
+    return fold
+
+
+def fold_x(x, fold: int):
+    """[B,z,y,X,C] -> [B,z,y,X/fold,fold*C]: ``fold`` adjacent x positions
+    side by side in the channels (position major, channel minor)."""
+    *lead, xs, c = x.shape
+    return x.reshape(*lead, xs // fold, fold * c)
+
+
+def unfold_x(x, fold: int):
+    """Inverse of :func:`fold_x`."""
+    *lead, xs, c = x.shape
+    return x.reshape(*lead, xs * fold, c // fold)
+
+
+def fold_kernel(kernel, fold: int):
+    """The kernel of the same 'SAME' convolution on an x-folded array.
+
+    ``kernel`` is ``[kz,ky,kx,Cin,Cout]``. Returns ``[kz,ky,taps,
+    fold*Cin,fold*Cout]`` and the zero blocks to pad in x, (lo, hi). Block
+    tap t takes input position p_in of block b+t to output position p_out
+    of block b with the published tap ``dx = fold*t + p_in - p_out`` where
+    the kernel has one and with zero elsewhere, so a zero block beyond the
+    edge is the zero padding of the positions in it. Pure data movement
+    (pad, take, transpose): every entry is a published weight or 0. With
+    ``fold == 1`` the taps are the published kernel's own."""
+    kz, ky, kx, cin, cout = kernel.shape
+    lo, hi = (kx - 1) // 2, kx // 2  # 'SAME' as flax pads it
+    t_lo, t_hi = -(-lo // fold), -(-hi // fold)
+    taps = t_lo + t_hi + 1
+    t = np.arange(-t_lo, t_hi + 1)[:, None, None]
+    p_in = np.arange(fold)[None, :, None]
+    p_out = np.arange(fold)[None, None, :]
+    k = fold * t + p_in - p_out + lo
+    k = np.where((k >= 0) & (k < kx), k, kx)  # kx: the zero slot
+    padded = jnp.pad(kernel, ((0, 0), (0, 0), (0, 1), (0, 0), (0, 0)))
+    folded = jnp.take(padded, k.reshape(-1), axis=2)
+    folded = folded.reshape(kz, ky, taps, fold, fold, cin, cout)
+    folded = folded.transpose(0, 1, 2, 3, 5, 4, 6)  # t, p_in, ci, p_out, co
+    return folded.reshape(kz, ky, taps, fold * cin, fold * cout), (t_lo, t_hi)
+
+
+class XFoldConv(nn.Module):
+    """``nn.Conv(features, kernel_size, padding='SAME')`` with the same
+    parameter tree, on an array whose channels hold ``fold`` adjacent x
+    positions. Rounds where nn.Conv does: the convolution's result in the
+    compute dtype, then the bias."""
+
+    features: int
+    kernel_size: Triple
+    dtype: jnp.dtype = jnp.float32
+    fold: int = 1
+
+    @nn.compact
+    def __call__(self, x):
+        kz, ky, _ = self.kernel_size
+        kernel = self.param(
+            "kernel", nn.initializers.lecun_normal(),
+            (*self.kernel_size, x.shape[-1] // self.fold, self.features),
+        )
+        bias = self.param("bias", nn.initializers.zeros_init(),
+                          (self.features,))
+        x, kernel, bias = promote_dtype(x, kernel, bias, dtype=self.dtype)
+        kernel, x_pad = fold_kernel(kernel, self.fold)
+        y = lax.conv_general_dilated(
+            x, kernel, window_strides=(1, 1, 1),
+            padding=(((kz - 1) // 2, kz // 2), ((ky - 1) // 2, ky // 2),
+                     x_pad),
+            dimension_numbers=("NDHWC", "DHWIO", "NDHWC"),
+        )
+        return y + jnp.tile(bias, self.fold)
+
+
+class XFoldUp(nn.Module):
+    """``nn.ConvTranspose(features, kernel_size=factor, strides=factor)``
+    with the same parameter tree, whose result comes out x-folded by
+    ``fold`` (a multiple of the x factor) with no full-resolution array
+    in between. With kernel == stride every input position emits its own
+    (fz, fy, fx, features) block, so the input folded by ``fold // fx``
+    needs one 1x1x1 convolution with a block-diagonal kernel: the x part
+    of each block is already the folded channel order, and only z and y
+    are interleaved afterwards, above the lanes."""
+
+    features: int
+    factor: Triple
+    dtype: jnp.dtype = jnp.float32
+    fold: int = 2
+
+    @nn.compact
+    def __call__(self, x):
+        fz, fy, fx = self.factor
+        b, z, y, xs, cin = x.shape
+        kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                            (fz, fy, fx, cin, self.features))
+        bias = self.param("bias", nn.initializers.zeros_init(),
+                          (self.features,))
+        x, kernel, bias = promote_dtype(x, kernel, bias, dtype=self.dtype)
+        g = self.fold // fx
+        # nn.ConvTranspose puts the spatially flipped kernel in each block
+        k = kernel[::-1, ::-1, ::-1].transpose(0, 1, 3, 2, 4)  # i,j,c,k,f
+        same = np.eye(g, dtype=bool)[:, None, :, None, None]
+        k = jnp.where(same, k[:, :, None, :, None], 0)  # i,j,g,c,g,k,f
+        k = k.reshape(fz, fy, 1, 1, 1, g * cin, self.fold * self.features)
+        x = fold_x(x, g)
+        rows = [[lax.conv_general_dilated(
+            x, k[i, j], (1, 1, 1), "VALID",
+            dimension_numbers=("NDHWC", "DHWIO", "NDHWC"))
+            for j in range(fy)] for i in range(fz)]
+        y_ = jnp.stack([jnp.stack(row, axis=3) for row in rows], axis=2)
+        y_ = y_.reshape(b, z * fz, y * fy, xs // g, -1)
+        return y_ + jnp.tile(bias, self.fold)
+
+
+def max_pool_folded(x, factor: Triple, fold: int):
+    """``nn.max_pool(x, factor, strides=factor)`` of an array x-folded by
+    ``fold`` (a multiple of the x factor): a maximum over z and y pairs
+    and over neighbouring positions inside the lanes. The result is
+    x-folded by ``fold // fx``."""
+    fz, fy, fx = factor
+    b, z, y, xs, c = x.shape
+    # z and y windows lie above the lanes: halve the array there first
+    x = x.reshape(b, z // fz, fz, y // fy, fy, xs, c).max(axis=(2, 4))
+    c //= fold
+    lanes = [x[..., p * c:(p + 1) * c] for p in range(fold)]
+    return jnp.concatenate(
+        [functools.reduce(jnp.maximum, lanes[g * fx:(g + 1) * fx])
+         for g in range(fold // fx)], axis=-1)
+
+
+def _conv(conv_impl: str, features: int, kernel_size: Triple, dtype,
+          fold: int, name=None):
+    """The convolution of a lowering: identical parameter trees, so
+    ``conv_impl`` is a pure lowering choice. "mxu" is never folded."""
+    if conv_impl == "mxu":
+        return MxuConv(features, kernel_size, dtype=dtype, name=name)
+    return XFoldConv(features, kernel_size, dtype=dtype, fold=fold,
+                     name=name)
 
 
 class Affine(nn.Module):
@@ -35,31 +203,35 @@ class Affine(nn.Module):
 
     features: int
     dtype: jnp.dtype = jnp.float32
+    fold: int = 1
 
     @nn.compact
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.ones, (self.features,))
         bias = self.param("bias", nn.initializers.zeros, (self.features,))
-        return x * scale.astype(self.dtype) + bias.astype(self.dtype)
+        scale = jnp.tile(scale.astype(self.dtype), self.fold)
+        bias = jnp.tile(bias.astype(self.dtype), self.fold)
+        return x * scale + bias
 
 
 class RSBlock(nn.Module):
     """Residual block: conv1(1,3,3) -> conv2(3,3,3) -> conv3(3,3,3), each
     conv -> bn -> relu, with the residual taken after conv1 (the
-    superhuman-RSUNet shape)."""
+    superhuman-RSUNet shape). ``fold``: the x-fold of its input."""
 
     features: int
     dtype: jnp.dtype = jnp.float32
     conv_impl: str = "native"
+    fold: int = 1
 
     def setup(self):
-        f, dt = self.features, self.dtype
-        self.conv1 = _make_conv(self.conv_impl, f, (1, 3, 3), dt, None)
-        self.bn1 = Affine(f, dtype=dt)
-        self.conv2 = _make_conv(self.conv_impl, f, (3, 3, 3), dt, None)
-        self.bn2 = Affine(f, dtype=dt)
-        self.conv3 = _make_conv(self.conv_impl, f, (3, 3, 3), dt, None)
-        self.bn3 = Affine(f, dtype=dt)
+        f, dt, fold = self.features, self.dtype, self.fold
+        self.conv1 = _conv(self.conv_impl, f, (1, 3, 3), dt, fold)
+        self.bn1 = Affine(f, dtype=dt, fold=fold)
+        self.conv2 = _conv(self.conv_impl, f, (3, 3, 3), dt, fold)
+        self.bn2 = Affine(f, dtype=dt, fold=fold)
+        self.conv3 = _conv(self.conv_impl, f, (3, 3, 3), dt, fold)
+        self.bn3 = Affine(f, dtype=dt, fold=fold)
 
     def __call__(self, x):
         x = nn.relu(self.bn1(self.conv1(x)))
@@ -86,59 +258,54 @@ class RSUNet(nn.Module):
     final_activation: str = "sigmoid"
     conv_impl: str = "native"  # "mxu": same params, 2D/GEMM lowering
 
-    def setup(self):
+    @nn.compact
+    def __call__(self, x):
         depth = len(self.width)
         assert len(self.down_factors) == depth - 1
         dt, impl = self.dtype, self.conv_impl
-        self.embed = _make_conv(impl, self.width[0], (1, 5, 5), dt, None)
-        self.enc = [
-            RSBlock(self.width[i], dtype=dt, conv_impl=impl, name=f"enc{i}")
-            for i in range(depth - 1)
-        ]
-        self.bridge = RSBlock(self.width[-1], dtype=dt, conv_impl=impl)
-        self.up = [
-            MxuConvTranspose(
-                self.width[i],
-                factor=self.down_factors[i],
-                dtype=dt,
-                name=f"up{i}",
-            )
-            if impl == "mxu"
-            else nn.ConvTranspose(
-                self.width[i],
-                kernel_size=self.down_factors[i],
-                strides=self.down_factors[i],
-                dtype=dt,
-                name=f"up{i}",
-            )
-            for i in range(depth - 1)
-        ]
-        self.dec = [
-            RSBlock(self.width[i], dtype=dt, conv_impl=impl, name=f"dec{i}")
-            for i in range(depth - 1)
-        ]
-        self.out = _make_conv(impl, self.out_channels, (1, 1, 1), dt, None)
+        # level i runs x-folded by folds[i]; only level 0 folds so far
+        fold = 1 if impl == "mxu" else x_fold(self.width[0], x.shape[-2])
+        profiling.trace_gauge("forward/x_fold", fold)
+        folds = [fold] + [1] * (depth - 1)
 
-    def __call__(self, x):
+        def block(i, name):
+            return RSBlock(self.width[i], dtype=dt, conv_impl=impl,
+                           fold=folds[i], name=name)
+
         orig_dtype = x.dtype
-        x = x.astype(self.dtype)
-        depth = len(self.width)
-        x = self.embed(x)
+        x = fold_x(x.astype(dt), fold)
+        x = _conv(impl, self.width[0], (1, 5, 5), dt, fold, name="embed")(x)
         skips = []
         for i in range(depth - 1):
-            x = self.enc[i](x)
+            x = block(i, f"enc{i}")(x)
             skips.append(x)
-            x = nn.max_pool(
-                x,
-                window_shape=self.down_factors[i],
-                strides=self.down_factors[i],
-            )
-        x = self.bridge(x)
+            factor = self.down_factors[i]
+            if folds[i] % factor[2]:  # positions of one window, two blocks
+                x = nn.max_pool(unfold_x(x, folds[i]), window_shape=factor,
+                                strides=factor)
+            else:
+                x = unfold_x(max_pool_folded(x, factor, folds[i]),
+                             folds[i] // factor[2])
+        x = block(depth - 1, "bridge")(x)
         for i in reversed(range(depth - 1)):
-            x = self.up[i](x)
+            factor = self.down_factors[i]
+            if impl == "mxu":
+                x = MxuConvTranspose(self.width[i], factor=factor, dtype=dt,
+                                     name=f"up{i}")(x)
+            elif folds[i] % factor[2]:
+                x = fold_x(nn.ConvTranspose(
+                    self.width[i], kernel_size=factor, strides=factor,
+                    dtype=dt, name=f"up{i}")(x), folds[i])
+            else:
+                x = XFoldUp(self.width[i], factor=factor, dtype=dt,
+                            fold=folds[i], name=f"up{i}")(x)
             x = x + skips[i]
-            x = self.dec[i](x)
-        x = self.out(x)
+            x = block(i, f"dec{i}")(x)
+        x = _conv(impl, self.out_channels, (1, 1, 1), dt, fold, name="out")(x)
+        # the activation in the output's dtype: what the chip computed all
+        # along while head, sigmoid and cast were one fusion (XLA keeps
+        # excess precision inside one), now that a copy lies between them
+        x = unfold_x(x, fold).astype(orig_dtype)
         if self.final_activation == "sigmoid":
             x = nn.sigmoid(x)
-        return x.astype(orig_dtype)
+        return x

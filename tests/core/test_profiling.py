@@ -539,6 +539,37 @@ def test_programs_json_carries_op_scopes_only_with_a_sink(clean_plane,
     assert streamed and "op_scopes" not in streamed[0]["programs"][0]
 
 
+def test_trace_gauge_lands_on_the_program_being_built(clean_plane, tmp_path):
+    """A model says at trace time which lowering it chose: the value is
+    a gauge and sits on that program's entry, not on a later program's."""
+    import jax
+    import jax.numpy as jnp
+
+    def build(fold):
+        def program(x):
+            if fold:
+                profiling.trace_gauge("forward/x_fold", fold)
+            return x * 2.0
+        return jax.jit(program)
+
+    telemetry.configure(str(tmp_path))
+    x = jnp.ones((8, 128), jnp.float32)
+    for fold in (4, 0):
+        program = ProgramCache(label=f"fold{fold}").get(
+            (f"fold{fold}",), lambda: build(fold))
+        program(x)
+        program(x)  # no second trace, nothing to overwrite
+    by_label = {e["label"]: e["x_fold"] for e in profiling.catalog()}
+    assert by_label == {"fold4": 4, "fold0": None}
+    assert telemetry.snapshot()["gauges"]["forward/x_fold"] == 4
+    profiling.trace_gauge("forward/x_fold", 2)  # outside any first call
+    assert telemetry.snapshot()["gauges"]["forward/x_fold"] == 2
+    telemetry.flush()
+    payload = json.loads((tmp_path / "programs.json").read_text())
+    assert sorted(str(e["x_fold"]) for e in payload["programs"]) == [
+        "4", "None"]
+
+
 def test_automatic_capture_yields_to_a_session_it_did_not_start(
         clean_plane, tmp_path):
     """A harness's (or an operator's) jax.profiler session is running:

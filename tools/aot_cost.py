@@ -1,0 +1,134 @@
+"""Rank lowerings of a configuration's forward without a chip.
+
+Compiles the RSUNet of a benchmark configuration for a *described* TPU
+v5e (``jax.experimental.topologies``; needs libtpu, no device) and prints
+XLA's own ``estimated_cycles`` for every op of the entry computation with
+its flax module (``op_name`` metadata) and its shape and layout, the
+largest first, and the total.
+
+This is the compiler's cost model and not a measurement. It ranks two
+lowerings of the same forward against each other and says which ops a
+lowering leaves expensive; it is never written under the name of a speed.
+Calibration, model over the chip's device time a program (my chip runs,
+PR 24; docs/performance.md "Sizing a lowering offline"): 1.38 and 1.42
+(rsunet-superhuman, rsunet-deepem) before the x-fold, 1.49 and 1.43 after
+it; and 1.09 for a build whose pool it mis-sized 13x.
+
+    JAX_PLATFORMS=cpu python tools/aot_cost.py rsunet-superhuman [--top 25]
+"""
+import argparse
+import json
+import os
+import re
+import sys
+
+CLOCK_HZ = 1.5e9  # the rate the totals are turned into ms with; a scale
+
+_ENTRY = re.compile(r"^ENTRY ")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\(")
+_CYCLES = re.compile(r'"estimated_cycles":"(\d+)"')
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def entry_ops(hlo_text: str) -> list:
+    """``[(cycles, op, opcode, shape with layout, op_name)]`` of the entry
+    computation of one compiled module's text, in program order."""
+    ops, inside = [], False
+    for line in hlo_text.splitlines():
+        if _ENTRY.match(line):
+            inside = True
+            continue
+        if inside and line.startswith("}"):
+            break
+        if not inside:
+            continue
+        match = _INSTRUCTION.match(line)
+        cycles = _CYCLES.search(line)
+        if match is None or cycles is None:
+            continue
+        op_name = _OP_NAME.search(line)
+        ops.append((int(cycles.group(1)), match.group(1), match.group(3),
+                    match.group(2), op_name.group(1) if op_name else ""))
+    return ops
+
+
+def module_of(op_name: str) -> str:
+    """``jit(apply)/RSUNet/enc0/conv2/conv_general_dilated`` ->
+    ``enc0/conv2/conv_general_dilated``."""
+    parts = op_name.split("/")
+    return "/".join(parts[2:]) if len(parts) > 2 else op_name
+
+
+def compile_forward(config: dict, batch: int):
+    """The configuration's forward (``RSUNet.apply`` on one batch of
+    patches) compiled for one chip of a described v5e."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from chunkflow_tpu.models import rsunet
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    chip = SingleDeviceSharding(topo.devices[0])
+    spec = config["model"]
+    model = rsunet.RSUNet(
+        in_channels=spec["in_channels"], out_channels=spec["out_channels"],
+        width=tuple(spec["width"]),
+        down_factors=tuple(map(tuple, spec["pooling"])),
+        dtype=jnp.dtype(spec["compute_dtype"]),
+        final_activation=spec["final_activation"])
+    shape = (batch, *config["patch"], spec["in_channels"])
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros(shape)))
+    params, x = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        (params, jax.ShapeDtypeStruct(shape, jnp.float32)))
+    return jax.jit(model.apply).lower(params, x).compile()
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("config", help="a name under benchmarks/configs/ "
+                        "or a path to such a file")
+    parser.add_argument("--batch", type=int, default=None,
+                        help="patches a program (default: the config's)")
+    parser.add_argument("--top", type=int, default=25)
+    parser.add_argument("--hlo", help="also write the optimized HLO here")
+    args = parser.parse_args(argv)
+    path = args.config
+    if not os.path.exists(path):
+        path = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            args.config + ".json")
+    with open(path) as f:
+        config = json.load(f)
+    compiled = compile_forward(config, args.batch or config["batch"])
+    text = compiled.as_text()
+    if args.hlo:
+        with open(args.hlo, "w") as f:
+            f.write(text)
+    ops = entry_ops(text)
+    total = sum(op[0] for op in ops)
+    by_opcode: dict = {}
+    for cycles, _, opcode, _, _ in ops:
+        by_opcode[opcode] = by_opcode.get(opcode, 0) + cycles
+    print("XLA's cost model for a described v5e: a ranking of lowerings, "
+          "NOT a measurement")
+    print(f"{config['name']}: {total / 1e6:.1f} M estimated cycles a "
+          f"program ({1e3 * total / CLOCK_HZ:.0f} ms at "
+          f"{CLOCK_HZ / 1e9:g} GHz), {len(ops)} entry ops")
+    for opcode, cycles in sorted(by_opcode.items(), key=lambda kv: -kv[1]):
+        print(f"  {opcode:<24} {cycles / 1e6:8.1f} M "
+              f"{100.0 * cycles / total:5.1f}%")
+    print(f"{'Mcycles':>8} {'%':>5}  op / shape{{layout}} / flax module")
+    for cycles, op, _, shape, op_name in sorted(ops, reverse=True)[:args.top]:
+        print(f"{cycles / 1e6:8.2f} {100.0 * cycles / total:5.1f}  {op} "
+              f"{shape} {module_of(op_name) or '-'}")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
