@@ -1,0 +1,234 @@
+"""What the deployment with a cropped output patch brings to the
+benchmark (PR 26): its geometry, its plain blend and reference, the bytes
+function, and the two reducers, on hand-made inputs."""
+import numpy as np
+import pytest
+
+from cfbench import blend, catalog, crop_blend, crop_volume
+from cfbench.run_record import RunRecord
+
+
+def test_the_deployments_geometry():
+    """The committed cell's own files: the task ISSUE 26 fixed."""
+    config = catalog.load_json("configs", "rsunet-superhuman-prod.json")
+    traffic = catalog.load_json("traffic", "volume-prod.json")
+    assert traffic["patch_grid"] == [6, 9, 9] and traffic["tasks"] == 16
+    g = crop_volume.CropGeometry.of(
+        config["patch"], config["output_patch"], config["overlap"],
+        margin=tuple(traffic["margin"]), block=tuple(traffic["block"]),
+        grid=tuple(traffic["patch_grid"]), n_tasks=traffic["tasks"])
+    assert g.stride == (14, 160, 160) and g.overlap == (6, 96, 96)
+    assert g.crop == (2, 32, 32)
+    assert g.chunk_in == (90, 1536, 1536) and g.task == (70, 1280, 1280)
+    assert g.patches_per_task == 486 and g.task_voxels == 114_688_000
+    # the trace holds a whole pipeline/dispatch (about a task period:
+    # 11.8 s on the v5e) inside the window
+    assert 24 <= traffic["trace"]["seconds"]
+    assert traffic["trace"]["start_after_s"] + traffic["trace"]["seconds"] < 45
+    # one output stride wide, from the middle of the first output patch:
+    # the first two output windows of an axis ([2, 18) and [16, 32) in z)
+    # overlap inside it
+    assert g.check_box() == ((10, 128, 128), (24, 288, 288))
+    # with no crop it is the plain geometry
+    same = crop_volume.CropGeometry.of(
+        [8, 32, 32], [8, 32, 32], [2, 8, 8], margin=(1, 4, 4),
+        block=(6, 24, 24), grid=(2, 2, 3), n_tasks=8)
+    assert same.overlap == (2, 8, 8) and same.crop == (0, 0, 0)
+    assert same.check_box() == ((4, 16, 16), (10, 40, 40))
+
+
+@pytest.mark.parametrize("kwargs, said", [
+    ({"output_patch": [5, 16, 16]}, "odd"),
+    ({"margin": (1, 8, 8)}, "smaller"),
+    ({"margin": (5, 12, 12)}, "leaves"),
+])
+def test_a_geometry_that_cannot_be_checked_is_refused(kwargs, said):
+    args = {"patch": [8, 32, 32], "output_patch": [4, 16, 16],
+            "output_overlap": [2, 8, 8], "margin": (3, 12, 12),
+            "block": (4, 8, 8), "grid": (2, 2, 3), "n_tasks": 8, **kwargs}
+    with pytest.raises(ValueError, match=said):
+        crop_volume.CropGeometry.of(**args)
+
+
+def test_plain_blend_of_cropped_patches():
+    """The identity network (each patch's prediction is its own centre)
+    blends back to the image; a constant one to the constant; and the
+    count says which patches reach the box."""
+    patch, out, overlap = (8, 32, 32), (4, 16, 16), (2, 8, 8)
+    image = np.random.default_rng(0).integers(
+        0, 256, (11, 42, 45), dtype=np.uint8)
+    crop = (slice(2, 6), slice(8, 24), slice(8, 24))
+    box = ((2, 8, 8), (9, 34, 37))
+    got, n = crop_blend.blend_box(image, patch, out, overlap, box,
+                                  lambda w: np.stack([w[crop], 1 - w[crop]]))
+    assert n == 27 and got.shape == (2, 7, 26, 29)
+    want = image[2:9, 8:34, 8:37].astype(np.float32) * np.float32(1 / 255)
+    assert np.abs(got[0] - want).max() < 1e-12
+    assert np.abs(got[1] - (1 - want)).max() < 1e-12
+    # a box inside the first two output windows of every axis: 8 patches
+    wide = np.zeros((14, 56, 56), np.uint8)     # 4x4x4 patches, aligned
+    got, n = crop_blend.blend_box(wide, patch, out, overlap,
+                                  ((4, 16, 16), (6, 24, 24)),
+                                  lambda w: np.ones((1, 4, 16, 16)))
+    assert n == 8 and np.allclose(got, 1.0)
+    # with output patch = input patch it is cfbench.blend's
+    one = lambda w: w[None]
+    a, na = crop_blend.blend_box(image, patch, patch, overlap,
+                                 ((1, 3, 5), (9, 30, 40)), one)
+    b, nb = blend.blend_box(image, patch, overlap,
+                            ((1, 3, 5), (9, 30, 40)), one)
+    assert na == nb and np.array_equal(a, b)
+
+
+def test_cropped_reference_matches_the_programs_engine():
+    """reference/rsunet_crop.py against the program's flax engine with an
+    output patch: the two share no code."""
+    import jax.numpy as jnp
+
+    from chunkflow_tpu.inference.engines import create_flax_engine
+
+    patch, out = (8, 32, 32), (4, 16, 16)
+    engine = create_flax_engine("", None, patch, 1, 4,
+                                model_variant="rsunet",
+                                output_patch_size=out)
+    reference = catalog.load_module("reference", "rsunet_crop")
+    forward = reference.make_forward(
+        {"model": {"final_activation": "sigmoid"}, "output_patch": out})
+    x = np.random.default_rng(1).random((2, 1) + patch, dtype=np.float32)
+    want = np.asarray(engine.apply(engine.params, jnp.asarray(x)))
+    got = np.moveaxis(np.asarray(forward(
+        engine.params, jnp.asarray(np.moveaxis(x, 1, -1)))), -1, 1)
+    assert got.shape == want.shape == (2, 4) + out
+    assert np.abs(got - want).max() < 2e-5
+    with pytest.raises(ValueError, match="centrally"):
+        reference.central_crop(jnp.zeros((1, 8, 32, 32, 1)), (5, 16, 16))
+
+
+@pytest.fixture(scope="module")
+def rehearsed_block():
+    """The configuration at its rehearsal geometry on a chunk of 2x2x2
+    patches: (config, the program's blended output, the reference's)."""
+    import jax.numpy as jnp
+
+    from cfbench import volume
+    from chunkflow_tpu.chunk.base import Chunk
+    from chunkflow_tpu.inference import Inferencer
+
+    config = catalog.load_json("configs", "rsunet-superhuman-prod.json")
+    config = {**config, **config["rehearse"]}
+    patch, out, overlap = (tuple(config[k]) for k in
+                           ("patch", "output_patch", "overlap"))
+    crop = tuple((p - o) // 2 for p, o in zip(patch, out))
+    shape = tuple(2 * p - (p - (o - v))
+                  for p, o, v in zip(patch, out, overlap))
+    image = volume.make_slab(np.random.default_rng([7, 0]), shape)
+    box = (crop, tuple(s - c for s, c in zip(shape, crop)))
+    inferencer = Inferencer(
+        input_patch_size=patch, output_patch_size=out,
+        output_patch_overlap=overlap, batch_size=config["batch"],
+        num_output_channels=config["model"]["out_channels"],
+        framework="flax", model_variant=config["engine"]["model_variant"],
+        dtype=config["engine"]["dtype"])
+    got = np.asarray(inferencer(Chunk(image)).array, np.float64)
+    params = inferencer.engine.params
+    reference = catalog.load_module("reference", config["reference"])
+
+    def blended(forward):
+        def one(window):
+            y = forward(params, jnp.asarray(window[None, ..., None]))
+            return np.moveaxis(np.asarray(y[0]), -1, 0)
+        return crop_blend.blend_box(image, patch, out, overlap, box, one)[0]
+
+    return config, reference, got, blended
+
+
+@pytest.mark.parametrize("computed_in, correct", [
+    ("the program", True),
+    ("bfloat16", True),
+    ("float8_e4m3fn", False),
+])
+def test_the_tolerance_refuses_the_precision_below(rehearsed_block,
+                                                   computed_in, correct):
+    """Both judges, as the driver calls them: the program's own output
+    and the reference with bfloat16 operands (the configuration's
+    precision) are correct; the reference with float8_e4m3 operands, the
+    nearest precision below, is not."""
+    import jax.numpy as jnp
+
+    from cfbench import check
+
+    config, reference, got, blended = rehearsed_block
+    want = blended(reference.make_forward(config))
+    if computed_in != "the program":
+        got = blended(reference.make_rounded_forward(
+            config, getattr(jnp, computed_in)))
+    r = record(config=config, client={})
+    check.judge(r, got, want, computed_in, {})
+    crop_blend.judge_mean(r, got, want)
+    assert r.correct is correct, r.notes
+    if not correct:     # by the bounds, and by nothing else
+        assert {n for n in r.notes if n.startswith("not correct")} <= {
+            "not correct: failed 'within the bound'",
+            "not correct: failed 'mean within the bound'"}
+
+
+def test_accumulate_bytes_by_hand():
+    bytes_of = catalog.load_module("flops", "blend_bytes")
+    config = {"patch": [20, 256, 256], "output_patch": [16, 192, 192],
+              "model": {"out_channels": 4}}
+    window = 16 * 192 * 192 * 4
+    # sums and weights read and written, the prediction read
+    assert bytes_of.accumulate_bytes_per_patch(config) == \
+        2 * 5 * window + 4 * window == 33_030_144
+    del config["output_patch"]
+    config["model"]["out_channels"] = 3
+    assert bytes_of.accumulate_bytes_per_patch(config) == \
+        (2 * 4 + 3) * 20 * 256 * 256 * 4
+
+
+def record(**kw):
+    return RunRecord(cell={}, config=kw.pop("config", {}), traffic={},
+                     device={"kind": "TPU v5 lite"}, **kw)
+
+
+def test_memory_fill_share():
+    reduce = catalog.load_module("reducers", "memory_fill_share").reduce
+    keys = {"in_use_key": "in_use", "reserved_key": "held",
+            "limit_key": "limit"}
+    r = record(client={"in_use": [6e9, 2e9], "held": [2e9, 2e9],
+                       "limit": [16e9, 16e9]})
+    assert reduce(r, **keys) == pytest.approx(50.0)
+    # a device that keeps no statistics, or a driver that read no limit
+    assert reduce(record(client={"in_use": [0], "held": [0],
+                                 "limit": [0]}), **keys) is None
+    assert reduce(record(client={"in_use": [6e9], "held": [2e9]}),
+                  **keys) is None
+
+
+def test_scope_bytes_roofline():
+    reduce = catalog.load_module("reducers", "scope_bytes_roofline").reduce
+    args = {"scopes": ["accumulate"], "module_key": "bytes",
+            "function": "accumulate_bytes_per_patch"}
+    config = {"bytes": "blend_bytes", "patch": [20, 256, 256],
+              "output_patch": [16, 192, 192], "model": {"out_channels": 4}}
+    programs = [{"op_scopes": {"accumulate": ["scatter.1", "fusion.2"],
+                               "forward": ["fusion.1"]}}]
+    # 1 s traced: 0.2 s under `accumulate` (two ops), 0.5 s of forward
+    ops = [["fusion.1 bf16[4]", "convolution", 0, 500_000_000],
+           ["scatter.1 f32[4]", "scatter", 500_000_000, 150_000_000],
+           ["fusion.2 f32[4]", "loop fusion", 650_000_000, 50_000_000]]
+    tables = {"window_s": 1.0, "t0_ns": 0, "t1_ns": 10 ** 9,
+              "devices": [{"name": "/device:TPU:0", "ops": ops}],
+              "host": []}
+    r = record(config=config, trace=tables, programs=programs,
+               client={"patches_per_s": 50.0})
+    want = 100.0 * 50.0 * 33_030_144 / (0.2 * 819e9)
+    assert reduce(r, **args) == pytest.approx(want)
+    # nothing to read: a configuration that names no bytes module, a
+    # program from before the scopes, no op under the scope
+    assert reduce(record(config={}, trace=tables, programs=programs,
+                         client={"patches_per_s": 50.0}), **args) is None
+    assert reduce(record(config=config, trace=tables,
+                         programs=[{"op_scopes": None}],
+                         client={"patches_per_s": 50.0}), **args) is None
+    assert reduce(r, **{**args, "scopes": ["normalize"]}) is None

@@ -258,6 +258,11 @@ class _ProgramRecord:
 _LEDGER_LOCK = threading.Lock()
 _LEDGER: dict = {}  # (family, key) -> _ProgramRecord
 
+# ``inference/<name>`` gauges the patch program sets while it is traced
+# (Inferencer._trace_geometry_gauges), each a key of its programs.json entry
+GEOMETRY_GAUGES = ("output_patch_share", "patches_per_task",
+                   "accumulator_bytes", "chunk_bytes")
+
 _TRACING = threading.local()  # .gauges: dict while a first call traces
 
 
@@ -671,6 +676,8 @@ def catalog() -> list:
                 "device_kind": rec.device_kind,
                 "op_scopes": rec.op_scopes,
                 "x_fold": rec.traced.get("forward/x_fold"),
+                **{name: rec.traced.get(f"inference/{name}")
+                   for name in GEOMETRY_GAUGES},
             }
             calls, dispatch_s = rec.calls, rec.dispatch_s
             flops, nbytes = rec.flops, rec.bytes_accessed

@@ -87,8 +87,15 @@ def create_flax_engine(
     num_output_channels: int = 3,
     dtype: str = "float32",
     model_variant: str = "parity",
+    output_patch_size=None,
 ) -> Engine:
     """The native convnet engine: a Flax 3D UNet (or user model file).
+
+    The model maps a whole input patch to a prediction of the same
+    extent; ``output_patch_size`` (default: the input patch) is the
+    central part of it that ``apply`` returns, ``[m, m + pout)`` per axis
+    with ``m = (pin - pout) // 2``. The whole forward is computed and
+    then cropped (reference patch/base.py: the network's valid core).
 
     ``model_path`` may be empty (use the built-in model), a python file
     exposing ``create_model(num_input_channels, num_output_channels)`` that
@@ -105,6 +112,18 @@ def create_flax_engine(
     """
     from chunkflow_tpu.models import rsunet, unet3d
 
+    pin = tuple(int(p) for p in input_patch_size)
+    pout = pin if output_patch_size is None else tuple(
+        int(p) for p in output_patch_size)
+    if any((i - o) < 0 or (i - o) % 2 for i, o in zip(pin, pout)):
+        raise ValueError(
+            f"the flax engine crops the input patch {pin} centrally to the "
+            f"output patch {pout}: the difference must be even and not "
+            f"negative on every axis"
+        )
+    margin = tuple((i - o) // 2 for i, o in zip(pin, pout))
+    crop = (slice(None), slice(None)) + tuple(
+        slice(m, m + o) for m, o in zip(margin, pout))
     compute_dtype = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
     module = None
     if model_path:
@@ -157,7 +176,9 @@ def create_flax_engine(
         x = jnp.moveaxis(batch, 1, -1)
         y = model.apply({"params": params}, x)
         out = jnp.moveaxis(y, -1, 1)
-        return out.astype(jnp.float32)
+        # a slice over a whole axis lowers to nothing: with output patch
+        # = input patch the program is the one it was without the crop
+        return out.astype(jnp.float32)[crop]
 
     return Engine(
         params=params,
@@ -231,6 +252,7 @@ def create_engine(framework: str, **kwargs) -> Engine:
             num_output_channels=kwargs.get("num_output_channels", 3),
             dtype=kwargs.get("dtype", "float32"),
             model_variant=kwargs.get("model_variant", "parity"),
+            output_patch_size=kwargs.get("output_patch_size"),
         )
     if framework == "universal":
         return create_universal_engine(

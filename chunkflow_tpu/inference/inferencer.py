@@ -358,6 +358,36 @@ class Inferencer:
         return acc / 8.0
 
     # ------------------------------------------------------------------
+    def _trace_geometry_gauges(self, chunk) -> None:
+        """Runs while the patch program is traced for a chunk shape: what
+        the program believes it holds, as gauges and on its entry in
+        ``programs.json`` (docs/observability.md), to lay against the
+        device's own ``peak_bytes_in_use``."""
+        from chunkflow_tpu.core import profiling
+
+        voxels = int(np.prod(chunk.shape[-3:]))
+        grid = enumerate_patches(
+            chunk.shape, self.input_patch_size, self.output_patch_size,
+            self.output_patch_overlap,
+        )
+        profiling.trace_gauge(
+            "inference/output_patch_share",
+            float(np.prod(tuple(self.output_patch_size)))
+            / float(np.prod(tuple(self.input_patch_size))),
+        )
+        profiling.trace_gauge("inference/patches_per_task", grid.num_patches)
+        # the float32 sums and the weight volume, chunk-sized (without
+        # the Pallas leg's aligned-window padding)
+        profiling.trace_gauge(
+            "inference/accumulator_bytes",
+            4 * (self.num_output_channels + 1) * voxels,
+        )
+        # the chunk as it arrives; the XLA gather leg adds a float32 copy
+        profiling.trace_gauge(
+            "inference/chunk_bytes",
+            chunk.size * np.dtype(chunk.dtype).itemsize,
+        )
+
     def _build_program(self):
         import jax
 
@@ -376,6 +406,7 @@ class Inferencer:
         out_dtype = self.output_dtype
 
         def program(chunk, in_starts, out_starts, valid, params):
+            self._trace_geometry_gauges(chunk)
             out, weight = local_blend(chunk, in_starts, out_starts, valid, params)
             return normalize_blend(out, weight, out_dtype)
 
