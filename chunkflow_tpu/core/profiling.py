@@ -270,7 +270,8 @@ def trace_gauge(name: str, value: float) -> None:
     """A gauge set by code that runs while a program is traced (a model
     saying which lowering it chose from the shapes it was given): a
     telemetry gauge, and a value on the entry of the program being built
-    in ``programs.json`` (there ``forward/x_fold`` is ``x_fold``)."""
+    in ``programs.json`` (there ``forward/<name>`` and
+    ``inference/<name>`` are ``<name>``)."""
     telemetry.gauge(name, value)
     gauges = getattr(_TRACING, "gauges", None)
     if gauges is not None:
@@ -676,6 +677,11 @@ def catalog() -> list:
                 "device_kind": rec.device_kind,
                 "op_scopes": rec.op_scopes,
                 "x_fold": rec.traced.get("forward/x_fold"),
+                # what else the model's trace said of its lowering
+                # (models/rsunet.py: dec{i}_voxel_share, flops_share)
+                **{name.split("/", 1)[1]: value
+                   for name, value in rec.traced.items()
+                   if name.startswith("forward/")},
                 **{name: rec.traced.get(f"inference/{name}")
                    for name in GEOMETRY_GAUGES},
             }

@@ -13,6 +13,7 @@ engine contract explicitly designed for device-side masking, incl. TPU).
 from __future__ import annotations
 
 import importlib.util
+import inspect
 import os
 from typing import Callable, NamedTuple, Optional, Tuple
 
@@ -91,11 +92,14 @@ def create_flax_engine(
 ) -> Engine:
     """The native convnet engine: a Flax 3D UNet (or user model file).
 
-    The model maps a whole input patch to a prediction of the same
-    extent; ``output_patch_size`` (default: the input patch) is the
-    central part of it that ``apply`` returns, ``[m, m + pout)`` per axis
-    with ``m = (pin - pout) // 2``. The whole forward is computed and
-    then cropped (reference patch/base.py: the network's valid core).
+    ``output_patch_size`` (default: the input patch) is the central part
+    of the model's prediction that ``apply`` returns, ``[m, m + pout)``
+    per axis with ``m = (pin - pout) // 2`` (reference patch/base.py: the
+    network's valid core). A model whose ``__call__`` takes an
+    ``output_patch_size`` keyword (models/rsunet.py) is handed the size and
+    returns that part alone, computing only what it depends on; from any
+    other model (``UNet3D``, the ``tpu*`` variants, a user's module) the
+    whole prediction is taken and cropped here.
 
     ``model_path`` may be empty (use the built-in model), a python file
     exposing ``create_model(num_input_channels, num_output_channels)`` that
@@ -121,9 +125,6 @@ def create_flax_engine(
             f"output patch {pout}: the difference must be even and not "
             f"negative on every axis"
         )
-    margin = tuple((i - o) // 2 for i, o in zip(pin, pout))
-    crop = (slice(None), slice(None)) + tuple(
-        slice(m, m + o) for m, o in zip(margin, pout))
     compute_dtype = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
     module = None
     if model_path:
@@ -171,13 +172,22 @@ def create_flax_engine(
             model, weight_path, input_patch_size, num_input_channels
         )
 
+    # the capability is the module's to declare, whoever built it
+    crops_itself = "output_patch_size" in inspect.signature(
+        type(model).__call__).parameters
+    region = {"output_patch_size": pout} if crops_itself else {}
+
     def apply(params, batch):
         # batch: [B, C, z, y, x] float32 -> channels-last for TPU conv
         x = jnp.moveaxis(batch, 1, -1)
-        y = model.apply({"params": params}, x)
+        y = model.apply({"params": params}, x, **region)
         out = jnp.moveaxis(y, -1, 1)
-        # a slice over a whole axis lowers to nothing: with output patch
-        # = input patch the program is the one it was without the crop
+        # the central output patch of whatever extent came back. A slice
+        # over a whole axis lowers to nothing: with output patch = input
+        # patch the program is the one it was without the crop
+        crop = (slice(None), slice(None)) + tuple(
+            slice((n - o) // 2, (n - o) // 2 + o)
+            for n, o in zip(out.shape[2:], pout))
         return out.astype(jnp.float32)[crop]
 
     return Engine(

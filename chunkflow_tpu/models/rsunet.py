@@ -31,10 +31,23 @@ transitions are emitted folded as well (:class:`XFoldUp`,
 quarter of the chip's time.  Norm is folded to a per-channel affine (no
 batch statistics at inference), compute is optionally bfloat16 with
 float32 params; the final activation is computed in the output's dtype.
+
+The encoder and the bridge run on the whole patch; the decoder runs, at
+each level, on the box the requested output region depends on
+(:func:`decoder_cone`).  A deployment blends the central part of every
+prediction only (20x256x256 in, 16x192x192 out upstream), and what a
+decoder block computes further than its halo from that part nobody
+reads: there ``dec0`` runs on 61% of its voxels and ``dec1`` on 71%
+(gauges ``forward/dec{i}_voxel_share``, ``forward/flops_share``).  The
+values kept are the whole forward's: the zeros a block pads at a cut
+edge reach its halo only, which the next slice drops.  With no margin
+every box is its whole array, no slice is emitted and the program is the
+one it was.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Sequence, Tuple
 
 import flax.linen as nn
@@ -49,6 +62,10 @@ from chunkflow_tpu.models.unet3d import MxuConv, MxuConvTranspose
 Triple = Tuple[int, int, int]
 
 LANES = 128  # the minor dimension of a TPU vreg, VMEM tile and MXU pass
+EMBED_KERNEL = (1, 5, 5)
+BLOCK_KERNELS = ((1, 3, 3), (3, 3, 3), (3, 3, 3))  # RSBlock's conv1-3
+# how far a voxel of an RSBlock's result reads into its input, per axis
+BLOCK_HALO = tuple(sum(k[a] // 2 for k in BLOCK_KERNELS) for a in range(3))
 
 
 def x_fold(width0: int, x_extent: int) -> int:
@@ -226,11 +243,12 @@ class RSBlock(nn.Module):
 
     def setup(self):
         f, dt, fold = self.features, self.dtype, self.fold
-        self.conv1 = _conv(self.conv_impl, f, (1, 3, 3), dt, fold)
+        k1, k2, k3 = BLOCK_KERNELS
+        self.conv1 = _conv(self.conv_impl, f, k1, dt, fold)
         self.bn1 = Affine(f, dtype=dt, fold=fold)
-        self.conv2 = _conv(self.conv_impl, f, (3, 3, 3), dt, fold)
+        self.conv2 = _conv(self.conv_impl, f, k2, dt, fold)
         self.bn2 = Affine(f, dtype=dt, fold=fold)
-        self.conv3 = _conv(self.conv_impl, f, (3, 3, 3), dt, fold)
+        self.conv3 = _conv(self.conv_impl, f, k3, dt, fold)
         self.bn3 = Affine(f, dtype=dt, fold=fold)
 
     def __call__(self, x):
@@ -239,6 +257,85 @@ class RSBlock(nn.Module):
         x = nn.relu(self.bn2(self.conv2(x)))
         x = nn.relu(self.bn3(self.conv3(x)) + residual)
         return x
+
+
+Box = Tuple[Tuple[int, int], ...]  # (lo, hi) on z, y, x, in a level's voxels
+
+
+def _voxels(box: Box) -> int:
+    return math.prod(hi - lo for lo, hi in box)
+
+
+def _whole(shape: Triple) -> Box:
+    return tuple((0, n) for n in shape)
+
+
+def decoder_cone(shapes: Sequence[Triple], region: Box, down_factors, folds,
+                 halo: Triple):
+    """What of each level (``shapes[i]``: its extent) the output ``region``
+    of a patch depends on, walking the decoder from the head back.
+
+    Returns one ``(box, want)`` a level, level 0 first, the bridge's last,
+    in that level's own voxels: ``want`` is the part of the level's result
+    that is read (by ``out`` at level 0, by ``up{i-1}`` below), ``box``
+    the part of ``up{i} + skip`` its block has to run on, which is
+    ``want`` grown by the block's ``halo``, clipped to the array and
+    rounded out to what can be sliced with no relayout: whole x-fold
+    blocks of this level, and whole windows of ``up{i}`` (kernel ==
+    stride), so that the level below emits exactly the box. An axis is
+    cut only where that drops more than the halo the cut costs (on the
+    chip a level-2 box of 60 in 64 was slower than the whole level, and
+    every rounding wider than this one slower still: PERF.md, PR 27).
+    Where the region is the whole patch every box is its whole array;
+    the bridge's box always is (the encoder's pools read everything)."""
+    want = tuple((lo // s * s, -(-hi // s) * s)
+                 for (lo, hi), s in zip(region, (1, 1, folds[0])))
+    cone = []
+    for i, factor in enumerate(down_factors):
+        step = (factor[0], factor[1],
+                math.lcm(folds[i], factor[2] * folds[i + 1]))
+        box = tuple((max(lo - h, 0) // s * s, -(-min(hi + h, n) // s) * s)
+                    for (lo, hi), h, n, s in zip(want, halo, shapes[i], step))
+        box = tuple((lo, hi) if max(lo, n - hi) > h else (0, n)
+                    for (lo, hi), h, n in zip(box, halo, shapes[i]))
+        cone.append((box, want))
+        want = tuple((lo // f, hi // f) for (lo, hi), f in zip(box, factor))
+    cone.append((_whole(shapes[-1]), want))
+    return cone
+
+
+def _crop(x, want: Box, held: Box, fold: int = 1):
+    """``x`` ([B,z,y,X/fold,fold*C]) holds the box ``held`` of its level:
+    the part ``want`` of it. Where they are the same nothing is emitted."""
+    if want == held:
+        return x
+    (z0, z1), (y0, y1), (x0, x1) = (
+        (lo - origin, hi - origin)
+        for (lo, hi), (origin, _) in zip(want, held))
+    return x[:, z0:z1, y0:y1, x0 // fold:x1 // fold]
+
+
+def forward_flops(width, in_channels: int, out_channels: int,
+                  level_voxels, dec_voxels, out_voxels: int) -> int:
+    """Operations of one patch forward from shapes: 2 x taps x Cin x Cout
+    for every result voxel of every convolution, 2 x Cin x Cout for every
+    voxel an upsampling emits (kernel == stride: one tap each).
+    ``level_voxels[i]``: the voxels of level i (encoder and bridge run on
+    all of them); ``dec_voxels[i]``: those ``up{i}`` emits and ``dec{i}``
+    runs on; ``out_voxels``: those of the head."""
+    taps = [math.prod(k) for k in BLOCK_KERNELS]
+
+    def block(c_in, w, v):
+        return 2 * v * (taps[0] * c_in * w + (taps[1] + taps[2]) * w * w)
+
+    total = 2 * level_voxels[0] * math.prod(EMBED_KERNEL) \
+        * in_channels * width[0]
+    for i, v in enumerate(dec_voxels):
+        total += block(width[max(i - 1, 0)], width[i], level_voxels[i])
+        total += 2 * v * width[i + 1] * width[i]  # up{i}
+        total += block(width[i], width[i], v)     # dec{i}
+    total += block(width[-2], width[-1], level_voxels[-1])  # bridge
+    return total + 2 * out_voxels * width[0] * out_channels
 
 
 class RSUNet(nn.Module):
@@ -259,7 +356,12 @@ class RSUNet(nn.Module):
     conv_impl: str = "native"  # "mxu": same params, 2D/GEMM lowering
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, output_patch_size=None):
+        """``x``: [B, z, y, x, in_channels]. Returns the prediction over the
+        central ``output_patch_size`` of it (default: all of it). The
+        decoder runs on the region's cone of dependence only
+        (:func:`decoder_cone`); every value returned is the one the whole
+        forward has there."""
         depth = len(self.width)
         assert len(self.down_factors) == depth - 1
         dt, impl = self.dtype, self.conv_impl
@@ -267,6 +369,19 @@ class RSUNet(nn.Module):
         fold = 1 if impl == "mxu" else x_fold(self.width[0], x.shape[-2])
         profiling.trace_gauge("forward/x_fold", fold)
         folds = [fold] + [1] * (depth - 1)
+        shapes = [tuple(x.shape[1:4])]
+        for factor in self.down_factors:
+            shapes.append(tuple(n // f for n, f in zip(shapes[-1], factor)))
+        size = shapes[0] if output_patch_size is None else tuple(
+            int(o) for o in output_patch_size)
+        if any(not 0 < o <= n for o, n in zip(size, shapes[0])):
+            raise ValueError(
+                f"no output patch {size} in a patch {shapes[0]}")
+        region = tuple(((n - o) // 2, (n - o) // 2 + o)
+                       for n, o in zip(shapes[0], size))
+        cone = decoder_cone(shapes, region, self.down_factors, folds,
+                            BLOCK_HALO)
+        self._trace_cone_gauges(shapes, cone)
 
         def block(i, name):
             return RSBlock(self.width[i], dtype=dt, conv_impl=impl,
@@ -274,7 +389,8 @@ class RSUNet(nn.Module):
 
         orig_dtype = x.dtype
         x = fold_x(x.astype(dt), fold)
-        x = _conv(impl, self.width[0], (1, 5, 5), dt, fold, name="embed")(x)
+        x = _conv(impl, self.width[0], EMBED_KERNEL, dt, fold,
+                  name="embed")(x)
         skips = []
         for i in range(depth - 1):
             x = block(i, f"enc{i}")(x)
@@ -289,6 +405,9 @@ class RSUNet(nn.Module):
         x = block(depth - 1, "bridge")(x)
         for i in reversed(range(depth - 1)):
             factor = self.down_factors[i]
+            box, _ = cone[i]
+            held, want = cone[i + 1]
+            x = _crop(x, want, held, folds[i + 1])
             if impl == "mxu":
                 x = MxuConvTranspose(self.width[i], factor=factor, dtype=dt,
                                      name=f"up{i}")(x)
@@ -299,13 +418,31 @@ class RSUNet(nn.Module):
             else:
                 x = XFoldUp(self.width[i], factor=factor, dtype=dt,
                             fold=folds[i], name=f"up{i}")(x)
-            x = x + skips[i]
+            x = x + _crop(skips[i], box, _whole(shapes[i]), folds[i])
             x = block(i, f"dec{i}")(x)
+        held, want = cone[0]
+        x = _crop(x, want, held, fold)
         x = _conv(impl, self.out_channels, (1, 1, 1), dt, fold, name="out")(x)
         # the activation in the output's dtype: what the chip computed all
         # along while head, sigmoid and cast were one fusion (XLA keeps
         # excess precision inside one), now that a copy lies between them
-        x = unfold_x(x, fold).astype(orig_dtype)
+        x = _crop(unfold_x(x, fold), region, want).astype(orig_dtype)
         if self.final_activation == "sigmoid":
             x = nn.sigmoid(x)
         return x
+
+    def _trace_cone_gauges(self, shapes, cone) -> None:
+        """Says how much of the decoder the traced forward runs: a gauge a
+        decoder level (voxels its block runs on over the level's; 1.0
+        where nothing is cut) and the forward's FLOPs over those of the
+        whole patch (docs/observability.md)."""
+        level_voxels = [math.prod(shape) for shape in shapes]
+        dec_voxels = [_voxels(box) for box, _ in cone[:-1]]
+        for i, (cut, whole) in enumerate(zip(dec_voxels, level_voxels)):
+            profiling.trace_gauge(f"forward/dec{i}_voxel_share", cut / whole)
+        counts = (self.width, self.in_channels, self.out_channels,
+                  level_voxels)
+        profiling.trace_gauge(
+            "forward/flops_share",
+            forward_flops(*counts, dec_voxels, _voxels(cone[0][1]))
+            / forward_flops(*counts, level_voxels[:-1], level_voxels[0]))

@@ -29,6 +29,10 @@ PIN, POUT, OVERLAP = (8, 32, 32), (4, 16, 16), (2, 8, 8)
 MARGIN = (2, 8, 8)
 ALIGNED = (10, 40, 40)      # 2x2x2 patches on the stride 2x8x8
 SNAPPED = (11, 42, 45)      # 3x3x3, the last of each axis snapped flush
+# a margin wide enough that the RSUNet leaves part of level 1 out as well
+# (models/rsunet.py decoder_cone); 2x2x2 patches on the stride 2x24x24
+PIN_WIDE, POUT_WIDE, MARGIN_WIDE = (8, 80, 80), (4, 32, 32), (2, 24, 24)
+ALIGNED_WIDE = (10, 104, 104)
 
 
 def make_inferencer(**kwargs):
@@ -45,7 +49,7 @@ def image(shape, seed=0):
         0, 256, shape, dtype=np.uint8)
 
 
-def plain_output(inferencer, array, pout=POUT):
+def plain_output(inferencer, array, pout=POUT, pin=PIN):
     """The blended output over the chunk's whole output frame, from the
     plain reference given the engine's own parameters."""
     reference = catalog.load_module("reference", "rsunet_crop")
@@ -57,10 +61,10 @@ def plain_output(inferencer, array, pout=POUT):
         out = forward(params, window[None, ..., None])
         return np.moveaxis(np.asarray(out[0]), -1, 0)
 
-    margin = [(i - o) // 2 for i, o in zip(PIN, pout)]
+    margin = [(i - o) // 2 for i, o in zip(pin, pout)]
     box = (tuple(margin),
            tuple(s - m for s, m in zip(array.shape, margin)))
-    return crop_blend.blend_box(array, PIN, pout, OVERLAP, box, one_patch)
+    return crop_blend.blend_box(array, pin, pout, OVERLAP, box, one_patch)
 
 
 @pytest.mark.parametrize("channels, dtype, shape, batch", [
@@ -92,6 +96,30 @@ def test_flax_engine_with_a_cropped_output_patch_equals_the_reference(
                              else (8e-3, 1e-3))
     assert gap.max() < max_bound and gap.mean() < mean_bound
     # a patch put one output stride off, or not cropped, reads ~0.05
+
+
+@pytest.mark.parametrize("channels, dtype, batch", [
+    (3, "float32", 4), (1, "bfloat16", 3), (4, "bfloat16", 8)])
+def test_a_cone_that_cuts_two_levels_equals_the_reference(
+        channels, dtype, batch):
+    """As above at 8x80x80 -> 4x32x32: the decoder's levels 0 and 1 run
+    on the output patch's cone of dependence only."""
+    inferencer = make_inferencer(
+        input_patch_size=PIN_WIDE, output_patch_size=POUT_WIDE,
+        num_output_channels=channels, dtype=dtype, batch_size=batch)
+    array = image(ALIGNED_WIDE, seed=channels)
+    out = inferencer(Chunk(array))
+    want, n_patches = plain_output(inferencer, array, POUT_WIDE, PIN_WIDE)
+    assert n_patches == 8
+    got = np.asarray(out.array, np.float64)
+    assert got.shape == want.shape == (channels,) + tuple(
+        s - 2 * m for s, m in zip(ALIGNED_WIDE, MARGIN_WIDE))
+    assert tuple(out.voxel_offset) == MARGIN_WIDE
+    assert want.std() > 1e-3
+    gap = np.abs(got - want)
+    max_bound, mean_bound = ((5e-5, 5e-6) if dtype == "float32"
+                             else (8e-3, 1e-3))
+    assert gap.max() < max_bound and gap.mean() < mean_bound
 
 
 def test_lower_precision_fails_the_float32_bounds():
@@ -214,6 +242,72 @@ def test_the_program_says_what_it_holds(monkeypatch, tmp_path):
             "inference/output_patch_share"] == 1.0
     finally:
         telemetry.reset()
+
+
+@pytest.mark.parametrize("pin, pout, cut", [
+    (PIN, POUT, (True, False)),              # level 0 alone
+    (PIN_WIDE, POUT_WIDE, (True, True)),     # level 1 as well
+    (PIN, PIN, (False, False)),              # no margin: 1.0 / 1.0 / 1.0
+])
+def test_the_program_says_how_much_of_the_decoder_it_runs(
+        monkeypatch, tmp_path, pin, pout, cut):
+    """``forward/dec{i}_voxel_share`` and ``forward/flops_share`` of the
+    RSUNet's trace, on the patch program's ``programs.json`` entry."""
+    monkeypatch.delenv("CHUNKFLOW_TELEMETRY", raising=False)
+    telemetry.reset()
+    try:
+        telemetry.configure(str(tmp_path))
+        make_inferencer(input_patch_size=pin, output_patch_size=pout)(
+            Chunk(image(pin)))
+        (entry,) = [e for e in profiling.catalog()
+                    if e["label"] == "inferencer"]
+        gauges = telemetry.snapshot()["gauges"]
+        shares = [entry["dec0_voxel_share"], entry["dec1_voxel_share"],
+                  entry["flops_share"]]
+        assert shares == [gauges["forward/dec0_voxel_share"],
+                          gauges["forward/dec1_voxel_share"],
+                          gauges["forward/flops_share"]]
+        assert [share < 1 for share in shares] == [*cut, any(cut)]
+        assert all(0.2 < share <= 1.0 for share in shares)
+        assert entry["x_fold"] == 4
+    finally:
+        telemetry.reset()
+
+
+MODEL_FILE = os.path.join(BENCH_DIR, "configs", "rsunet-deepem.model.py")
+
+
+@pytest.mark.parametrize("model_path, variant, itself", [
+    ("", "rsunet", True),
+    (MODEL_FILE, "parity", True),   # a user file that returns the RSUNet
+    ("", "parity", False),
+    ("", "tpu", False),
+])
+def test_a_module_that_takes_the_output_patch_is_handed_it(
+        model_path, variant, itself):
+    """The engine asks the module, not its name: one whose ``__call__``
+    takes ``output_patch_size`` returns the output patch itself, every
+    other module's whole prediction is cropped by the engine; either way
+    ``apply`` gives the central part of the whole forward."""
+    import jax
+    import jax.numpy as jnp
+
+    engine = create_flax_engine(model_path, None, PIN, 1, 3,
+                                model_variant=variant,
+                                output_patch_size=POUT)
+    whole = create_flax_engine(model_path, None, PIN, 1, 3,
+                               model_variant=variant)
+    batch = jnp.asarray(image((2, 1) + PIN), jnp.float32) / 255
+    telemetry.reset()
+    try:
+        got = np.asarray(jax.jit(engine.apply)(engine.params, batch))
+        gauges = telemetry.snapshot()["gauges"]
+    finally:
+        telemetry.reset()
+    want = np.asarray(jax.jit(whole.apply)(engine.params, batch))
+    assert got.shape == (2, 3) + POUT
+    assert np.abs(got - want[:, :, 2:6, 8:24, 8:24]).max() <= 1e-6
+    assert (gauges.get("forward/dec0_voxel_share", 1.0) < 1) == itself
 
 
 def test_the_tutorials_production_command_line_parses():

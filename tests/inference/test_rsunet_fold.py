@@ -164,3 +164,164 @@ def test_folded_kernel_holds_published_weights_and_zeros(kernel_size, fold):
                 np.testing.assert_array_equal(
                     blocks[:, :, t + lo, p_in, :, p_out, :],
                     np.broadcast_to(want, blocks.shape[:2] + (cin, cout)))
+
+
+# -- the decoder runs on the output patch's cone of dependence only ---------
+
+# name -> (input patch, output patch): margins that cut level 0 alone, level
+# 1 too, that are odd and no multiple of a fold, zero on one axis, zero on all
+REGIONS = {
+    "level0": ((8, 32, 32), (4, 16, 16)),
+    "level1": ((8, 80, 80), (4, 32, 32)),
+    "odd": ((8, 64, 64), (6, 46, 42)),        # margins 1, 9, 11
+    "off-fold": ((8, 64, 64), (4, 34, 38)),   # margins 2, 15, 13
+    "y-only": ((8, 64, 64), (8, 28, 64)),     # margins 0, 18, 0
+    "x-only": ((8, 64, 64), (8, 64, 32)),     # margins 0, 0, 16
+    "none": ((8, 32, 32), (8, 32, 32)),
+}
+
+
+def _forward_gauges(model, params, x, size):
+    """The result with an output region and the ``forward/*`` gauges its
+    trace set."""
+    from chunkflow_tpu.core import telemetry
+
+    telemetry.reset()
+    try:
+        out = model.apply(params, x, output_patch_size=size)
+        gauges = telemetry.snapshot()["gauges"]
+    finally:
+        telemetry.reset()
+    return np.asarray(out), {k.split("/", 1)[1]: v for k, v in gauges.items()
+                             if k.startswith("forward/")}
+
+
+@pytest.mark.parametrize("region", sorted(REGIONS))
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+def test_output_region_equals_the_whole_forward_cropped(widths, region):
+    pin, pout = REGIONS[region]
+    model = rsunet.RSUNet(width=WIDTHS[widths])
+    params = _params(widths, DOWN)
+    x = jax.random.uniform(jax.random.PRNGKey(5), (2,) + pin + (1,))
+    margin = [(i - o) // 2 for i, o in zip(pin, pout)]
+    inner = (slice(None),) + tuple(
+        slice(m, m + o) for m, o in zip(margin, pout))
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(model.apply(params, x))[inner]
+        got, gauges = _forward_gauges(model, params, x, pout)
+    assert got.shape == want.shape == (2,) + pout + (3,)
+    assert 0.05 < want.std()
+    assert np.abs(got - want).max() <= 1e-6
+    # and something was left out, wherever a margin is wider than the halo
+    cut = [gauges[f"dec{i}_voxel_share"] for i in range(3)]
+    assert all(0 < share <= 1 for share in cut) and cut[2] == 1.0
+    if region == "none":
+        assert cut == [1.0, 1.0, 1.0] and gauges["flops_share"] == 1.0
+    else:
+        assert cut[0] < 1 and gauges["flops_share"] < 1
+        if region.startswith("level"):
+            assert (cut[1] < 1) == (region == "level1")
+
+
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+def test_without_a_margin_the_forward_is_the_one_it_was(widths):
+    """Every box is its whole array and no slice is emitted: the jaxpr
+    with the whole patch as output region is the jaxpr without one."""
+    model = rsunet.RSUNet(width=WIDTHS[widths], dtype=jnp.bfloat16)
+    params = _params(widths, DOWN)
+    x = jnp.zeros((2, 8, 32, 32, 1))
+    plain = jax.make_jaxpr(lambda p, v: model.apply(p, v))(params, x)
+    whole = jax.make_jaxpr(lambda p, v: model.apply(
+        p, v, output_patch_size=(8, 32, 32)))(params, x)
+    assert str(plain) == str(whole)
+    cut = jax.make_jaxpr(lambda p, v: model.apply(
+        p, v, output_patch_size=(4, 16, 16)))(params, x)
+    assert str(cut) != str(plain)
+
+
+@pytest.mark.parametrize("size", [(9, 32, 32), (8, 0, 32), (8, 32, 34)])
+def test_an_output_patch_outside_the_patch_raises(size):
+    model = rsunet.RSUNet()
+    x = jnp.zeros((1, 8, 32, 32, 1))
+    with pytest.raises(ValueError, match="no output patch"):
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), x,
+                                          output_patch_size=size))
+
+
+def _levels(patch):
+    shapes = [patch]
+    for factor in DOWN:
+        shapes.append(tuple(n // f for n, f in zip(shapes[-1], factor)))
+    return shapes
+
+
+@pytest.mark.parametrize("patch,region,fold,boxes", [
+    # the production geometry, 20x256x256 -> 16x192x192 at F = 4 (PERF.md)
+    ((20, 256, 256), ((2, 18), (32, 224), (32, 224)), 4, [
+        ((0, 20), (28, 228), (28, 228)),
+        ((0, 20), (10, 118), (10, 118)),
+        ((0, 10), (0, 64), (0, 64)),   # 2 of 64 a side: under the halo
+        ((0, 5), (0, 32), (0, 32))]),
+    # the same at F = 8: the x box rounds out to whole blocks of 8
+    ((20, 256, 256), ((2, 18), (32, 224), (32, 224)), 8, [
+        ((0, 20), (28, 228), (24, 232)),
+        ((0, 20), (10, 118), (8, 120)),
+        ((0, 10), (0, 64), (0, 64)),
+        ((0, 5), (0, 32), (0, 32))]),
+    # a margin as wide as the halo cuts nothing: z here, and all of "none"
+    ((8, 32, 32), ((2, 6), (8, 24), (8, 24)), 4, [
+        ((0, 8), (4, 28), (4, 28)),
+        ((0, 8), (0, 16), (0, 16)),
+        ((0, 4), (0, 8), (0, 8)),
+        ((0, 2), (0, 4), (0, 4))]),
+    ((8, 32, 32), ((0, 8), (0, 32), (0, 32)), 4, [
+        ((0, 8), (0, 32), (0, 32)),
+        ((0, 8), (0, 16), (0, 16)),
+        ((0, 4), (0, 8), (0, 8)),
+        ((0, 2), (0, 4), (0, 4))]),
+    # an odd region: grown by (2, 3, 3), then out to even rows and blocks
+    ((8, 64, 64), ((1, 7), (9, 55), (11, 53)), 4, [
+        ((0, 8), (6, 58), (4, 60)),
+        ((0, 8), (0, 32), (0, 32)),
+        ((0, 4), (0, 16), (0, 16)),
+        ((0, 2), (0, 8), (0, 8))]),
+])
+def test_the_cone_of_dependence(patch, region, fold, boxes):
+    shapes = _levels(patch)
+    cone = rsunet.decoder_cone(shapes, region, DOWN, [fold, 1, 1, 1],
+                               rsunet.BLOCK_HALO)
+    assert [box for box, _ in cone] == boxes
+    assert rsunet.BLOCK_HALO == (2, 3, 3)
+    for i, ((box, want), shape) in enumerate(zip(cone, shapes)):
+        for (lo, hi), (w_lo, w_hi), n in zip(box, want, shape):
+            assert 0 <= lo <= w_lo < w_hi <= hi <= n
+        if i < len(DOWN):  # the level below emits exactly the box
+            assert cone[i + 1][1] == tuple(
+                (lo // f, hi // f) for (lo, hi), f in zip(box, DOWN[i]))
+            assert all(lo % f == 0 and hi % f == 0
+                       for (lo, hi), f in zip(box, DOWN[i]))
+    assert cone[0][0][2][0] % fold == 0 and cone[0][0][2][1] % fold == 0
+
+
+@pytest.mark.parametrize("config", ["rsunet-superhuman", "rsunet-deepem",
+                                    "rsunet-superhuman-prod"])
+def test_the_modules_whole_patch_flops_are_the_benchmarks(config):
+    """``forward/flops_share`` is over the count of
+    ``benchmarks/flops/rsunet.py``: the module's own formula gives the
+    same number for the whole patch of every configuration."""
+    import importlib.util
+    import json
+
+    bench = os.path.join(os.path.dirname(__file__), "..", "..", "benchmarks")
+    spec = importlib.util.spec_from_file_location(
+        "bench_flops_rsunet", os.path.join(bench, "flops", "rsunet.py"))
+    flops = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(flops)
+    with open(os.path.join(bench, "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    voxels = [int(np.prod(shape)) for shape in _levels(tuple(cfg["patch"]))]
+    model = cfg["model"]
+    assert [tuple(f) for f in model["pooling"]] == list(DOWN)
+    assert rsunet.forward_flops(
+        model["width"], model["in_channels"], model["out_channels"],
+        voxels, voxels[:-1], voxels[0]) == flops.flops_per_patch(cfg)

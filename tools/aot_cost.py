@@ -17,6 +17,7 @@ it; and 1.09 for a build whose pool it mis-sized 13x.
     JAX_PLATFORMS=cpu python tools/aot_cost.py rsunet-superhuman [--top 25]
 """
 import argparse
+import functools
 import json
 import os
 import re
@@ -61,7 +62,8 @@ def module_of(op_name: str) -> str:
 
 def compile_forward(config: dict, batch: int):
     """The configuration's forward (``RSUNet.apply`` on one batch of
-    patches) compiled for one chip of a described v5e."""
+    patches, returning the configuration's output patch) compiled for one
+    chip of a described v5e."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
     import jax.numpy as jnp
@@ -86,7 +88,9 @@ def compile_forward(config: dict, batch: int):
     params, x = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
         (params, jax.ShapeDtypeStruct(shape, jnp.float32)))
-    return jax.jit(model.apply).lower(params, x).compile()
+    forward = functools.partial(
+        model.apply, output_patch_size=config.get("output_patch"))
+    return jax.jit(forward).lower(params, x).compile()
 
 
 def main(argv=None) -> int:
