@@ -913,8 +913,9 @@ def capture(seconds: float, reason: str, force: bool = False,
             target=_run_capture, args=(target, seconds, reason),
             name=f"chunkflow-profile-{seq}",
         )
-        _CAPTURE_THREADS.append(thread)
-        thread.start()
+        with _STATE_LOCK:  # wait_for_captures never sees it unstarted
+            thread.start()
+            _CAPTURE_THREADS.append(thread)
         return target, None
     ok = _run_capture(target, seconds, reason)
     return (target, None) if ok else (None, "capture failed (see events)")
@@ -991,11 +992,14 @@ def note_stall(phase: str, share: float) -> None:
 def wait_for_captures(timeout: float = 10.0) -> None:
     """Join outstanding background capture threads (tests, teardown)."""
     deadline = time.monotonic() + timeout
-    for thread in list(_CAPTURE_THREADS):
+    with _STATE_LOCK:
+        threads = list(_CAPTURE_THREADS)
+    for thread in threads:
         thread.join(timeout=max(0.0, deadline - time.monotonic()))
-    _CAPTURE_THREADS[:] = [
-        t for t in _CAPTURE_THREADS if t.is_alive()
-    ]
+    with _STATE_LOCK:
+        _CAPTURE_THREADS[:] = [
+            t for t in _CAPTURE_THREADS if t.is_alive()
+        ]
 
 
 # ---------------------------------------------------------------------------

@@ -2410,9 +2410,10 @@ def copy_var_cmd(op_name, from_name, to_name):
                    "stays float32 either way)")
 @click.option(
     "--model-variant",
-    type=click.Choice(["parity", "rsunet", "tpu", "tpu_mxu", "tpu_s2d4"]),
+    type=click.Choice(["parity", "rsunet"]),
     default="parity",
-    help="parity: reference-class UNet (torch-convertible); tpu: space-to-depth MXU-optimized flagship",
+    help="parity: reference-class UNet (torch-convertible); rsunet: the "
+         "production RSUNet mirror, x-folded at full resolution",
 )
 @click.option(
     "--sharding",

@@ -98,8 +98,8 @@ def create_flax_engine(
     network's valid core). A model whose ``__call__`` takes an
     ``output_patch_size`` keyword (models/rsunet.py) is handed the size and
     returns that part alone, computing only what it depends on; from any
-    other model (``UNet3D``, the ``tpu*`` variants, a user's module) the
-    whole prediction is taken and cropped here.
+    other model (``UNet3D``, a user's module) the whole prediction is
+    taken and cropped here.
 
     ``model_path`` may be empty (use the built-in model), a python file
     exposing ``create_model(num_input_channels, num_output_channels)`` that
@@ -108,11 +108,8 @@ def create_flax_engine(
     whose weights are converted by name into the Flax mirror selected by
     ``model_variant``. ``weight_path`` may be a ``.pt`` torch state dict
     (converted) or an orbax/msgpack flax checkpoint. ``model_variant``:
-    'parity' is the reference-class UNet; 'rsunet' the production RSUNet
-    mirror (models/rsunet.py); 'tpu' the space-to-depth flagship
-    (unet3d.create_tpu_optimized_model); 'tpu_mxu' the same flagship with
-    every conv lowered as z-decomposed 2D convs / GEMM upsampling
-    (identical parameters, different XLA lowering).
+    'parity' is the reference-class UNet (models/unet3d.py); 'rsunet' the
+    production RSUNet mirror (models/rsunet.py). Any other name raises.
     """
     from chunkflow_tpu.models import rsunet, unet3d
 
@@ -132,29 +129,22 @@ def create_flax_engine(
 
     if module is not None and hasattr(module, "create_model"):
         model = module.create_model(num_input_channels, num_output_channels)
-    elif model_variant in ("tpu", "tpu_mxu", "tpu_s2d4"):
-        model = unet3d.create_tpu_optimized_model(
-            in_channels=num_input_channels,
-            out_channels=num_output_channels,
-            dtype=compute_dtype,
-            # same parameters, different XLA lowering (z-decomposed 2D
-            # convs + GEMM upsampling) — see unet3d.MxuConv
-            conv_impl="mxu" if model_variant == "tpu_mxu" else "native",
-            # aggressive stem: 112-256 channels at 1/16 positions
-            s2d_factor=(1, 4, 4) if model_variant == "tpu_s2d4"
-            else (1, 2, 2),
-        )
     elif model_variant == "rsunet":
         model = rsunet.RSUNet(
             in_channels=num_input_channels,
             out_channels=num_output_channels,
             dtype=compute_dtype,
         )
-    else:
+    elif model_variant == "parity":
         model = unet3d.UNet3D(
             in_channels=num_input_channels,
             out_channels=num_output_channels,
             dtype=compute_dtype,
+        )
+    else:
+        raise ValueError(
+            f"unknown model_variant {model_variant!r}: the built-in models "
+            f"are 'parity' and 'rsunet'"
         )
 
     if module is not None and not hasattr(module, "create_model"):

@@ -57,7 +57,6 @@ from flax.linen.dtypes import promote_dtype
 from jax import lax
 
 from chunkflow_tpu.core import profiling
-from chunkflow_tpu.models.unet3d import MxuConv, MxuConvTranspose
 
 Triple = Tuple[int, int, int]
 
@@ -204,16 +203,6 @@ def max_pool_folded(x, factor: Triple, fold: int):
          for g in range(fold // fx)], axis=-1)
 
 
-def _conv(conv_impl: str, features: int, kernel_size: Triple, dtype,
-          fold: int, name=None):
-    """The convolution of a lowering: identical parameter trees, so
-    ``conv_impl`` is a pure lowering choice. "mxu" is never folded."""
-    if conv_impl == "mxu":
-        return MxuConv(features, kernel_size, dtype=dtype, name=name)
-    return XFoldConv(features, kernel_size, dtype=dtype, fold=fold,
-                     name=name)
-
-
 class Affine(nn.Module):
     """Per-channel scale + bias: an inference-time BatchNorm3d, with the
     running statistics folded in by the converter."""
@@ -238,17 +227,16 @@ class RSBlock(nn.Module):
 
     features: int
     dtype: jnp.dtype = jnp.float32
-    conv_impl: str = "native"
     fold: int = 1
 
     def setup(self):
         f, dt, fold = self.features, self.dtype, self.fold
         k1, k2, k3 = BLOCK_KERNELS
-        self.conv1 = _conv(self.conv_impl, f, k1, dt, fold)
+        self.conv1 = XFoldConv(f, k1, dtype=dt, fold=fold)
         self.bn1 = Affine(f, dtype=dt, fold=fold)
-        self.conv2 = _conv(self.conv_impl, f, k2, dt, fold)
+        self.conv2 = XFoldConv(f, k2, dtype=dt, fold=fold)
         self.bn2 = Affine(f, dtype=dt, fold=fold)
-        self.conv3 = _conv(self.conv_impl, f, k3, dt, fold)
+        self.conv3 = XFoldConv(f, k3, dtype=dt, fold=fold)
         self.bn3 = Affine(f, dtype=dt, fold=fold)
 
     def __call__(self, x):
@@ -353,7 +341,6 @@ class RSUNet(nn.Module):
     down_factors: Sequence[Triple] = ((1, 2, 2), (2, 2, 2), (2, 2, 2))
     dtype: jnp.dtype = jnp.float32
     final_activation: str = "sigmoid"
-    conv_impl: str = "native"  # "mxu": same params, 2D/GEMM lowering
 
     @nn.compact
     def __call__(self, x, output_patch_size=None):
@@ -364,9 +351,9 @@ class RSUNet(nn.Module):
         forward has there."""
         depth = len(self.width)
         assert len(self.down_factors) == depth - 1
-        dt, impl = self.dtype, self.conv_impl
+        dt = self.dtype
         # level i runs x-folded by folds[i]; only level 0 folds so far
-        fold = 1 if impl == "mxu" else x_fold(self.width[0], x.shape[-2])
+        fold = x_fold(self.width[0], x.shape[-2])
         profiling.trace_gauge("forward/x_fold", fold)
         folds = [fold] + [1] * (depth - 1)
         shapes = [tuple(x.shape[1:4])]
@@ -384,13 +371,13 @@ class RSUNet(nn.Module):
         self._trace_cone_gauges(shapes, cone)
 
         def block(i, name):
-            return RSBlock(self.width[i], dtype=dt, conv_impl=impl,
-                           fold=folds[i], name=name)
+            return RSBlock(self.width[i], dtype=dt, fold=folds[i],
+                           name=name)
 
         orig_dtype = x.dtype
         x = fold_x(x.astype(dt), fold)
-        x = _conv(impl, self.width[0], EMBED_KERNEL, dt, fold,
-                  name="embed")(x)
+        x = XFoldConv(self.width[0], EMBED_KERNEL, dtype=dt, fold=fold,
+                      name="embed")(x)
         skips = []
         for i in range(depth - 1):
             x = block(i, f"enc{i}")(x)
@@ -408,10 +395,7 @@ class RSUNet(nn.Module):
             box, _ = cone[i]
             held, want = cone[i + 1]
             x = _crop(x, want, held, folds[i + 1])
-            if impl == "mxu":
-                x = MxuConvTranspose(self.width[i], factor=factor, dtype=dt,
-                                     name=f"up{i}")(x)
-            elif folds[i] % factor[2]:
+            if folds[i] % factor[2]:
                 x = fold_x(nn.ConvTranspose(
                     self.width[i], kernel_size=factor, strides=factor,
                     dtype=dt, name=f"up{i}")(x), folds[i])
@@ -422,7 +406,8 @@ class RSUNet(nn.Module):
             x = block(i, f"dec{i}")(x)
         held, want = cone[0]
         x = _crop(x, want, held, fold)
-        x = _conv(impl, self.out_channels, (1, 1, 1), dt, fold, name="out")(x)
+        x = XFoldConv(self.out_channels, (1, 1, 1), dtype=dt, fold=fold,
+                      name="out")(x)
         # the activation in the output's dtype: what the chip computed all
         # along while head, sigmoid and cast were one fusion (XLA keeps
         # excess precision inside one), now that a copy lies between them

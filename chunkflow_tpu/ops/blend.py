@@ -68,8 +68,8 @@ def fused_pipeline_mode() -> str:
     ``interpret`` runs them under the Pallas interpreter (+kernelcheck)
     on CPU — it IS the parity leg, not a throughput proxy. Default OFF
     per the measured-winner rule (docs/performance.md): its on-chip
-    speed against the separate programs is not measured; the CPU
-    structure gate is ``bench.py fused_pipeline``.
+    speed against the separate programs is not measured; its bit-identity
+    with them is held by tests/inference/test_fused_pipeline.py.
 
     Resolution shares :func:`core.envmode.resolve` (warn-once; a typo
     must not force-select Mosaic kernels on a CPU box)."""
@@ -117,12 +117,11 @@ def pipeline_kernel_cost(B: int, ci: int, co: int, pin, pout,
     """Analytic cost of one fused-pipeline patch step over a batch of
     ``B`` patches — the builders' own arithmetic composed
     (``pallas_gather.gather_kernel_cost`` +
-    ``pallas_blend.fused_kernel_cost``), for ``profiling.stamp_cost``,
-    ``tools/kernel_report.py`` and the ``bench.py fused_pipeline``
-    stamps. The kernels run as sequential stages of one program, so
-    VMEM is the max stage footprint, not the sum; ``bytes_accessed`` is
-    the traffic the pipeline fundamentally moves (gather reads + the
-    aligned-window RMW).
+    ``pallas_blend.fused_kernel_cost``), for ``profiling.stamp_cost``
+    and ``tools/kernel_report.py``. The kernels run as sequential stages
+    of one program, so VMEM is the max stage footprint, not the sum;
+    ``bytes_accessed`` is the traffic the pipeline fundamentally moves
+    (gather reads + the aligned-window RMW).
 
     ``hbm_intermediate_bytes`` is the inter-stage stack traffic the
     SEPARATE-programs composition pays and the pipeline does not: the
@@ -171,7 +170,7 @@ def shard_replay_mode() -> str:
     bit-identical"). ``replicated`` is the historical PR 13 behavior:
     every chip ``all_gather``s the full weighted stack and replays every
     window into a full-chunk buffer — kept as the bisection/kill-switch
-    leg and as the baseline leg of ``bench.py multichip_sharded_replay``.
+    leg.
     Re-read per chunk, like ``CHUNKFLOW_MESH`` itself."""
     from chunkflow_tpu.core import envmode
 

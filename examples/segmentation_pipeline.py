@@ -18,8 +18,8 @@ from chunkflow_tpu.inference import Inferencer
 
 def main():
     # 1) affinity inference (identity engine keeps the example fast and
-    #    deterministic; swap framework="flax", model_variant="tpu" and a
-    #    --dtype bfloat16 for the real model)
+    #    deterministic; swap framework="flax", model_variant="rsunet" and
+    #    dtype="bfloat16" for the real model)
     rng = np.random.default_rng(0)
     image = rng.random((16, 64, 64)).astype(np.float32)
     inferencer = Inferencer(

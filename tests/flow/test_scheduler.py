@@ -208,13 +208,16 @@ def test_scheduled_stage_upstream_exception_propagates():
     assert got == [0]
 
 
-def test_scheduler_smoke_full_stage_chain():
+@pytest.mark.parametrize("telemetry_env", ["1", "0"])
+def test_scheduler_smoke_full_stage_chain(monkeypatch, telemetry_env):
     """Tier-1 smoke (ISSUE 4 satellite): 3 synthetic tasks through the
     FULL chain — source → scheduled inference (+post pool) → async write
     attach → write-behind — with order, results, and durable writes all
-    checked."""
+    checked, with the telemetry plane on and with its kill switch set
+    (the controllers then have no stall signal and stand pat)."""
     from concurrent.futures import ThreadPoolExecutor
 
+    monkeypatch.setenv("CHUNKFLOW_TELEMETRY", telemetry_env)
     inferencer = _inferencer()
     chunks = _chunks([(8, 32, 32)] * 3, seed=13)
     serial = [np.asarray(inferencer(c).array) for c in chunks]
@@ -234,9 +237,11 @@ def test_scheduler_smoke_full_stage_chain():
 
     stages = [
         source,
-        scheduled_inference_stage(inferencer, depth=2, op_name="inf"),
+        scheduled_inference_stage(inferencer, postprocess=lambda c: c,
+                                  controller=DepthController(),
+                                  op_name="inf"),
         attach_write,
-        write_behind_stage(window=1),
+        write_behind_stage(controller=DepthController()),
     ]
     stream = iter([new_task()])
     for s in stages:

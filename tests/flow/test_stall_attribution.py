@@ -157,11 +157,10 @@ def test_telemetry_off_run_is_bit_identical():
 
 def test_overhead_gate():
     """Telemetry-on wall time within noise of telemetry-off on a
-    sleep-calibrated synthetic pipeline (the CPU-safe stand-in for the
-    pipeline_overlap micro-benchmark; bench.py telemetry_overhead runs
-    the real thing). 25% is a deliberately loose CI bound — the
-    acceptance target of <2% is asserted on the calibrated benchmark,
-    not on a shared test box."""
+    sleep-calibrated synthetic pipeline. 25% is a deliberately loose
+    bound for a shared test box: it catches a lock or an fsync on the
+    per-event path, not a percentage; what telemetry costs on the chip
+    is a traced run of a benchmark cell against an untraced one (PERF.md)."""
     import os
 
     def timed_run():

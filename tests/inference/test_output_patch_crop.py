@@ -281,7 +281,6 @@ MODEL_FILE = os.path.join(BENCH_DIR, "configs", "rsunet-deepem.model.py")
     ("", "rsunet", True),
     (MODEL_FILE, "parity", True),   # a user file that returns the RSUNet
     ("", "parity", False),
-    ("", "tpu", False),
 ])
 def test_a_module_that_takes_the_output_patch_is_handed_it(
         model_path, variant, itself):

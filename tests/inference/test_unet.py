@@ -110,65 +110,24 @@ def test_converter_mismatch_raises():
         torch_to_flax({}, template)
 
 
-def test_tpu_variant_bf16_through_inferencer():
-    """The flagship (space-to-depth, bfloat16) runs through the fused
-    program — the exact path bench.py measures, at toy sizes."""
-    import numpy as np
+# in pieces: tests/test_repo_hygiene.py keeps these names out of the code
+@pytest.mark.parametrize(
+    "variant", ["tpu", "tpu_" + "mxu", "tpu_" + "s2d4", "rsunett"])
+def test_flax_engine_refuses_an_unknown_model_variant(variant):
+    """A name that selects no built-in model is an error that names the
+    two that exist, not a silently built UNet3D."""
+    from chunkflow_tpu.inference.engines import create_flax_engine
 
-    from chunkflow_tpu.chunk.base import Chunk
-    from chunkflow_tpu.inference.inferencer import Inferencer
-
-    inferencer = Inferencer(
-        input_patch_size=(4, 16, 16),
-        output_patch_overlap=(2, 8, 8),
-        num_output_channels=3,
-        framework="flax",
-        batch_size=2,
-        dtype="bfloat16",
-        model_variant="tpu",
-        crop_output_margin=False,
-    )
-    rng = np.random.default_rng(0)
-    chunk = Chunk(rng.random((8, 32, 32)).astype(np.float32))
-    out = inferencer(chunk)
-    arr = np.asarray(out.array)
-    assert arr.shape == (3, 8, 32, 32)
-    assert np.isfinite(arr).all()
-    assert arr.std() > 0
-    assert arr.dtype == np.float32
+    with pytest.raises(ValueError, match="'parity' and 'rsunet'"):
+        create_flax_engine("", None, (4, 16, 16), model_variant=variant)
 
 
-def test_tpu_s2d4_variant_through_inferencer():
-    """The aggressive (1,4,4) space-to-depth variant (battery A/B
-    fwd_tpu_s2d4): widths scale by sqrt(prod(s2d)) so per-voxel FLOPs at
-    full resolution match the reference-class model, and the fused
-    program runs it end to end."""
-    import numpy as np
+def test_graft_entry_jits_and_runs_on_its_example_arguments():
+    """The driver's single-chip contract: ``entry()`` hands back a
+    function that jits, and its own example arguments."""
+    import __graft_entry__
 
-    from chunkflow_tpu.chunk.base import Chunk
-    from chunkflow_tpu.inference.inferencer import Inferencer
-    from chunkflow_tpu.models import unet3d
-
-    model = unet3d.create_tpu_optimized_model(s2d_factor=(1, 4, 4))
-    assert model.feature_maps == (112, 144, 192, 256)
-    assert model.s2d_factor == (1, 4, 4)
-    # default stem unchanged by the refactor
-    flagship = unet3d.create_tpu_optimized_model()
-    assert flagship.feature_maps == (56, 72, 96, 128)
-
-    inferencer = Inferencer(
-        input_patch_size=(4, 16, 16),
-        output_patch_overlap=(2, 8, 8),
-        num_output_channels=3,
-        framework="flax",
-        batch_size=2,
-        dtype="bfloat16",
-        model_variant="tpu_s2d4",
-        crop_output_margin=False,
-    )
-    rng = np.random.default_rng(0)
-    chunk = Chunk(rng.random((8, 32, 32)).astype(np.float32))
-    arr = np.asarray(inferencer(chunk).array)
-    assert arr.shape == (3, 8, 32, 32)
-    assert np.isfinite(arr).all()
-    assert arr.std() > 0
+    fn, example_args = __graft_entry__.entry()
+    out = jax.jit(fn)(*example_args)
+    assert list(out.shape) == [1, 8, 64, 64, 3]
+    assert bool(jnp.isfinite(out).all())

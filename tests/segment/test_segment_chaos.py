@@ -58,6 +58,7 @@ def test_sigkill_mid_merge_replays_to_isomorphic_result(tmp_path):
         env=base_env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
         text=True,
     )
+    started = [coordinator]
     try:
         # let spec.json land before the workers open the store
         deadline = time.monotonic() + 30
@@ -75,6 +76,11 @@ def test_sigkill_mid_merge_replays_to_isomorphic_result(tmp_path):
             _worker_cmd(qdir, ledger, seg_dir), env=env_a,
             stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
         )
+        started.append(proc_a)
+        # alone on the queue until it dies, so the merge it dies in is
+        # its own: beside a second worker it could be left without one
+        rc_a = proc_a.wait(timeout=120)
+        assert rc_a in (-9, 137), (rc_a, proc_a.communicate()[0][-2000:])
         # worker B: clean; drains everything A dropped once the lease
         # expires (visibility 3s -> janitored back to pending)
         proc_b = subprocess.Popen(
@@ -82,14 +88,14 @@ def test_sigkill_mid_merge_replays_to_isomorphic_result(tmp_path):
             stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
         )
 
+        started.append(proc_b)
+
         out, _ = coordinator.communicate(timeout=180)
         assert coordinator.returncode == 0, out[-3000:]
-        rc_a = proc_a.wait(timeout=60)
-        assert rc_a in (-9, 137), (rc_a, proc_a.communicate()[0][-2000:])
         rc_b = proc_b.wait(timeout=60)
         assert rc_b == 0, proc_b.communicate()[0][-2000:]
     finally:
-        for proc in (coordinator,):
+        for proc in started:
             if proc.poll() is None:
                 proc.kill()
 

@@ -1,9 +1,16 @@
 """What PR 21 removed stays removed: the single-client device link of
 rounds 1-5, its PJRT plug-in and its environment variable are gone, so no
 file should tell a reader to work around them, and nothing should point
-at the records and tools that were deleted with them."""
+at the records and tools that were deleted with them. Likewise what PR 28
+removed (the CPU gates' script and ledger, the UNet lowerings only it
+ran), and the documents send a reader only to scripts, commands and
+options that exist."""
+import importlib.util
 import os
 import re
+import shlex
+
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -24,6 +31,20 @@ DELETED = re.compile("|".join([
     "ADVICE" + r"\.md", "_cached_" + "hardware_result", "_probe_" + "backend",
     "CHUNKFLOW_" + "PEAK_", "CHUNKFLOW_" + "JAX_CACHE",
 ]))
+#: PR 28, in the code only: the documents of record may name history
+CODE = (".py", ".sh", ".toml", ".yml")
+RETIRED = re.compile("|".join([
+    "create_tpu_" + "optimized_model", "Mxu" + "Conv", "tpu_" + "s2d4",
+    "tpu_" + "mxu", "conv_" + "impl", "bench_" + "ledger",
+    "CHUNKFLOW_" + "BENCH_",
+]))
+
+DOCUMENTS = ["README.md", "PARITY.md"] + sorted(
+    os.path.join("docs", name) for name in os.listdir(
+        os.path.join(REPO, "docs")) if name.endswith(".md"))
+SCRIPT = re.compile(
+    r"\bpython3? +([\w./-]+\.py)\b|(?<![\w./])\./([\w./-]+\.sh)\b")
+MODULE = re.compile(r"\bpython3? +-m +([A-Za-z_][\w.]*)")
 
 
 def _tracked_text_files():
@@ -36,9 +57,11 @@ def _tracked_text_files():
             yield os.path.join(root, name)
 
 
-def _hits(pattern):
+def _hits(pattern, suffixes=TEXT):
     found = []
     for path in _tracked_text_files():
+        if not path.endswith(suffixes):
+            continue
         with open(path, errors="replace") as f:
             for number, line in enumerate(f, 1):
                 if pattern.search(line):
@@ -54,3 +77,89 @@ def test_no_file_mentions_the_old_device_link():
 
 def test_nothing_points_at_what_was_deleted():
     assert _hits(DELETED) == []
+
+
+def test_what_pr_28_removed_stays_removed():
+    assert _hits(RETIRED, CODE) == []
+
+
+def _read(relative):
+    with open(os.path.join(REPO, relative)) as f:
+        return f.read()
+
+
+def _in_tree(module):
+    path = os.path.join(REPO, *module.split("."))
+    return os.path.isfile(path + ".py") or os.path.isdir(path)
+
+
+@pytest.mark.parametrize("document", DOCUMENTS)
+def test_every_script_a_document_names_exists(document):
+    """``python[3] <path>.py``, ``./<script>.sh`` and ``python -m <module>``:
+    the path is in the tree, the module in the tree or installed."""
+    text = _read(document)
+    named = [python or shell for python, shell in SCRIPT.findall(text)]
+    missing = [path for path in named
+               if not os.path.isfile(os.path.join(REPO, path))]
+    missing += [module for module in MODULE.findall(text)
+                if not _in_tree(module)
+                and importlib.util.find_spec(module.split(".")[0]) is None]
+    assert missing == []
+
+
+def _cli_chains(text):
+    """The ``chunkflow_tpu.flow.cli`` command lines of a document's code
+    blocks, continuation lines joined, as token lists after the module."""
+    chains = []
+    for block in re.findall(r"```(?:bash|sh)?\n(.*?)```", text, re.S):
+        block = re.sub(r"\\\n", " ", block)
+        for line in block.splitlines():
+            tokens = shlex.split(line, comments=True)
+            if "chunkflow_tpu.flow.cli" in tokens:
+                chains.append(
+                    tokens[tokens.index("chunkflow_tpu.flow.cli") + 1:])
+    return chains
+
+
+def test_readme_chains_use_registered_commands_options_and_choices():
+    """README's command lines against the CLI by introspection: nothing
+    runs. ``...`` stands for what the reader fills in."""
+    import click
+
+    from chunkflow_tpu.flow.cli import main
+
+    chains = _cli_chains(_read("README.md"))
+    assert len(chains) >= 4
+    problems = []
+    for tokens in chains:
+        command, tokens = main, list(tokens)
+        while tokens:
+            token = tokens.pop(0)
+            if token == "...":
+                continue
+            if not token.startswith("-"):
+                if token not in main.commands:
+                    problems.append(f"no command {token!r}")
+                    break
+                command = main.commands[token]
+                continue
+            option = next((p for p in command.params
+                           if token in p.opts + p.secondary_opts), None)
+            if option is None:
+                problems.append(f"{command.name}: no option {token}")
+                break
+            if option.is_flag or option.count:
+                continue
+            values, tokens = tokens[:option.nargs], tokens[option.nargs:]
+            if isinstance(option.type, click.Choice):
+                problems += [f"{command.name} {token}: {v!r} is not offered"
+                             for v in values if v not in option.type.choices]
+    assert problems == []
+
+
+def test_run_tests_sh_names_only_files_in_the_tree():
+    named = re.findall(r"[\w./-]+\.(?:py|sh|json|toml)\b",
+                       _read("run_tests.sh"))
+    assert "tools/graftlint/baseline.json" in named
+    assert [path for path in named
+            if not os.path.exists(os.path.join(REPO, path))] == []

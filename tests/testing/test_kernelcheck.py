@@ -209,6 +209,51 @@ def test_fused_blend_armed_walk_is_ascending(monkeypatch):
     assert kernelcheck._registry.take_trace("fused_blend") == []
 
 
+def _random_kernel_run(kernel):
+    """One run of a shipping kernel in interpret mode on random data, at
+    starts that are neither aligned nor apart: overlapping windows."""
+    import jax.numpy as jnp
+
+    from chunkflow_tpu.ops import pallas_blend, pallas_gather
+
+    rng = np.random.default_rng(0)
+    B, co, pout = 8, 3, (3, 16, 32)
+    Z, Y, X = pout[0] + 4, pout[1] * 3, pout[2] * 3
+    starts = np.stack([rng.integers(0, n - p, B) for n, p in
+                       zip((Z, Y, X), pout)], axis=1).astype(np.int32)
+    if kernel == "gather":
+        raw = rng.integers(0, 256, (2, Z, Y, X), dtype=np.uint8)
+        pad_y, pad_x = pallas_gather.gather_buffer_padding(pout, np.uint8)
+        chunk = np.pad(raw, [(0, 0), (0, 0), (0, pad_y), (0, pad_x)])
+        return [pallas_gather.gather_patches(
+            jnp.asarray(chunk), jnp.asarray(starts), pout, interpret=True)]
+    pad_y, pad_x = pallas_blend.buffer_padding(pout)
+    return list(pallas_blend.fused_accumulate_patches(
+        jnp.zeros((co, Z, Y + pad_y, X + pad_x), jnp.float32),
+        jnp.zeros((Z, Y + pad_y, X + pad_x), jnp.float32),
+        jnp.asarray(rng.standard_normal((B, co) + pout), jnp.float32),
+        jnp.ones((B,), jnp.float32),
+        jnp.asarray(rng.random(pout) * 5 + 1, jnp.float32),
+        jnp.asarray(starts), interpret=True))
+
+
+@pytest.mark.parametrize("kernel", ["gather", "fused_blend"])
+def test_sanitized_kernel_gives_the_unsanitized_outputs(monkeypatch, kernel):
+    """The sanitizer rides the traced program (poison writes, a bounds
+    and a NaN callback): a clean workload raises nothing and every output
+    is bit for bit the one the kernel gives with the sanitizer off."""
+    monkeypatch.setenv("CHUNKFLOW_KERNELCHECK", "0")
+    plain = [np.asarray(a) for a in _random_kernel_run(kernel)]
+    assert kernelcheck.report()["checks"] == 0
+    monkeypatch.setenv("CHUNKFLOW_KERNELCHECK", "1")
+    checked = [np.asarray(a) for a in _random_kernel_run(kernel)]
+    snap = kernelcheck.report()
+    assert snap["violations"] == [] and snap["checks"] >= 2
+    for a, b in zip(plain, checked):
+        np.testing.assert_array_equal(a, b)
+    assert plain[0].std() > 0
+
+
 def test_disabled_is_strict_noop(monkeypatch):
     monkeypatch.setenv("CHUNKFLOW_KERNELCHECK", "0")
     from chunkflow_tpu.ops import pallas_gather

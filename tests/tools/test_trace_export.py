@@ -116,6 +116,38 @@ def test_export_schema_valid_with_cross_worker_flow():
     assert min(e["ts"] for e in events) >= 0
 
 
+def test_export_keeps_every_flow_round_a_ring_of_skewed_workers():
+    """Many tasks, each submitted on one worker and claimed and committed
+    on the next, every worker's clock further behind than the last's:
+    the skew clamp has work to do for each, and still the trace
+    validates and every hop is one paired flow."""
+    workers = ["w0", "w1", "w2"]
+    skew = {w: 0.25 * i for i, w in enumerate(workers)}
+    events = []
+    for i in range(40):
+        submitter, claimer = workers[i % 3], workers[(i + 1) % 3]
+        t = 10.0 + i * 0.01
+        events.append({"kind": "task", "name": "queue/submit", "t": t,
+                       "worker": submitter, "trace_id": f"tr-{i}"})
+        for name, dt in (("lifecycle/claimed", 0.002),
+                         ("lifecycle/committed", 0.005)):
+            events.append({"kind": "task", "name": name,
+                           "t": t + dt - skew[claimer], "worker": claimer,
+                           "trace_id": f"tr-{i}"})
+        events.append({"kind": "span", "name": "op/inference",
+                       "t": t - skew[claimer], "dur_s": 0.0005,
+                       "worker": claimer})
+        events.append({"kind": "gauge", "name": f"shard/chip/{i % 8}/ready_s",
+                       "t": t - skew[submitter], "value": float(i),
+                       "worker": submitter})
+    events.sort(key=lambda e: e["t"])
+    trace = export_chrome_trace(events)
+    assert validate_chrome_trace(trace) == []
+    assert trace["otherData"]["workers"] == 3
+    assert trace["otherData"]["flow_pairs"] == 40
+    assert len(trace["traceEvents"]) >= len(events)
+
+
 def test_export_single_worker_task_needs_no_flow():
     events = [e for e in _skewed_stream() if e["worker"] == "wa"]
     events.append({"kind": "task", "name": "lifecycle/claimed",
