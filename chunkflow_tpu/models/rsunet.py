@@ -25,10 +25,15 @@ trace time from the published ``[kz,ky,kx,Cin,Cout]`` one
 points; only exact zeros are added.  F is the largest power of two with
 ``F * width[0] <= 128`` that divides the x extent (:func:`x_fold`: 28 -> 4,
 16 -> 8); at F = 1 the folded kernel is the published kernel and the call
-is the plain convolution, which is how levels 1-3 run.  The two
-transitions are emitted folded as well (:class:`XFoldUp`,
-:func:`max_pool_folded`): behind a plain reshape the pool alone took a
-quarter of the chip's time.  Norm is folded to a per-channel affine (no
+is the plain convolution.  Level 1 runs folded too, by the fold the pool
+above hands it (:func:`level_folds`: 36 channels x 2, 32 x 4): unfolded,
+XLA put the batch in the lanes of every level-1 array at batch 6 and
+copied each one in and out of that layout around every block (PERF.md,
+PR 29).  A fold is inherited through a pool and never made by a reshape
+that splits the lanes, so levels 2 and 3 run unfolded.  The transitions
+are emitted folded as well (:class:`XFoldUp`, :func:`max_pool_folded`):
+behind a plain reshape the pool alone took a quarter of the chip's time.
+Norm is folded to a per-channel affine (no
 batch statistics at inference), compute is optionally bfloat16 with
 float32 params; the final activation is computed in the output's dtype.
 
@@ -74,6 +79,25 @@ def x_fold(width0: int, x_extent: int) -> int:
     while 2 * fold * width0 <= LANES and x_extent % (2 * fold) == 0:
         fold *= 2
     return fold
+
+
+# levels 0 and 1 run folded: a level further down that inherits a fold (64 x 2
+# at widths 16/32/64/128) read no faster folded on the chip (PERF.md, PR 29)
+FOLDED_LEVELS = 2
+
+
+def level_folds(width: Sequence[int], x_extent: int, down_factors) -> list:
+    """The x-fold every level runs in. Level 0's is :func:`x_fold`; a level
+    below takes what the pool above hands down (the fold above over the
+    pool's x factor) as far as its own width and extent allow, and 1 where
+    the pool's windows straddle the blocks."""
+    folds = [x_fold(width[0], x_extent)]
+    for level, factor in enumerate(down_factors, 1):
+        x_extent //= factor[2]
+        handed = 1 if folds[-1] % factor[2] else folds[-1] // factor[2]
+        folds.append(min(x_fold(width[level], x_extent), handed)
+                     if level < FOLDED_LEVELS else 1)
+    return folds
 
 
 def fold_x(x, fold: int):
@@ -149,9 +173,10 @@ class XFoldConv(nn.Module):
 
 class XFoldUp(nn.Module):
     """``nn.ConvTranspose(features, kernel_size=factor, strides=factor)``
-    with the same parameter tree, whose result comes out x-folded by
-    ``fold`` (a multiple of the x factor) with no full-resolution array
-    in between. With kernel == stride every input position emits its own
+    with the same parameter tree, on an array x-folded by ``in_fold``,
+    whose result comes out x-folded by ``fold`` (a multiple of
+    ``in_fold`` times the x factor) with no full-resolution array in
+    between. With kernel == stride every input position emits its own
     (fz, fy, fx, features) block, so the input folded by ``fold // fx``
     needs one 1x1x1 convolution with a block-diagonal kernel: the x part
     of each block is already the folded channel order, and only z and y
@@ -161,11 +186,13 @@ class XFoldUp(nn.Module):
     factor: Triple
     dtype: jnp.dtype = jnp.float32
     fold: int = 2
+    in_fold: int = 1
 
     @nn.compact
     def __call__(self, x):
         fz, fy, fx = self.factor
-        b, z, y, xs, cin = x.shape
+        b, z, y, _, lanes = x.shape
+        cin = lanes // self.in_fold
         kernel = self.param("kernel", nn.initializers.lecun_normal(),
                             (fz, fy, fx, cin, self.features))
         bias = self.param("bias", nn.initializers.zeros_init(),
@@ -177,13 +204,13 @@ class XFoldUp(nn.Module):
         same = np.eye(g, dtype=bool)[:, None, :, None, None]
         k = jnp.where(same, k[:, :, None, :, None], 0)  # i,j,g,c,g,k,f
         k = k.reshape(fz, fy, 1, 1, 1, g * cin, self.fold * self.features)
-        x = fold_x(x, g)
+        x = fold_x(x, g // self.in_fold)
         rows = [[lax.conv_general_dilated(
             x, k[i, j], (1, 1, 1), "VALID",
             dimension_numbers=("NDHWC", "DHWIO", "NDHWC"))
             for j in range(fy)] for i in range(fz)]
         y_ = jnp.stack([jnp.stack(row, axis=3) for row in rows], axis=2)
-        y_ = y_.reshape(b, z * fz, y * fy, xs // g, -1)
+        y_ = y_.reshape(b, z * fz, y * fy, x.shape[3], -1)
         return y_ + jnp.tile(bias, self.fold)
 
 
@@ -352,10 +379,12 @@ class RSUNet(nn.Module):
         depth = len(self.width)
         assert len(self.down_factors) == depth - 1
         dt = self.dtype
-        # level i runs x-folded by folds[i]; only level 0 folds so far
-        fold = x_fold(self.width[0], x.shape[-2])
+        # level i runs x-folded by folds[i]
+        folds = level_folds(self.width, x.shape[-2], self.down_factors)
+        fold = folds[0]
         profiling.trace_gauge("forward/x_fold", fold)
-        folds = [fold] + [1] * (depth - 1)
+        for i in range(1, depth):
+            profiling.trace_gauge(f"forward/x_fold_{i}", folds[i])
         shapes = [tuple(x.shape[1:4])]
         for factor in self.down_factors:
             shapes.append(tuple(n // f for n, f in zip(shapes[-1], factor)))
@@ -386,9 +415,9 @@ class RSUNet(nn.Module):
             if folds[i] % factor[2]:  # positions of one window, two blocks
                 x = nn.max_pool(unfold_x(x, folds[i]), window_shape=factor,
                                 strides=factor)
-            else:
+            else:  # comes out folded by folds[i] // fx: level i+1's, or more
                 x = unfold_x(max_pool_folded(x, factor, folds[i]),
-                             folds[i] // factor[2])
+                             folds[i] // factor[2] // folds[i + 1])
         x = block(depth - 1, "bridge")(x)
         for i in reversed(range(depth - 1)):
             factor = self.down_factors[i]
@@ -401,7 +430,8 @@ class RSUNet(nn.Module):
                     dtype=dt, name=f"up{i}")(x), folds[i])
             else:
                 x = XFoldUp(self.width[i], factor=factor, dtype=dt,
-                            fold=folds[i], name=f"up{i}")(x)
+                            fold=folds[i], in_fold=folds[i + 1],
+                            name=f"up{i}")(x)
             x = x + _crop(skips[i], box, _whole(shapes[i]), folds[i])
             x = block(i, f"dec{i}")(x)
         held, want = cone[0]

@@ -541,7 +541,10 @@ def test_programs_json_carries_op_scopes_only_with_a_sink(clean_plane,
 
 def test_trace_gauge_lands_on_the_program_being_built(clean_plane, tmp_path):
     """A model says at trace time which lowering it chose: the value is
-    a gauge and sits on that program's entry, not on a later program's."""
+    a gauge and sits on that program's entry, not on a later program's.
+    ``x_fold`` is level 0's fold; a level below says its own as
+    ``forward/x_fold_<level>``, which is the entry's ``x_fold_<level>``
+    (1: that level runs unfolded)."""
     import jax
     import jax.numpy as jnp
 
@@ -549,6 +552,8 @@ def test_trace_gauge_lands_on_the_program_being_built(clean_plane, tmp_path):
         def program(x):
             if fold:
                 profiling.trace_gauge("forward/x_fold", fold)
+                profiling.trace_gauge("forward/x_fold_1", fold // 2)
+                profiling.trace_gauge("forward/x_fold_2", 1)
             return x * 2.0
         return jax.jit(program)
 
@@ -561,7 +566,11 @@ def test_trace_gauge_lands_on_the_program_being_built(clean_plane, tmp_path):
         program(x)  # no second trace, nothing to overwrite
     by_label = {e["label"]: e["x_fold"] for e in profiling.catalog()}
     assert by_label == {"fold4": 4, "fold0": None}
+    below = {e["label"]: (e.get("x_fold_1"), e.get("x_fold_2"))
+             for e in profiling.catalog()}
+    assert below == {"fold4": (2, 1), "fold0": (None, None)}
     assert telemetry.snapshot()["gauges"]["forward/x_fold"] == 4
+    assert telemetry.snapshot()["gauges"]["forward/x_fold_1"] == 2
     profiling.trace_gauge("forward/x_fold", 2)  # outside any first call
     assert telemetry.snapshot()["gauges"]["forward/x_fold"] == 2
     telemetry.flush()

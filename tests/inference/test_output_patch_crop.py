@@ -269,7 +269,7 @@ def test_the_program_says_how_much_of_the_decoder_it_runs(
                           gauges["forward/flops_share"]]
         assert [share < 1 for share in shares] == [*cut, any(cut)]
         assert all(0.2 < share <= 1.0 for share in shares)
-        assert entry["x_fold"] == 4
+        assert entry["x_fold"] == 4 and entry["x_fold_1"] == 2
     finally:
         telemetry.reset()
 

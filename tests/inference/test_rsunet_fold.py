@@ -1,8 +1,8 @@
-"""The x-folded full-resolution level of the RSUNet is an exact
-re-lowering: the folded forward equals the same module held to F = 1, the
-fold factor follows from width[0] and the x extent alone, the parameter
-tree is what converted checkpoints were written against, and a fold of 1
-is the plain convolution."""
+"""The x-folded levels of the RSUNet are an exact re-lowering: the folded
+forward equals the same module held to F = 1, every level's fold follows
+from the widths, the x extent and the pooling alone, the parameter tree is
+what converted checkpoints were written against, and a fold of 1 is the
+plain convolution."""
 import functools
 import os
 
@@ -55,7 +55,7 @@ def test_folded_forward_equals_the_unfolded_one(widths, dtype, x_extent,
                                                 monkeypatch):
     levels, down = SHAPES[x_extent]
     width = WIDTHS[widths][:levels]
-    fold = rsunet.x_fold(width[0], x_extent)
+    folds = rsunet.level_folds(width, x_extent, down)
     model = rsunet.RSUNet(width=width, down_factors=down,
                           dtype=jnp.dtype(dtype))
     x = jax.random.uniform(jax.random.PRNGKey(0), (2, 4, 16, x_extent, 1))
@@ -64,23 +64,30 @@ def test_folded_forward_equals_the_unfolded_one(widths, dtype, x_extent,
     def forward():  # a new function each time: nothing cached across folds
         return np.asarray(jax.jit(lambda p, v: model.apply(p, v))(params, x))
 
-    seen = []
+    seen = []  # (level, fold) of every convolution that ran
     real = rsunet.XFoldConv.__call__
 
     def spy(self, x):
-        seen.append(self.fold)
+        block = self.path[0]  # embed, enc{i}, bridge, dec{i}, out
+        level = (int(block[3:]) if block[:3] in ("enc", "dec")
+                 else levels - 1 if block == "bridge" else 0)
+        seen.append((level, self.fold))
         return real(self, x)
 
+    # embed, enc0, dec0, out at level 0; enc{i} and dec{i} below; the bridge
+    convs = [8] + [6] * (levels - 2) + [3]
     with jax.default_matmul_precision("highest"):
         monkeypatch.setattr(rsunet.XFoldConv, "__call__", spy)
         folded = forward()
-        assert max(seen) == fold
-        # level 0: embed, enc0, dec0, out; everything below runs at 1
-        assert seen.count(fold) == (8 if fold > 1 else len(seen))
+        assert sorted(seen) == sorted(
+            (level, folds[level]) for level in range(levels)
+            for _ in range(convs[level]))
+        assert folds[0] == rsunet.x_fold(width[0], x_extent)
+        assert all(fold == 1 for fold in folds[2:])
         monkeypatch.setattr(rsunet, "x_fold", lambda width0, x: 1)
         del seen[:]
         plain = forward()
-        assert set(seen) == {1}
+        assert {fold for _, fold in seen} == {1}
     assert folded.shape == plain.shape == x.shape[:-1] + (3,)
     assert 0.05 < plain.std()  # a forward that says something
     # float32: the summation order of one convolution; bfloat16: 2 ulp
@@ -97,6 +104,41 @@ def test_folded_forward_equals_the_unfolded_one(widths, dtype, x_extent,
 ])
 def test_fold_factor_rule(width0, x_extent, fold):
     assert rsunet.x_fold(width0, x_extent) == fold
+
+
+@pytest.mark.parametrize("width,x_extent,down,folds", [
+    # level 1 takes the fold the (1,2,2) pool hands it: 36 x 2, 32 x 4
+    ((28, 36, 48, 64), 256, DOWN, [4, 2, 1, 1]),
+    ((16, 32, 64, 128), 256, DOWN, [8, 4, 1, 1]),
+    ((28, 36, 48, 64), 32, DOWN, [4, 2, 1, 1]),
+    ((16, 32, 64, 128), 32, DOWN, [8, 4, 1, 1]),
+    # 30 folds by 2 and hands down 1; 20 by 4 and hands down 2
+    ((28, 36), 30, DOWN[:1], [2, 1]),
+    ((16, 32), 30, DOWN[:1], [2, 1]),
+    ((28, 36), 20, DOWN[:1], [4, 2]),
+    ((16, 32), 20, DOWN[:1], [4, 2]),
+    # an odd extent folds nowhere, whatever the pool
+    ((28, 36), 17, ((1, 2, 1),), [1, 1]),
+    ((16, 32), 17, ((1, 2, 1),), [1, 1]),
+    # a pool that leaves x alone hands the whole fold down, as far as the
+    # width below allows
+    ((16, 32), 32, ((1, 2, 1),), [8, 4]),
+    ((16, 16), 32, ((1, 2, 1),), [8, 8]),
+    # a width over 64 never folds, nor does anything below level 1
+    ((16, 65, 64), 256, DOWN[:2], [8, 1, 1]),
+    ((16, 128), 256, DOWN[:1], [8, 1]),
+    ((65, 16), 256, DOWN[:1], [1, 1]),
+    ((8, 8, 8), 256, DOWN[:2], [16, 8, 1]),
+    # windows of 4 straddle blocks of 2: nothing to hand down
+    ((28, 36), 24, ((1, 2, 4),), [4, 1]),
+    ((28, 36), 30, ((1, 2, 3),), [2, 1]),
+])
+def test_level_folds_rule(width, x_extent, down, folds):
+    assert rsunet.level_folds(width, x_extent, down) == folds
+    for fold, level_width, factor in zip(folds, width, (*down, (1, 1, 1))):
+        assert fold * level_width <= rsunet.LANES or fold == 1
+        assert x_extent % fold == 0
+        x_extent //= factor[2]
 
 
 @pytest.mark.parametrize("widths", sorted(WIDTHS))
@@ -255,40 +297,43 @@ def _levels(patch):
     return shapes
 
 
-@pytest.mark.parametrize("patch,region,fold,boxes", [
+@pytest.mark.parametrize("patch,region,widths,folds,boxes", [
     # the production geometry, 20x256x256 -> 16x192x192 at F = 4 (PERF.md)
-    ((20, 256, 256), ((2, 18), (32, 224), (32, 224)), 4, [
+    ((20, 256, 256), ((2, 18), (32, 224), (32, 224)), "28-36-48-64",
+     [4, 2, 1, 1], [
         ((0, 20), (28, 228), (28, 228)),
         ((0, 20), (10, 118), (10, 118)),
         ((0, 10), (0, 64), (0, 64)),   # 2 of 64 a side: under the halo
         ((0, 5), (0, 32), (0, 32))]),
     # the same at F = 8: the x box rounds out to whole blocks of 8
-    ((20, 256, 256), ((2, 18), (32, 224), (32, 224)), 8, [
+    ((20, 256, 256), ((2, 18), (32, 224), (32, 224)), "16-32-64-128",
+     [8, 4, 1, 1], [
         ((0, 20), (28, 228), (24, 232)),
         ((0, 20), (10, 118), (8, 120)),
         ((0, 10), (0, 64), (0, 64)),
         ((0, 5), (0, 32), (0, 32))]),
     # a margin as wide as the halo cuts nothing: z here, and all of "none"
-    ((8, 32, 32), ((2, 6), (8, 24), (8, 24)), 4, [
+    ((8, 32, 32), ((2, 6), (8, 24), (8, 24)), "28-36-48-64", [4, 2, 1, 1], [
         ((0, 8), (4, 28), (4, 28)),
         ((0, 8), (0, 16), (0, 16)),
         ((0, 4), (0, 8), (0, 8)),
         ((0, 2), (0, 4), (0, 4))]),
-    ((8, 32, 32), ((0, 8), (0, 32), (0, 32)), 4, [
+    ((8, 32, 32), ((0, 8), (0, 32), (0, 32)), "28-36-48-64", [4, 2, 1, 1], [
         ((0, 8), (0, 32), (0, 32)),
         ((0, 8), (0, 16), (0, 16)),
         ((0, 4), (0, 8), (0, 8)),
         ((0, 2), (0, 4), (0, 4))]),
     # an odd region: grown by (2, 3, 3), then out to even rows and blocks
-    ((8, 64, 64), ((1, 7), (9, 55), (11, 53)), 4, [
+    ((8, 64, 64), ((1, 7), (9, 55), (11, 53)), "28-36-48-64", [4, 2, 1, 1], [
         ((0, 8), (6, 58), (4, 60)),
         ((0, 8), (0, 32), (0, 32)),
         ((0, 4), (0, 16), (0, 16)),
         ((0, 2), (0, 8), (0, 8))]),
 ])
-def test_the_cone_of_dependence(patch, region, fold, boxes):
+def test_the_cone_of_dependence(patch, region, widths, folds, boxes):
     shapes = _levels(patch)
-    cone = rsunet.decoder_cone(shapes, region, DOWN, [fold, 1, 1, 1],
+    assert rsunet.level_folds(WIDTHS[widths], patch[2], DOWN) == folds
+    cone = rsunet.decoder_cone(shapes, region, DOWN, folds,
                                rsunet.BLOCK_HALO)
     assert [box for box, _ in cone] == boxes
     assert rsunet.BLOCK_HALO == (2, 3, 3)
@@ -300,7 +345,48 @@ def test_the_cone_of_dependence(patch, region, fold, boxes):
                 (lo // f, hi // f) for (lo, hi), f in zip(box, DOWN[i]))
             assert all(lo % f == 0 and hi % f == 0
                        for (lo, hi), f in zip(box, DOWN[i]))
-    assert cone[0][0][2][0] % fold == 0 and cone[0][0][2][1] % fold == 0
+    # every slice in x takes whole blocks of its level's fold
+    for (box, want), fold in zip(cone, folds):
+        assert all(edge % fold == 0 for edge in box[2] + want[2])
+
+
+@pytest.mark.parametrize("in_fold,fold", [(1, 2), (1, 4), (2, 4), (2, 8),
+                                          (4, 8)])
+@pytest.mark.parametrize("factor", [(1, 2, 2), (2, 2, 2)])
+def test_upsampling_of_a_folded_input_is_the_transposed_convolution(
+        factor, in_fold, fold):
+    """``XFoldUp`` takes the fold its input arrives in and emits the fold
+    asked for: ``nn.ConvTranspose`` on the unfolded array, folded."""
+    cin, features = 5, 7
+    x = jax.random.uniform(jax.random.PRNGKey(6), (2, 3, 4, 16, cin))
+    native = nn.ConvTranspose(features, kernel_size=factor, strides=factor)
+    params = _perturbed(native.init(jax.random.PRNGKey(0), x))
+    ours = rsunet.XFoldUp(features, factor=factor, fold=fold,
+                          in_fold=in_fold)
+    folded = rsunet.fold_x(x, in_fold)
+    mine = ours.init(jax.random.PRNGKey(0), folded)
+    assert jax.tree_util.tree_map(jnp.shape, mine) == \
+        jax.tree_util.tree_map(jnp.shape, params)
+    with jax.default_matmul_precision("highest"):
+        want = rsunet.fold_x(native.apply(params, x), fold)
+        got = ours.apply(params, folded)
+    assert got.shape == want.shape == (
+        2, 3 * factor[0], 8, 32 // fold, fold * features)
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() <= 1e-6
+
+
+@pytest.mark.parametrize("fold", [2, 4, 8])
+@pytest.mark.parametrize("factor", [(1, 2, 2), (2, 2, 2), (2, 2, 1)])
+def test_pool_of_a_folded_array_is_the_max_pool(factor, fold):
+    """``max_pool_folded`` hands down the fold over the x factor: the
+    values are ``nn.max_pool``'s of the unfolded array, bit for bit."""
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, 4, 6, 32, 5))
+    want = nn.max_pool(x, window_shape=factor, strides=factor)
+    got = rsunet.max_pool_folded(rsunet.fold_x(x, fold), factor, fold)
+    handed = fold // factor[2]
+    assert got.shape == rsunet.fold_x(want, handed).shape
+    np.testing.assert_array_equal(
+        np.asarray(rsunet.unfold_x(got, handed)), np.asarray(want))
 
 
 @pytest.mark.parametrize("config", ["rsunet-superhuman", "rsunet-deepem",

@@ -1,6 +1,9 @@
 """tools/aot_cost.py reads XLA's cost model out of a compiled module's
 text: the entry computation's ops with their cycles, shapes and flax
-modules, and nothing of the fused computations."""
+modules, and nothing of the fused computations; ``--by-module`` sums them
+by flax module with the convolutions apart from the rest."""
+import pytest
+
 from tools import aot_cost
 
 HLO = '''HloModule jit_apply
@@ -12,6 +15,10 @@ HLO = '''HloModule jit_apply
 ENTRY %main.5 (x: bf16[4,20,256,64,112]) -> bf16[4,20,256,64,112] {
   %x = bf16[4,20,256,64,112]{4,3,2,1,0:T(8,128)(2,1)} parameter(0)
   %copy.7 = bf16[4,20,256,64,112]{3,4,2,1,0:T(8,128)(2,1)} copy(%x), metadata={op_name="jit(apply)/RSUNet/enc0/jit(relu)/max"}, backend_config={"window_config":{"estimated_cycles":"4000000"}}
+  %fusion.8 = bf16[20,256,32,9,112]{4,2,3,1,0:T(8,128)(2,1)} fusion(%copy.7), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(apply)/RSUNet/enc0/conv2/conv_general_dilated"}, backend_config={"window_config":{"estimated_cycles":"500000"}}
+  %reduce-window.2 = bf16[20,128,32,9,112]{4,2,3,1,0:T(8,128)(2,1)} fusion(%fusion.8), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(apply)/RSUNet/reduce_max"}, backend_config={"window_config":{"estimated_cycles":"300000"}}
+  %convolution.3 = bf16[20,128,32,9,72]{4,2,3,1,0:T(8,128)(2,1)} convolution(%reduce-window.2, %x), metadata={op_name="jit(apply)/RSUNet/up1/conv_general_dilated"}, backend_config={"window_config":{"estimated_cycles":"200000"}}
+  %copy.4 = bf16[20,128,32,9,72]{1,4,3,2,0:T(8,128)(2,1)} copy(%convolution.3), backend_config={"window_config":{"estimated_cycles":"100000"}}
   ROOT %fusion.9 = bf16[20,256,32,9,112]{4,2,3,1,0:T(8,128)(2,1)} fusion(%copy.7), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(apply)/RSUNet/enc0/conv2/conv_general_dilated"}, backend_config={"window_config":{"estimated_cycles":"10000000"}}
 }
 '''
@@ -19,9 +26,33 @@ ENTRY %main.5 (x: bf16[4,20,256,64,112]) -> bf16[4,20,256,64,112] {
 
 def test_entry_ops_reads_cycles_shapes_and_modules():
     ops = aot_cost.entry_ops(HLO)
-    assert [(op[0], op[1], op[2]) for op in ops] == [
-        (4000000, "copy.7", "copy"), (10000000, "fusion.9", "fusion")]
-    assert ops[1][3].startswith("bf16[20,256,32,9,112]{4,2,3,1,0:T(8,128)")
-    assert aot_cost.module_of(ops[1][4]) == "enc0/conv2/conv_general_dilated"
+    assert [(op[0], op[1], op[2], op[5]) for op in ops] == [
+        (4000000, "copy.7", "copy", ""),
+        (500000, "fusion.8", "fusion", "kLoop"),
+        (300000, "reduce-window.2", "fusion", "kOutput"),
+        (200000, "convolution.3", "convolution", ""),
+        (100000, "copy.4", "copy", ""),
+        (10000000, "fusion.9", "fusion", "kOutput")]
+    assert ops[-1][3].startswith("bf16[20,256,32,9,112]{4,2,3,1,0:T(8,128)")
+    assert aot_cost.module_of(ops[-1][4]) == \
+        "enc0/conv2/conv_general_dilated"
     assert aot_cost.module_of(ops[0][4]) == "enc0/jit(relu)/max"
     assert aot_cost.module_of("") == ""
+
+
+@pytest.mark.parametrize("module,conv,rest", [
+    # an output fusion is the convolution with its epilogue; the copy and
+    # the loop fusion under the same module's name are not
+    ("enc0", 10000000, 4500000),
+    # a bare convolution counts as one
+    ("up1", 200000, 0),
+    # the model's own ops (the pool, here rooted in a reduce-window, so no
+    # convolution for all its kOutput) and XLA's unnamed copies
+    ("-", 0, 400000),
+])
+def test_by_module_keeps_convolutions_apart_from_the_rest(module, conv, rest):
+    table = aot_cost.by_module(aot_cost.entry_ops(HLO))
+    assert sorted(table) == ["-", "enc0", "up1"]
+    assert table[module] == [conv, rest]
+    assert sum(map(sum, table.values())) == sum(
+        op[0] for op in aot_cost.entry_ops(HLO))

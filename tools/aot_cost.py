@@ -12,9 +12,12 @@ lowering leaves expensive; it is never written under the name of a speed.
 Calibration, model over the chip's device time a program (my chip runs,
 PR 24; docs/performance.md "Sizing a lowering offline"): 1.38 and 1.42
 (rsunet-superhuman, rsunet-deepem) before the x-fold, 1.49 and 1.43 after
-it; and 1.09 for a build whose pool it mis-sized 13x.
+it; and 1.09 for a build whose pool it mis-sized 13x. Convolutions and
+the rest are mis-sized differently, which is why ``--by-module`` keeps
+them apart.
 
     JAX_PLATFORMS=cpu python tools/aot_cost.py rsunet-superhuman [--top 25]
+    JAX_PLATFORMS=cpu python tools/aot_cost.py rsunet-superhuman --by-module
 """
 import argparse
 import functools
@@ -29,11 +32,14 @@ _ENTRY = re.compile(r"^ENTRY ")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\(")
 _CYCLES = re.compile(r'"estimated_cycles":"(\d+)"')
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
+_KIND = re.compile(r", kind=(k\w+),")
+_NOT_CONV_ROOT = re.compile(r"reduce|scatter|dynamic-update-slice|sort", re.I)
 
 
 def entry_ops(hlo_text: str) -> list:
-    """``[(cycles, op, opcode, shape with layout, op_name)]`` of the entry
-    computation of one compiled module's text, in program order."""
+    """``[(cycles, op, opcode, shape with layout, op_name, fusion kind)]``
+    of the entry computation of one compiled module's text, in program
+    order."""
     ops, inside = [], False
     for line in hlo_text.splitlines():
         if _ENTRY.match(line):
@@ -48,8 +54,10 @@ def entry_ops(hlo_text: str) -> list:
         if match is None or cycles is None:
             continue
         op_name = _OP_NAME.search(line)
+        kind = _KIND.search(line)
         ops.append((int(cycles.group(1)), match.group(1), match.group(3),
-                    match.group(2), op_name.group(1) if op_name else ""))
+                    match.group(2), op_name.group(1) if op_name else "",
+                    kind.group(1) if kind else ""))
     return ops
 
 
@@ -58,6 +66,25 @@ def module_of(op_name: str) -> str:
     ``enc0/conv2/conv_general_dilated``."""
     parts = op_name.split("/")
     return "/".join(parts[2:]) if len(parts) > 2 else op_name
+
+
+def by_module(ops) -> dict:
+    """``{flax module: [cycles of its convolutions, cycles of the rest]}``
+    of :func:`entry_ops`' list. The module is the first name under the
+    model (``enc1``, ``up0``; ``-`` for what the model's own ``__call__``
+    emits, the pools and skip adds, and for XLA's unnamed copies). A
+    convolution is what the chip's trace reduction takes for one
+    (``benchmarks/cfbench/trace.py`` ``parse_op``): a ``kOutput`` fusion,
+    the convolution with its epilogue, unless its name says it is rooted
+    elsewhere (a reduce-window: the pool), or a bare ``convolution``."""
+    table: dict = {}
+    for cycles, op, opcode, _, op_name, kind in ops:
+        path = module_of(op_name).split("/")
+        row = table.setdefault(path[0] if len(path) > 1 else "-", [0, 0])
+        conv = opcode == "convolution" or (
+            kind == "kOutput" and not _NOT_CONV_ROOT.search(op))
+        row[0 if conv else 1] += cycles
+    return table
 
 
 def compile_forward(config: dict, batch: int):
@@ -100,13 +127,18 @@ def main(argv=None) -> int:
     parser.add_argument("--batch", type=int, default=None,
                         help="patches a program (default: the config's)")
     parser.add_argument("--top", type=int, default=25)
+    parser.add_argument("--by-module", action="store_true",
+                        help="cycles summed by flax module, convolutions "
+                        "apart from the rest, in place of the op list")
     parser.add_argument("--hlo", help="also write the optimized HLO here")
     args = parser.parse_args(argv)
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if checkout not in sys.path:  # run as a script: tools/ is on the path
+        sys.path.insert(0, checkout)
     path = args.config
     if not os.path.exists(path):
-        path = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "benchmarks", "configs",
-            args.config + ".json")
+        path = os.path.join(checkout, "benchmarks", "configs",
+                            args.config + ".json")
     with open(path) as f:
         config = json.load(f)
     compiled = compile_forward(config, args.batch or config["batch"])
@@ -117,7 +149,7 @@ def main(argv=None) -> int:
     ops = entry_ops(text)
     total = sum(op[0] for op in ops)
     by_opcode: dict = {}
-    for cycles, _, opcode, _, _ in ops:
+    for cycles, _, opcode, *_ in ops:
         by_opcode[opcode] = by_opcode.get(opcode, 0) + cycles
     print("XLA's cost model for a described v5e: a ranking of lowerings, "
           "NOT a measurement")
@@ -127,8 +159,16 @@ def main(argv=None) -> int:
     for opcode, cycles in sorted(by_opcode.items(), key=lambda kv: -kv[1]):
         print(f"  {opcode:<24} {cycles / 1e6:8.1f} M "
               f"{100.0 * cycles / total:5.1f}%")
+    if args.by_module:
+        print(f"{'module':<10} {'conv M':>8} {'rest M':>8} {'%':>5}")
+        for module, (conv, rest) in sorted(
+                by_module(ops).items(), key=lambda kv: -sum(kv[1])):
+            print(f"{module:<10} {conv / 1e6:8.1f} {rest / 1e6:8.1f} "
+                  f"{100.0 * (conv + rest) / total:5.1f}")
+        return 0
     print(f"{'Mcycles':>8} {'%':>5}  op / shape{{layout}} / flax module")
-    for cycles, op, _, shape, op_name in sorted(ops, reverse=True)[:args.top]:
+    for cycles, op, _, shape, op_name, _ in sorted(
+            ops, reverse=True)[:args.top]:
         print(f"{cycles / 1e6:8.2f} {100.0 * cycles / total:5.1f}  {op} "
               f"{shape} {module_of(op_name) or '-'}")
     return 0
