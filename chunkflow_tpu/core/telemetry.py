@@ -550,6 +550,9 @@ class _NullSpan:
     def cancel(self) -> None:
         pass
 
+    def annotate(self, **attrs) -> None:
+        pass
+
     def __enter__(self):
         return self
 
@@ -614,6 +617,12 @@ class _Span:
         """Record nothing when the block ends: for a span that turned
         out to time no work (a queue poll that found no task)."""
         self.cancelled = True
+
+    def annotate(self, **attrs) -> None:
+        """Attributes known only inside the block (how many requests a
+        read turned out to need): on the span's record like those given
+        at the start."""
+        self.attrs.update(attrs)
 
     def bind(self, trace_id: Optional[str]) -> None:
         """Give the span the task it turned out to work for: a span

@@ -165,7 +165,7 @@ DEFAULT_DEPTHS = {
     "inflight": 2,  # dispatched-but-undrained device outputs
     "post": 2,      # drain + host post-processing tasks in the worker pool
     "write": 2,     # tasks with storage writes still in flight
-    "storage": 8,   # concurrent block reads per cutout (volume/storage.py;
+    "storage": 8,   # driver requests in flight per cutout (volume/storage.py;
                     # floored at the live read_concurrency() in __init__)
 }
 
@@ -214,8 +214,8 @@ class DepthController:
             k: max(v, self.depths.get(k, 0))
             for k, v in dict(DEPTH_LIMITS, **(limits or {})).items()
         }
-        # the storage knob mirrors the live per-cutout block-read
-        # parallelism (volume/storage.py): start from whatever the env
+        # the storage knob mirrors the live per-cutout bound on driver
+        # requests in flight (volume/storage.py): start from whatever the env
         # knob resolved to, so the first controller raise widens it
         # instead of clamping it back down
         from chunkflow_tpu.volume import storage as _vol_storage
@@ -250,9 +250,9 @@ class DepthController:
         self._slot_bytes = max(self._slot_bytes, int(nbytes))
 
     def resident_slots(self) -> int:
-        # the storage knob is block-read parallelism, not a chunk-sized
-        # slot: blocks are orders of magnitude smaller than chunks and
-        # already bounded by the hot-block cache's own byte budget
+        # the storage knob bounds the requests of ONE cutout, which
+        # together never hold more than that cutout's blocks: no slot of
+        # its own however deep it is
         return sum(
             v for k, v in self.depths.items() if k != "storage"
         )
@@ -299,9 +299,8 @@ class DepthController:
                 continue  # ceiling or watermark: graceful static fallback
             self.depths[knob] = old + 1
             if knob == "storage":
-                # push the widened block-read parallelism to the live
-                # storage plane (volume/storage.py consumes it per
-                # cutout; the next read wave picks it up)
+                # push the widened bound to the live storage plane
+                # (volume/storage.py reads it once a cutout)
                 from chunkflow_tpu.volume import storage as _vol_storage
 
                 _vol_storage.set_read_concurrency(old + 1)

@@ -9,7 +9,8 @@ wrong shape at fleet scale:
 
 1. **Storage is block-granular.** A precomputed/zarr/n5 volume is a
    key-value store of fixed-size blocks; a cutout is a *set* of block
-   GETs that the serial path needlessly serializes behind one future.
+   GETs, and a worker can keep the ones its neighbor tasks will ask for
+   again.
 2. **Task grids overlap.** Inference chunks carry halos, so neighboring
    tasks re-fetch the same boundary blocks from cold storage — on an
    overlapping grid most block reads are repeats of a neighbor's.
@@ -31,10 +32,14 @@ plugins, in-memory test/bench fixtures):
   :func:`shared_cache` so halo reads of already-fetched blocks hit host
   memory (the page/block-granularity idiom Ragged Paged Attention uses
   to keep serving occupancy high, PAPERS.md);
-* :func:`blockwise_cutout` — a cutout as storage-block-aligned sub-reads
-  issued as concurrent backend futures (bounded by
-  :func:`read_concurrency`, an adaptive-scheduler knob) and assembled
-  host-side;
+* :func:`blockwise_cutout` — a cutout through the block cache: the blocks
+  the cache lacks go to the driver as one request over the box that
+  covers them (a few boxes where cached blocks lie in between;
+  :func:`read_concurrency`, an adaptive-scheduler knob, bounds the boxes
+  in flight), and the cache is fed from slices of what comes back. The
+  driver reads a box's blocks on threads of its own: a cutout costs this
+  thread one wait, not one for every eight blocks with the interpreter
+  lock to win back each time;
 * :func:`blockwise_save` — the coalescing write path: block-aligned
   writes commit as concurrent per-block futures (no read-modify-write)
   and update the cache write-through; unaligned writes fall back to one
@@ -46,10 +51,10 @@ single-read path bit-identically (:func:`storage_mode`);
 ``CHUNKFLOW_STORAGE_CACHE_MB=0`` disables the cache (every read goes to
 storage). Telemetry (docs/storage.md, docs/observability.md): spans
 ``storage/read`` / ``storage/write``; counters ``storage/hits``,
-``storage/misses``, ``storage/block_reads``, ``storage/bytes_read``,
-``storage/bytes_written``, ``storage/aligned_writes``,
-``storage/unaligned_writes``, ``storage/evictions``; gauge
-``storage/cache_bytes``.
+``storage/misses``, ``storage/block_reads``, ``storage/read_requests``,
+``storage/bytes_read``, ``storage/bytes_written``,
+``storage/aligned_writes``, ``storage/unaligned_writes``,
+``storage/evictions``; gauge ``storage/cache_bytes``.
 
 Coherence note: the cache is per-worker and trusts the write-once block
 layout — blocks observed all-zero (tensorstore's fill_missing rendering
@@ -112,11 +117,13 @@ _READ_CONCURRENCY: Optional[int] = None
 
 
 def read_concurrency() -> int:
-    """Concurrent block reads issued per cutout: the
-    ``CHUNKFLOW_STORAGE_CONCURRENCY`` initial value (default 8), runtime
-    adjustable via :func:`set_read_concurrency` — the adaptive
-    scheduler's ``storage`` depth knob widens it when ``scheduler/load``
-    dominates the stall breakdown (flow/scheduler.py)."""
+    """Driver requests (boxes of missing blocks, :func:`blockwise_cutout`)
+    a cutout keeps in flight: the ``CHUNKFLOW_STORAGE_CONCURRENCY``
+    initial value (default 8), runtime adjustable via
+    :func:`set_read_concurrency` — the adaptive scheduler's ``storage``
+    depth knob widens it when ``scheduler/load`` dominates the stall
+    breakdown (flow/scheduler.py). A cold cutout is one box, so the bound
+    bites only where cached blocks cut the misses into many."""
     with _CONC_LOCK:
         if _READ_CONCURRENCY is not None:
             return _READ_CONCURRENCY
@@ -128,8 +135,8 @@ def read_concurrency() -> int:
 
 
 def set_read_concurrency(n: int) -> None:
-    """Set the live per-cutout block-read parallelism (DepthController
-    ``storage`` knob; tests)."""
+    """Set the live per-cutout bound on requests in flight
+    (DepthController ``storage`` knob; tests)."""
     global _READ_CONCURRENCY
     with _CONC_LOCK:
         _READ_CONCURRENCY = max(1, int(n))
@@ -425,11 +432,13 @@ class MemoryBackend(StorageBackend):
     test fixture and the bench's cold-storage stand-in.
 
     ``latency_s`` charges a simulated per-BLOCK fetch latency (an object
-    GET per storage block, how remote stores actually bill a cutout):
-    reading ``[lo, hi)`` sleeps ``latency_s`` times the number of
-    storage blocks the range covers, inside a worker thread of the
-    backend's pool — so concurrent block reads genuinely overlap their
-    latencies and a serial whole-range read genuinely pays them all."""
+    GET per storage block, how remote stores actually bill a cutout),
+    slept inside a worker thread of the backend's pool. A read of
+    ``[lo, hi)`` is charged the storage blocks it covers ``max_workers``
+    at a time, which is how a driver reads a box: it fans the box out
+    over its blocks under its own request limit (one block is one
+    latency; a box of 741 blocks at 8 workers is 93). A write is charged
+    its blocks one after the other."""
 
     _SEQ = itertools.count()
 
@@ -441,6 +450,7 @@ class MemoryBackend(StorageBackend):
         self._array = array
         self._lock = threading.Lock()
         self._latency_s = float(latency_s)
+        self._max_workers = int(max_workers)
         self.cache_token = f"memory-{next(self._SEQ)}"
         self._block_shape = tuple(
             int(v) for v in (block_shape or array.shape)
@@ -478,7 +488,8 @@ class MemoryBackend(StorageBackend):
         if self._latency_s:
             # sleep OUTSIDE the lock (GL012): the latency is the remote
             # round-trip, not contention on the local buffer
-            time.sleep(self._latency_s * self._covered_blocks(lo, hi))
+            rounds = -(-self._covered_blocks(lo, hi) // self._max_workers)
+            time.sleep(self._latency_s * rounds)
         with self._lock:
             return np.array(self._array[self._slices(lo, hi)], copy=True)
 
@@ -819,26 +830,122 @@ def reset_open_backends() -> None:
 # ---------------------------------------------------------------------------
 # blockwise concurrent reads
 # ---------------------------------------------------------------------------
+def _block_ranges(lo, hi, block, goff):
+    """Per dimension, the grid indices (grid anchored at ``goff``) of the
+    blocks that ``[lo, hi)`` touches."""
+    return [
+        range((lo[d] - goff[d]) // block[d],
+              -((-(hi[d] - goff[d])) // block[d]))
+        for d in range(len(lo))
+    ]
+
+
+def _grid_bounds(start, stop, block, goff, dlo, dhi):
+    """Bounds ``(lo, hi)`` of the whole blocks with grid indices
+    ``[start, stop)``, clamped to the domain."""
+    ndim = len(start)
+    return (
+        tuple(max(goff[d] + start[d] * block[d], dlo[d])
+              for d in range(ndim)),
+        tuple(min(goff[d] + stop[d] * block[d], dhi[d])
+              for d in range(ndim)),
+    )
+
+
 def _covering_blocks(lo, hi, block, goff, dlo, dhi):
     """Clamped block bounds ``(blo, bhi)`` covering ``[lo, hi)`` on the
     grid anchored at ``goff``, in grid order."""
-    ndim = len(lo)
-    ranges = []
-    for d in range(ndim):
-        first = (lo[d] - goff[d]) // block[d]
-        last = -((-(hi[d] - goff[d])) // block[d])
-        ranges.append(range(first, last))
-    blocks = []
-    for idx in itertools.product(*ranges):
-        blo = tuple(
-            max(goff[d] + idx[d] * block[d], dlo[d]) for d in range(ndim)
-        )
-        bhi = tuple(
-            min(goff[d] + (idx[d] + 1) * block[d], dhi[d])
-            for d in range(ndim)
-        )
-        blocks.append((blo, bhi))
-    return blocks
+    return [
+        _grid_bounds(idx, [i + 1 for i in idx], block, goff, dlo, dhi)
+        for idx in itertools.product(*_block_ranges(lo, hi, block, goff))
+    ]
+
+
+def _tile_boxes(mask: np.ndarray) -> List[Tuple[tuple, tuple]]:
+    """Index boxes ``(start, stop)`` that tile exactly the True cells of
+    ``mask``. Greedy in grid order: the first cell not yet covered opens
+    a box, which grows along the last axis, then the one before, as long
+    as the whole next slab is True and uncovered. A mask that is all
+    True is one box; a False cell in the middle leaves the few boxes
+    around it; no box ever holds a False cell."""
+    mask = mask.copy()
+    boxes = []
+    for flat in np.flatnonzero(mask):
+        start = tuple(int(i) for i in np.unravel_index(flat, mask.shape))
+        if not mask[start]:
+            continue  # an earlier box took it
+        stop = [i + 1 for i in start]
+        for d in reversed(range(mask.ndim)):
+            while stop[d] < mask.shape[d]:
+                slab = tuple(
+                    slice(stop[d], stop[d] + 1) if k == d
+                    else slice(start[k], stop[k])
+                    for k in range(mask.ndim)
+                )
+                if not mask[slab].all():
+                    break
+                stop[d] += 1
+        mask[tuple(slice(a, b) for a, b in zip(start, stop))] = False
+        boxes.append((start, tuple(stop)))
+    return boxes
+
+
+def _box_blocks(arr: np.ndarray, box_lo, block, goff, dlo, dhi):
+    """The blocks of a box the driver returned, ready for the cache:
+    ``(blo, array)`` pairs, every array memory of its own (a view would
+    keep the whole box alive), all-zero blocks left out.
+
+    No numpy call is made per block. Each one gives the interpreter lock
+    up and has to win it back from the worker's other threads, and a
+    cutout has hundreds of blocks: per block ``.any()`` and a copy took
+    48 ms a cutout of 741 blocks alone and 216-248 ms beside the threads
+    a worker runs (the chip's host, PERF.md Findings PR 34); this takes
+    40 ms either way. The box is cut, per axis, into runs of equally
+    sized blocks (the whole ones, and the clamped one at the domain's
+    edge; along the first axis a row of blocks at a time); each part is
+    turned block-major by one copy and checked for zeros by one
+    reduction, and a block is then a slice of its bytes."""
+    ndim = arr.ndim
+    box_hi = tuple(l + s for l, s in zip(box_lo, arr.shape))
+    runs = []  # per axis: (offset in the box, block size, blocks)
+    for d, indices in enumerate(_block_ranges(box_lo, box_hi, block, goff)):
+        edges = [max(goff[d] + i * block[d], dlo[d]) for i in indices]
+        edges.append(box_hi[d])
+        axis, offset = [], 0
+        for size, group in itertools.groupby(
+                b - a for a, b in zip(edges, edges[1:])):
+            count = len(list(group))
+            axis.append((offset, size, count))
+            offset += size * count
+        runs.append(axis)
+    # one row of blocks along the first axis at a time: the block-major
+    # copy is then a few MB that the allocator hands back and forth, where
+    # a copy of the whole box is fresh memory every cutout, and on the
+    # chip's host the page faults of that cost more than the copying
+    # (212 MB: 461 ms whole, 178 ms by rows; PERF.md Findings PR 34)
+    runs[0] = [(o + i * s, s, 1) for o, s, n in runs[0] for i in range(n)]
+    for part in itertools.product(*runs):
+        counts = [n for _, _, n in part]
+        shape = tuple(s for _, s, _ in part)
+        # axes [n0, s0, n1, s1, ...] -> [n0, n1, ..., s0, s1, ...], in the
+        # backend's own axis order (n: blocks, s: block size)
+        split = arr[tuple(slice(o, o + s * n) for o, s, n in part)].reshape(
+            [v for _, s, n in part for v in (n, s)])
+        major = np.ascontiguousarray(split.transpose(
+            list(range(0, 2 * ndim, 2)) + list(range(1, 2 * ndim, 2))
+        )).reshape(int(np.prod(counts)), -1)
+        nonzero = major.any(axis=1)
+        raw = memoryview(major.view(np.uint8).reshape(-1))
+        nbytes = major.shape[1] * major.itemsize
+        for k, idx in enumerate(itertools.product(*map(range, counts))):
+            if nonzero[k]:
+                blo = tuple(
+                    box_lo[d] + part[d][0] + idx[d] * part[d][1]
+                    for d in range(ndim)
+                )
+                yield blo, np.frombuffer(
+                    bytes(raw[k * nbytes:(k + 1) * nbytes]), dtype=arr.dtype
+                ).reshape(shape)
 
 
 def _copy_block(out, lo, hi, arr, blo, bhi) -> None:
@@ -879,57 +986,88 @@ def serial_cutout(backend: StorageBackend, lo: Sequence[int],
 def blockwise_cutout(backend: StorageBackend, lo: Sequence[int],
                      hi: Sequence[int],
                      cache: Optional[BlockCache] = None) -> np.ndarray:
-    """Read ``[lo, hi)`` as storage-block-aligned sub-reads: cached
-    blocks are served from host memory; misses are issued as concurrent
-    backend futures in waves of :func:`read_concurrency` and assembled
-    host-side. Reads FULL (clamped) blocks even at the request edges —
-    the whole point: a neighbor task's halo read then hits the cache
-    instead of cold storage."""
+    """Read ``[lo, hi)`` through the block cache: cached blocks are
+    served from host memory, and the blocks the cache lacks go to the
+    driver as ONE request over the box of whole (clamped) blocks that
+    covers them, or the few boxes around cached blocks that lie in
+    between (:func:`_tile_boxes`: from the blocks' coordinates alone). The
+    driver fans a box out over its blocks on threads of its own, which
+    never take the interpreter lock; this thread waits once a box, copies
+    it into the result (or returns it, where the box is the request), and
+    feeds the cache from it (:func:`_box_blocks`: copies, zeros left out,
+    no numpy call a block). A box never covers a
+    cached block, so what a cache hit supplied (a block written and not
+    yet durable) is never overwritten by the driver's bytes.
+    :func:`read_concurrency` bounds the boxes in flight. Whole blocks are
+    read even at the request's edges: a neighbor task's halo read then
+    hits the cache instead of cold storage."""
     lo, hi = tuple(int(v) for v in lo), tuple(int(v) for v in hi)
     _check_domain(backend, lo, hi)
     dlo, dhi = backend.domain
-    out = np.empty(
-        tuple(h - l for l, h in zip(lo, hi)), dtype=backend.dtype
-    )
-    blocks = _covering_blocks(
-        lo, hi, backend.block_shape, backend.grid_offset, dlo, dhi
-    )
-    hits = 0
+    block, goff = backend.block_shape, backend.grid_offset
+    token = backend.cache_token
+    ranges = _block_ranges(lo, hi, block, goff)
+    blocks = _covering_blocks(lo, hi, block, goff, dlo, dhi)
+    missing = np.ones(len(blocks), dtype=bool)
+    hits = []
     bytes_read = 0
-    missing: List[tuple] = []
     with telemetry.span("storage/read", mode="blockwise",
-                        blocks=len(blocks)):
-        for blo, bhi in blocks:
-            cached = (
-                cache.get((backend.cache_token, blo))
-                if cache is not None else None
+                        blocks=len(blocks)) as span:
+        if cache is not None:
+            for k, bounds in enumerate(blocks):
+                cached = cache.get((token, bounds[0]))
+                if cached is not None:
+                    missing[k] = False
+                    hits.append((cached, bounds))
+        first = [r.start for r in ranges]
+        boxes = [
+            _grid_bounds(
+                [f + i for f, i in zip(first, start)],
+                [f + i for f, i in zip(first, stop)],
+                block, goff, dlo, dhi,
             )
-            if cached is None:
-                missing.append((blo, bhi))
-            else:
-                hits += 1
-                _copy_block(out, lo, hi, cached, blo, bhi)
+            for start, stop in _tile_boxes(
+                missing.reshape([len(r) for r in ranges]))
+        ]
+        span.annotate(requests=len(boxes))
+        # a request on block bounds with nothing cached is its one box:
+        # the driver's array is then the result, and is not copied
+        whole = boxes == [(lo, hi)]
+        out = None if whole else np.empty(
+            tuple(h - l for l, h in zip(lo, hi)), dtype=backend.dtype
+        )
+        for cached, (blo, bhi) in hits:
+            _copy_block(out, lo, hi, cached, blo, bhi)
         wave = max(1, read_concurrency())
-        for i in range(0, len(missing), wave):
-            batch = missing[i:i + wave]
+        for i in range(0, len(boxes), wave):
+            batch = boxes[i:i + wave]
             futures = [
-                backend.read_async(blo, bhi) for blo, bhi in batch
+                backend.read_async(box_lo, box_hi)
+                for box_lo, box_hi in batch
             ]
-            for (blo, bhi), future in zip(batch, futures):
+            for (box_lo, box_hi), future in zip(batch, futures):
                 arr = np.asarray(future.result())
                 bytes_read += arr.nbytes
-                # all-zero blocks may simply not exist yet (fill_missing
-                # rendering): never pin them — a later read must see the
-                # neighbor's eventual write, not stale cached zeros
-                if cache is not None and arr.any():
-                    cache.put((backend.cache_token, blo), arr)
-                _copy_block(out, lo, hi, arr, blo, bhi)
+                if whole:
+                    out = arr
+                else:
+                    _copy_block(out, lo, hi, arr, box_lo, box_hi)
+                if cache is not None:
+                    # all-zero blocks may simply not exist yet
+                    # (fill_missing rendering) and are never pinned: a
+                    # later read must see the neighbor's eventual write,
+                    # not stale cached zeros
+                    for blo, block_arr in _box_blocks(
+                            arr, box_lo, block, goff, dlo, dhi):
+                        cache.put((token, blo), block_arr)
     if telemetry.enabled():
-        if hits:
-            telemetry.inc("storage/hits", hits)
-        if missing:
-            telemetry.inc("storage/misses", len(missing))
-            telemetry.inc("storage/block_reads", len(missing))
+        n_missing = int(missing.sum())
+        if n_missing < len(blocks):
+            telemetry.inc("storage/hits", len(blocks) - n_missing)
+        if n_missing:
+            telemetry.inc("storage/misses", n_missing)
+            telemetry.inc("storage/block_reads", n_missing)
+            telemetry.inc("storage/read_requests", len(boxes))
             telemetry.inc("storage/bytes_read", bytes_read)
         if cache is not None:
             telemetry.gauge("storage/cache_bytes", cache.nbytes)

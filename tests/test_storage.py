@@ -37,11 +37,52 @@ def _clean_state():
     storage._reset_read_concurrency()
 
 
-def _backend(shape=(40, 50, 60), block=(16, 16, 16), seed=0, **kw):
+def _backend(shape=(40, 50, 60), block=(16, 16, 16), seed=0,
+             cls=MemoryBackend, **kw):
     rng = np.random.default_rng(seed)
     # 1..255: no all-zero block (zero blocks are deliberately uncached)
     data = rng.integers(1, 255, size=shape, dtype=np.uint8)
-    return data, MemoryBackend(data.copy(), block_shape=block, **kw)
+    return data, cls(data.copy(), block_shape=block, **kw)
+
+
+class CountingBackend(MemoryBackend):
+    """Records every driver request, and how many were in flight when
+    each was issued (issued and not yet awaited by the reader)."""
+
+    def __init__(self, *args, **kw):
+        super().__init__(*args, **kw)
+        self.requests = []      # (lo, hi) per read_async
+        self.in_flight = []     # requests outstanding at each issue
+        self._awaited = 0
+
+    def read_async(self, lo, hi):
+        backend = self
+        future = super().read_async(lo, hi)
+
+        class Awaited:
+            def result(self):
+                value = future.result()
+                backend._awaited += 1
+                return value
+
+        self.requests.append((tuple(lo), tuple(hi)))
+        self.in_flight.append(len(self.requests) - self._awaited)
+        return Awaited()
+
+
+def _counting():
+    return _backend(cls=CountingBackend)
+
+
+def _block_by_block(backend, lo, hi):
+    """The reference reader of the box path: every covering block read
+    on its own and assembled, as the cutout did before it sent boxes."""
+    out = np.empty([h - l for l, h in zip(lo, hi)], dtype=backend.dtype)
+    dlo, dhi = backend.domain
+    for blo, bhi in storage._covering_blocks(
+            lo, hi, backend.block_shape, backend.grid_offset, dlo, dhi):
+        storage._copy_block(out, lo, hi, backend._read(blo, bhi), blo, bhi)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -143,6 +184,7 @@ def test_cutout_counters_flow_into_telemetry_and_metrics():
     assert counters["storage/misses"] == 8
     assert counters["storage/hits"] == 8
     assert counters["storage/block_reads"] == 8
+    assert counters["storage/read_requests"] == 1  # one box, no second read
     assert counters["storage/bytes_read"] == 8 * 16 ** 3
     text = render_prometheus()
     assert "chunkflow_storage_hits_total" in text
@@ -167,12 +209,295 @@ def test_all_zero_blocks_are_never_pinned():
 
 
 def test_read_concurrency_waves_stay_correct():
-    data, backend = _backend()
+    """What the bound bounds now: the boxes a cutout has in flight.
+    A checkerboard of cached blocks leaves every missing block a box of
+    its own, the most requests a cutout can need."""
+    data, backend = _counting()            # 3 x 4 x 4 blocks
+    cache = BlockCache(1 << 24)
+    blocks = storage._covering_blocks(
+        (0, 0, 0), (40, 50, 60), backend.block_shape, (0, 0, 0),
+        *backend.domain)
+    for k, (blo, bhi) in enumerate(blocks):
+        if sum(np.unravel_index(k, (3, 4, 4))) % 2:
+            cache.put((backend.cache_token, blo), np.array(
+                data[tuple(slice(l, h) for l, h in zip(blo, bhi))]))
     set_read_concurrency(2)
-    out = blockwise_cutout(backend, (0, 0, 0), (40, 50, 60))
+    out = blockwise_cutout(backend, (0, 0, 0), (40, 50, 60), cache=cache)
     np.testing.assert_array_equal(out, data)
     assert storage.read_concurrency() == 2
+    assert len(backend.requests) == 24
+    assert max(backend.in_flight) == 2
     backend.close()
+
+
+# ---------------------------------------------------------------------------
+# one driver request a cutout
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("lo, hi, box", [
+    ((0, 0, 0), (40, 50, 60), ((0, 0, 0), (40, 50, 60))),    # whole volume
+    ((3, 5, 7), (37, 49, 55), ((0, 0, 0), (40, 50, 60))),    # nothing aligned
+    ((17, 18, 19), (30, 31, 32), ((16, 16, 16), (32, 32, 32))),  # one block
+    ((20, 20, 20), (36, 36, 36), ((16, 16, 16), (40, 48, 48))),  # 2x2x2
+    # at the domain's edge the box clamps as the trailing blocks do
+    ((33, 49, 50), (40, 50, 60), ((32, 48, 48), (40, 50, 60))),
+])
+@pytest.mark.parametrize("with_cache", [False, True])
+def test_cold_cutout_is_one_driver_request(lo, hi, box, with_cache):
+    data, backend = _counting()
+    cache = BlockCache(1 << 24) if with_cache else None
+    out = blockwise_cutout(backend, lo, hi, cache=cache)
+    assert backend.requests == [box]
+    np.testing.assert_array_equal(out, _block_by_block(backend, lo, hi))
+    np.testing.assert_array_equal(
+        out, data[tuple(slice(l, h) for l, h in zip(lo, hi))])
+    if with_cache:
+        # every block of the box is in the cache, as memory of its own
+        # and no more (a view would keep the whole box alive)
+        blocks = storage._covering_blocks(
+            lo, hi, backend.block_shape, (0, 0, 0), *backend.domain)
+        assert len(cache) == len(blocks)
+        for blo, bhi in blocks:
+            cached = cache.get((backend.cache_token, blo))
+            owner = cached
+            while getattr(owner, "base", None) is not None:
+                owner = owner.base
+            assert len(memoryview(owner).cast("B")) == cached.nbytes
+            assert not cached.flags.writeable
+            np.testing.assert_array_equal(
+                cached, data[tuple(slice(l, h) for l, h in zip(blo, bhi))])
+        # and the same cutout again asks the driver for nothing; the
+        # first result is the caller's to write on (where the box is the
+        # request it is the driver's own array, and the cache holds copies)
+        assert out.flags.writeable and out.flags.c_contiguous
+        out[...] = 0
+        again = blockwise_cutout(backend, lo, hi, cache=cache)
+        assert backend.requests == [box]
+        np.testing.assert_array_equal(
+            again, data[tuple(slice(l, h) for l, h in zip(lo, hi))])
+    backend.close()
+
+
+def _cached(pattern):
+    """Grid indices (3 x 4 x 4 blocks) of the blocks to cache first."""
+    cells = list(np.ndindex(3, 4, 4))
+    return {
+        "one in the middle": [(1, 1, 2)],
+        "two apart": [(0, 1, 1), (2, 2, 3)],
+        "the neighbor's halo (a slab)": [c for c in cells if c[2] == 0],
+        "a corner": [(0, 0, 0)],
+        "a row through the middle": [c for c in cells
+                                     if c[0] == 1 and c[1] == 2],
+        "all but one": [c for c in cells if c != (2, 3, 3)],
+        "all": cells,
+    }[pattern]
+
+
+@pytest.mark.parametrize("pattern, n_boxes", [
+    ("one in the middle", 6), ("two apart", 8),
+    ("the neighbor's halo (a slab)", 1), ("a corner", 3),
+    ("a row through the middle", 4), ("all but one", 1), ("all", 0),
+])
+def test_cached_blocks_leave_the_few_boxes_around_them(pattern, n_boxes):
+    """The boxes tile exactly the missing blocks: none covers a cached
+    block, none is read twice, and the bytes are the block-by-block
+    path's."""
+    data, backend = _counting()
+    cache = BlockCache(1 << 24)
+    cached = _cached(pattern)
+    for idx in cached:
+        blo = tuple(16 * i for i in idx)
+        bhi = tuple(min(b + 16, d) for b, d in zip(blo, data.shape))
+        # what the cache holds differs from the driver's bytes, so a box
+        # laid over a cached block would show
+        cache.put((backend.cache_token, blo), np.full(
+            [h - l for l, h in zip(blo, bhi)], 255, dtype=np.uint8))
+    out = blockwise_cutout(backend, (1, 2, 3), (40, 50, 60), cache=cache)
+    want = data.copy()
+    covered = np.zeros(data.shape, dtype=int)
+    for idx in cached:
+        sel = tuple(slice(16 * i, 16 * i + 16) for i in idx)
+        want[sel] = 255
+        covered[sel] += 1
+    for lo, hi in backend.requests:
+        covered[tuple(slice(l, h) for l, h in zip(lo, hi))] += 1
+    assert (covered == 1).all()
+    assert len(backend.requests) == n_boxes
+    np.testing.assert_array_equal(out, want[1:, 2:, 3:])
+    counters = telemetry.snapshot()["counters"]
+    assert counters.get("storage/read_requests", 0) == n_boxes
+    assert counters.get("storage/block_reads", 0) == 48 - len(cached)
+    assert counters.get("storage/hits", 0) == len(cached)
+    backend.close()
+
+
+@pytest.mark.parametrize("shape, holes", [
+    ((1,), []), ((5,), [(2,)]), ((3, 4), [(1, 1), (2, 3)]),
+    ((3, 4, 4), [(1, 1, 2)]), ((19, 13, 3, 1), [(9, 6, 1, 0)]),
+    ((4, 4), [(i, j) for i in range(4) for j in range(4) if (i + j) % 2]),
+    ((2, 3), [(i, j) for i in range(2) for j in range(3)]),
+])
+def test_tile_boxes_tile_the_true_cells_exactly(shape, holes):
+    mask = np.ones(shape, dtype=bool)
+    for hole in holes:
+        mask[hole] = False
+    covered = np.zeros(shape, dtype=int)
+    boxes = storage._tile_boxes(mask)
+    for start, stop in boxes:
+        covered[tuple(slice(a, b) for a, b in zip(start, stop))] += 1
+    np.testing.assert_array_equal(covered, mask.astype(int))
+    assert len(boxes) <= max(1, 2 * len(shape) * len(holes))
+    if not holes:
+        assert boxes == [((0,) * len(shape), shape)]
+
+
+@pytest.mark.parametrize("dtype, dlo, dhi, goff, block, box_lo, box_hi", [
+    # ragged at the domain's end
+    ("uint8", (0, 0, 0), (40, 50, 60), (0, 0, 0), (16, 16, 16),
+     (0, 0, 0), (40, 50, 60)),
+    # a box inside, whole blocks only
+    ("uint8", (0, 0, 0), (40, 50, 60), (0, 0, 0), (16, 16, 16),
+     (16, 16, 32), (32, 48, 48)),
+    # xyzc with the channels in the block, float32
+    ("float32", (0, 0, 0, 0), (20, 12, 9, 3), (0, 0, 0, 0), (8, 4, 4, 3),
+     (0, 0, 0, 0), (20, 12, 9, 3)),
+    # the grid anchored before the domain: the first block is clamped too
+    ("uint16", (0, 0), (30, 21), (-5, -3), (8, 8), (0, 0), (30, 21)),
+    # one block
+    ("uint8", (0,), (7,), (0,), (16,), (0,), (7,)),
+    # a dtype whose buffer format is no single character
+    ("complex64", (0, 0), (9, 8), (0, 0), (4, 4), (0, 0), (9, 8)),
+])
+def test_box_blocks_are_the_blocks_of_the_box(dtype, dlo, dhi, goff, block,
+                                              box_lo, box_hi):
+    rng = np.random.default_rng(5)
+    shape = [h - l for l, h in zip(box_lo, box_hi)]
+    arr = rng.integers(1, 200, size=shape).astype(dtype)
+    blocks = storage._covering_blocks(box_lo, box_hi, block, goff, dlo, dhi)
+    zero = blocks[len(blocks) // 2]
+    sel = lambda b: tuple(                              # noqa: E731
+        slice(l - o, h - o) for l, h, o in zip(b[0], b[1], box_lo))
+    if len(blocks) > 1:
+        arr[sel(zero)] = 0
+    got = dict(storage._box_blocks(arr, box_lo, block, goff, dlo, dhi))
+    want = {b[0]: arr[sel(b)] for b in blocks
+            if len(blocks) == 1 or b is not zero}
+    assert sorted(got) == sorted(want)
+    for blo, block_arr in got.items():
+        assert block_arr.dtype == arr.dtype
+        np.testing.assert_array_equal(block_arr, want[blo])
+        assert not np.shares_memory(block_arr, arr)
+
+
+def test_cached_bytes_win_over_the_drivers():
+    """Read-after-write through the cache: a block written with
+    ``wait=False`` and not yet durable is served from the cache, in the
+    middle of a cutout whose other blocks come from the driver."""
+    data, backend = _counting()
+    cache = BlockCache(1 << 24)
+
+    class Done:
+        def result(self):
+            return None
+
+    class Pending:
+        """The source is copied, the commit waits for the drain: the
+        write-behind window held open."""
+        copy = Done()
+
+        def __init__(self, lo, hi, arr):
+            self.args = (lo, hi, np.array(arr))
+
+        def result(self):
+            backend._write(*self.args)
+
+    backend.write_async = Pending
+    w = np.full((16, 16, 16), 200, dtype=np.uint8)
+    pending = blockwise_save(backend, (16, 16, 16), w, cache=cache,
+                             wait=False)
+    out = blockwise_cutout(backend, (0, 0, 0), (40, 50, 60), cache=cache)
+    # the driver still holds the old bytes; the cutout must not
+    np.testing.assert_array_equal(backend._array[16:32, 16:32, 16:32],
+                                  data[16:32, 16:32, 16:32])
+    want = data.copy()
+    want[16:32, 16:32, 16:32] = 200
+    np.testing.assert_array_equal(out, want)
+    assert all(
+        not all(l <= 16 and h >= 32 for l, h in zip(lo, hi))
+        for lo, hi in backend.requests)
+    pending.result()
+    np.testing.assert_array_equal(
+        serial_cutout(backend, (0, 0, 0), (40, 50, 60)), want)
+    backend.close()
+
+
+def test_zero_block_inside_a_box_is_not_pinned():
+    data, backend = _counting()
+    backend._array[16:32, 32:48, 0:16] = 0       # one absent block
+    cache = BlockCache(1 << 24)
+    out = blockwise_cutout(backend, (0, 0, 0), (40, 50, 60), cache=cache)
+    assert len(backend.requests) == 1
+    assert not out[16:32, 32:48, 0:16].any()
+    assert len(cache) == 47
+    assert cache.get((backend.cache_token, (16, 32, 0))) is None
+    # the neighbor writes it; the next cutout asks the driver for that
+    # block alone and sees the write
+    backend._array[16:32, 32:48, 0:16] = 9
+    out = blockwise_cutout(backend, (0, 0, 0), (40, 50, 60), cache=cache)
+    assert backend.requests[1:] == [((16, 32, 0), (32, 48, 16))]
+    assert (out[16:32, 32:48, 0:16] == 9).all()
+    backend.close()
+
+
+def test_read_span_carries_requests_beside_blocks(tmp_path):
+    import json
+
+    path = telemetry.configure(str(tmp_path))
+    _data, backend = _counting()
+    blockwise_cutout(backend, (0, 0, 0), (40, 50, 60))
+    telemetry.flush()
+    events = [json.loads(line) for line in open(path).read().splitlines()
+              if line]
+    assert [(e["mode"], e["blocks"], e["requests"]) for e in events
+            if e["kind"] == "span" and e["name"] == "storage/read"] == [
+        ("blockwise", 48, 1)]
+    backend.close()
+
+
+def test_precomputed_cutout_is_one_request_and_stays_strict(
+        tmp_path, monkeypatch):
+    """Through the real driver: a cold cutout of a ``file://`` volume is
+    one ``read_async`` whose bytes are the serial path's; absent blocks
+    read as zeros and are not pinned; strict mode still raises."""
+    pytest.importorskip("tensorstore")
+    from chunkflow_tpu.chunk.base import Chunk
+    from chunkflow_tpu.core.bbox import BoundingBox
+    from chunkflow_tpu.volume.precomputed import PrecomputedVolume
+
+    vol = PrecomputedVolume.create(
+        str(tmp_path / "v"), volume_size=(24, 40, 48), dtype="uint8",
+        voxel_size=(1, 1, 1), block_size=(8, 16, 16))
+    rng = np.random.default_rng(3)
+    top = rng.integers(1, 255, size=(16, 40, 48), dtype=np.uint8)
+    vol.save(Chunk(top))                     # z [16, 24) is never written
+    backend = vol._backend(0)
+    requests = []
+    read_async = backend.read_async
+    monkeypatch.setattr(
+        backend, "read_async",
+        lambda lo, hi: requests.append((lo, hi)) or read_async(lo, hi))
+    storage.reset_shared_cache()
+    box = BoundingBox((1, 3, 5), (24, 40, 47))
+    out = np.asarray(vol.cutout(box).array)
+    assert requests == [((0, 0, 0, 0), (48, 40, 24, 1))]   # xyzc, clamped
+    want = np.zeros((24, 40, 48), dtype=np.uint8)
+    want[:16] = top
+    np.testing.assert_array_equal(out, want[1:, 3:, 5:47])
+    assert len(shared_cache()) == 2 * 3 * 3  # the written blocks only
+    monkeypatch.setenv("CHUNKFLOW_STORAGE", "serial")
+    np.testing.assert_array_equal(np.asarray(vol.cutout(box).array), out)
+    monkeypatch.delenv("CHUNKFLOW_STORAGE")
+    with pytest.raises(FileNotFoundError):
+        vol.cutout(box, fill_missing=False)
 
 
 def test_out_of_domain_requests_raise():
