@@ -27,6 +27,8 @@ class RunRecord:
     failed: int = 0
     correct: bool = False
     notes: list = dataclasses.field(default_factory=list)
+    # what ``correct`` compared: name -> {"value", "limit"}
+    checks: dict = dataclasses.field(default_factory=dict)
 
     def spans_in_window(self, name: str) -> list:
         """Spans of ``name`` that ended inside the measured window, each

@@ -272,6 +272,7 @@ def run(ctx) -> RunRecord:
     answer = decode_answer(client.check_raw[1])
     answer = answer.reshape((-1,) + answer.shape[-3:])
     payload = client.payloads[client.check_index]
+    ctx.memory_peaks()     # before the reference's programs load
     want, n_patches = check.reference_output(
         ctx, payload.array, ((0, 0, 0), payload.shape))
     check.judge(

@@ -306,6 +306,7 @@ def run(ctx) -> RunRecord:
     if cleaner.check_block is None:
         record.notes.append("the comparison's task was never committed")
         return record
+    ctx.memory_peaks()     # before the reference's programs load
     want, n_patches = check.reference_output(
         ctx, volume.seeded_task_input(ctx.seed, g, cleaner.check_task),
         g.check_box())

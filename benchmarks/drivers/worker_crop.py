@@ -7,8 +7,8 @@ they are. What differs is what that driver builds from one ``patch`` and
 one ``overlap``: the geometry (:mod:`cfbench.crop_volume`, from the
 configuration's ``patch``, ``output_patch`` and ``overlap``, the last
 between *output* patches), the ``inference`` command line, and the
-comparison that decides ``correct`` (:mod:`cfbench.crop_blend`, under the
-configuration's bounds on the largest and on the mean difference).
+comparison that decides ``correct`` (:mod:`cfbench.crop_blend`'s reference
+under :func:`cfbench.check.judge`).
 
 The traffic file's parameters are the ``worker`` kind's. ``margin`` is
 what ``load-precomputed`` expands a task by and ``crop-margin`` takes
@@ -142,6 +142,7 @@ def run(ctx) -> RunRecord:
         record.notes.append("the comparison's task was never committed")
         return record
     box = g.check_box()
+    ctx.memory_peaks()     # before the reference's programs load
     want, n_patches = crop_blend.reference_output(
         ctx, volume.seeded_task_input(ctx.seed, g, cleaner.check_task), box)
     check.judge(
@@ -150,5 +151,4 @@ def run(ctx) -> RunRecord:
         f"reference patches",
         {"every fetched task committed": record.failed == 0,
          "queue empty": left == 0})
-    crop_blend.judge_mean(record, cleaner.check_block, want)
     return record

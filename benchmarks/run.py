@@ -13,12 +13,13 @@ metrics with no telemetry and no profiler; ``--trace 1`` reports its
 per-layer metrics from the program's ``--metrics-dir`` and a device trace
 of a few steady seconds.
 
-Without a TPU, with fewer chips than the cell asks for, or on a
-``device_kind`` the peaks table lacks, it exits non-zero and prints no
-result. ``--rehearse`` runs the same code at the tiny sizes the
-configuration and traffic files give under ``rehearse``, on CPU devices,
-and reports ``platform: cpu``: a rehearsal of the control flow, never a
-measurement.
+Without a TPU, with fewer chips than the cell asks for, on a
+``device_kind`` the peaks table lacks, or where the cell's traffic keeps
+its volumes in memory and ``/dev/shm`` has no room for them
+(:mod:`cfbench.workdir`), it exits non-zero and prints no result.
+``--rehearse`` runs the same code at the tiny sizes the configuration and
+traffic files give under ``rehearse``, on CPU devices, and reports
+``platform: cpu``: a rehearsal of the control flow, never a measurement.
 """
 import time
 
@@ -27,23 +28,23 @@ T0 = time.time()   # set-up is counted from here
 import argparse      # noqa: E402
 import json          # noqa: E402
 import os            # noqa: E402
-import shutil        # noqa: E402
 import sys           # noqa: E402
 import threading     # noqa: E402
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 CHECKOUT = os.path.dirname(BENCH_DIR)
+CHECKOUT_WORK = os.path.join(BENCH_DIR, ".work")
 sys.path.insert(0, BENCH_DIR)
 sys.path.insert(0, CHECKOUT)
 
-from cfbench import catalog, peaks, trace  # noqa: E402
+from cfbench import catalog, peaks, trace, workdir  # noqa: E402
 
 
 class Context:
     """What a driver gets: the cell's data, the run's arguments, a work
     directory, and the two services that need the chip's one process."""
 
-    def __init__(self, args, bench, device):
+    def __init__(self, args, bench):
         self.t0 = T0
         self.cell = catalog.cell(bench, args.workload)
         self.config = catalog.config_of(bench, self.cell)
@@ -55,12 +56,13 @@ class Context:
                             **self.traffic.get("rehearse", {})}
         self.seed, self.seconds = args.seed, args.seconds
         self.trace, self.rehearse = bool(args.trace), args.rehearse
-        self.device = device
-        self.work = os.path.join(BENCH_DIR, ".work",
-                                 f"{self.cell['name']}-{os.getpid()}")
+        self.device = None     # as JAX reports it, once it is asked
+        self.work, self.work_note = workdir.place(
+            self.traffic, self.cell["name"], args.rehearse, CHECKOUT_WORK)
         self.metrics_dir = os.path.join(self.work, "metrics")
         self.trace_dir = os.path.join(self.work, "trace")
         self.profiler_error = None
+        self._memory_peaks = None
 
     def start_profiler_thread(self, window, spec) -> threading.Thread:
         """Trace ``spec['seconds']`` steady seconds, ``start_after_s``
@@ -86,6 +88,16 @@ class Context:
                                   daemon=True)
         thread.start()
         return thread
+
+    def memory_peaks(self) -> dict:
+        """The devices' peaks, read once: a driver asks when its window
+        has closed and before the plain reference runs on the chip (a
+        process's peak never falls again, and the reference's programs
+        reserve scratch of their own); ``main`` asks again and gets the
+        same."""
+        if self._memory_peaks is None:
+            self._memory_peaks = memory_peaks(int(self.cell["chips"]))
+        return self._memory_peaks
 
     @staticmethod
     def resolve_args(args: list) -> list:
@@ -200,21 +212,23 @@ def main() -> int:
         ).strip()
         # CPU entries stay out of <checkout>/.jax_cache
         os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(
-            BENCH_DIR, ".work", "rehearsal-cache"))
+            CHECKOUT_WORK, "rehearsal-cache"))
 
-    device = describe_devices(chips, args.rehearse)
+    # before JAX is asked for a device: a cell whose volumes find no room
+    # in memory ends here, as one that finds no chip ends below
+    ctx = Context(args, bench)
+    print(f"note: {ctx.work_note}", file=sys.stderr)
+    ctx.device = device = describe_devices(chips, args.rehearse)
     place_compile_cache()
-    ctx = Context(args, bench, device)
     for name, value in (ctx.traffic.get("env") or {}).items():
         os.environ[name] = str(value)
     driver = catalog.load_module("drivers", ctx.traffic["kind"])
-    shutil.rmtree(ctx.work, ignore_errors=True)
-    os.makedirs(ctx.work)
+    workdir.create(ctx.work, CHECKOUT_WORK)
     try:
         record = driver.run(ctx)
         if ctx.profiler_error is not None:
             raise ctx.profiler_error
-        record.client.update(memory_peaks(chips))
+        record.client.update(ctx.memory_peaks())
         for note in record.notes:
             print(f"note: {note}", file=sys.stderr)
         breakdown = None
@@ -233,7 +247,7 @@ def main() -> int:
             catalog.metrics_of(bench, ctx.cell["name"], group),
             directory, record)
     finally:
-        shutil.rmtree(ctx.work, ignore_errors=True)
+        workdir.remove(ctx.work)
 
     # the HBM the process occupied on the fullest chip: buffers and the
     # programs' scratch, which this runtime counts apart
@@ -251,6 +265,13 @@ def main() -> int:
             "metrics": metrics, "device": device_out}
     if breakdown is not None:
         line["breakdown"] = breakdown
+    # each number compared beside its limit: last in the line, and the
+    # run's last words on standard error
+    line["checks"] = record.checks
+    for name, check in record.checks.items():
+        print(f"check: {name} {check['value']!r} limit {check['limit']!r}",
+              file=sys.stderr)
+    sys.stderr.flush()
     sys.stdout.flush()
     print(json.dumps(line))
     return 0

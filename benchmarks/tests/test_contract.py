@@ -143,6 +143,20 @@ def test_the_harness_names_no_cell_config_or_metric():
         assert "CHUNKFLOW_" not in text, path
 
 
+def test_parked_metrics_belong_to_parked_cells():
+    """``parked.json`` holds every entry of a parked cell, the span metrics
+    of PR 23 among them (until PR 35 in a file beside it), and none that
+    names an accepted cell."""
+    extra = parked()
+    cells = {w["name"] for w in extra["workloads"]}
+    for m in extra["end_to_end"] + extra["per_layer"]:
+        assert m["workloads"] and set(m["workloads"]) <= cells, m["name"]
+    names = {m["name"] for m in extra["per_layer"]}
+    assert {"data4_task_inside_p50_ms", "data4_idle_unattributed_share",
+            "serve_codec_ms_request"} <= names
+    assert not os.path.exists(os.path.join(BENCH_DIR, "parked_spans.json"))
+
+
 def test_every_parked_cell_says_why():
     extra = parked()
     assert {w["name"] for w in extra["workloads"]} == set(extra["why"])

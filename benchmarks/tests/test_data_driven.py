@@ -35,8 +35,8 @@ def test_new_files_and_entries_are_enough(tmp_path):
     traffic["rehearse"]["patch_grid"] = [2, 3, 2]
     put("traffic/volume-tall.json", traffic)
     # a per-layer metric over a span no metric reads yet
-    put("layer_metrics/dispatch_ms_task.json",
-        {"reducer": "span_ms_per_task", "args": {"name": "pipeline/dispatch"}})
+    put("layer_metrics/stage_ms_task.json",
+        {"reducer": "span_ms_per_task", "args": {"name": "pipeline/stage"}})
     cell = "rsunet-narrow.volume-tall"
     bench["configs"].append({
         "name": "rsunet-narrow", "source": "test",
@@ -49,7 +49,7 @@ def test_new_files_and_entries_are_enough(tmp_path):
         if "rsunet-deepem.volume" in metric.get("workloads", []):
             metric["workloads"].append(cell)
     bench["per_layer"].append({
-        "name": "dispatch_ms_task", "unit": "ms", "better": "lower",
+        "name": "stage_ms_task", "unit": "ms", "better": "lower",
         "source": "program_span", "layer": "inferencer, program cache",
         "moves": "volume_mvox_s", "workloads": [cell]})
     with open(os.path.join(checkout, "BENCHMARK.json"), "w") as f:
@@ -61,7 +61,7 @@ def test_new_files_and_entries_are_enough(tmp_path):
         line = json.loads(done.stdout.strip().splitlines()[-1])
         check_line(bench, cell, trace, line)
         if trace:
-            assert line["metrics"]["dispatch_ms_task"]["value"] > 0
+            assert line["metrics"]["stage_ms_task"]["value"] > 0
     # nothing that was there has been edited
     for path, content in before.items():
         with open(path, "rb") as f:

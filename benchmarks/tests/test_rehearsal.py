@@ -29,6 +29,10 @@ def check_line(bench, cell, trace, line):
                          "device"}
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0
+    # each number compared beside its limit, last in the line
+    assert list(line)[-1] == "checks" and line["checks"]
+    for check in line["checks"].values():
+        assert 0 <= check["value"] <= check["limit"]
     chips = next(w["chips"] for w in bench["workloads"] if w["name"] == cell)
     assert line["device"]["platform"] == "cpu"
     assert line["device"]["count"] == chips

@@ -186,43 +186,3 @@ def test_codec_ms_per_request():
                       per="serving/http") == pytest.approx(1000.0)
     assert per.reduce(record(window=(0.0, 10.0)), names=["serving/decode"],
                       per="serving/http") is None
-
-
-# ---------------------------------------------------------------------------
-# the same metrics for the parked cells: entries kept beside parked.json
-# (benchmarks/parked_spans.json) until a `benchmark` PR moves them in
-# ---------------------------------------------------------------------------
-def with_parked_spans():
-    import json
-    import os
-
-    from conftest import BENCH_DIR, merged_bench
-    with open(os.path.join(BENCH_DIR, "parked_spans.json")) as f:
-        extra = json.load(f)
-    merged = merged_bench()
-    merged["per_layer"] = merged["per_layer"] + extra["per_layer"]
-    return merged, extra["per_layer"]
-
-
-def test_parked_span_entries_keep_the_contract():
-    import test_contract
-    merged, extra = with_parked_spans()
-    test_contract.test_metrics(lambda: merged)
-    parked_cells = {w["name"] for w in merged["workloads"]} - {
-        w["name"] for w in test_contract.bench()["workloads"]}
-    for m in extra:
-        assert set(m["workloads"]) <= parked_cells
-
-
-def test_parked_cells_rehearse_with_their_span_entries(tmp_path):
-    import json
-
-    from conftest import make_checkout
-    from test_rehearsal import check_line, run_cell
-    merged, extra = with_parked_spans()
-    checkout = make_checkout(str(tmp_path), merged)
-    for cell in sorted({c for m in extra for c in m["workloads"]}):
-        done = run_cell(checkout, cell, 1)
-        assert done.returncode == 0, done.stderr[-2000:]
-        line = json.loads(done.stdout.strip().splitlines()[-1])
-        check_line(merged, cell, 1, line)
