@@ -139,10 +139,11 @@ def device_peaks(device_kind: str) -> dict:
 #: under (ops/blend.py, ops/pallas_gather.py, ops/fold_blend.py,
 #: parallel/engine.py, serve/packer.py): patch gather, model forward,
 #: bump-weighted accumulation, weight normalization, and the mesh
-#: engine's cross-chip exchanges. Scopes are metadata: the compiled
-#: code is the same with and without them.
+#: engine's cross-chip exchanges; and, in the program ops/mask.py builds
+#: for a device-resident chunk, ``mask``. Scopes are metadata: the
+#: compiled code is the same with and without them.
 DEVICE_SCOPES = ("gather", "forward", "accumulate", "normalize",
-                 "collective")
+                 "collective", "mask")
 
 _HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
 _HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = (.*)$")

@@ -2750,6 +2750,7 @@ def mask_cmd(op_name, volume_path, mip, inverse, fill_missing, input_chunk_name,
     """Multiply the chunk(s) by a (usually coarser-resolution) mask volume."""
     import math
 
+    from chunkflow_tpu.core import telemetry
     from chunkflow_tpu.core.bbox import BoundingBox
     from chunkflow_tpu.core.cartesian import Cartesian
     from chunkflow_tpu.ops.mask import maskout
@@ -2775,11 +2776,18 @@ def mask_cmd(op_name, volume_path, mip, inverse, fill_missing, input_chunk_name,
         stop = Cartesian(
             *(int(math.ceil(e / f)) for e, f in zip(first.bbox.stop, factor))
         )
-        mask_chunk = vol.cutout(
-            BoundingBox(start, stop), mip=mip, fill_missing=fill_missing
-        )
+        coarse_box = BoundingBox(start, stop)
+        with telemetry.span(
+                "mask/cutout",
+                blocks=len(vol.block_names(coarse_box, mip))) as sp:
+            mask_chunk = vol.cutout(
+                coarse_box, mip=mip, fill_missing=fill_missing
+            )
+            sp.annotate(bytes=int(mask_chunk.array.nbytes))
         # one mask cutout masks every listed chunk (reference flow
-        # applies MaskOperator to a chunk list)
+        # applies MaskOperator to a chunk list); each is masked where it
+        # is, host or device (ops/mask.py), and a cutout that is all
+        # zero zeroes it with no multiply and nothing uploaded
         for in_name, out_name in zip(in_names, out_names):
             task[out_name] = maskout(
                 task[in_name], mask_chunk, inverse=inverse

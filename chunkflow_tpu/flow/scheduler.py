@@ -430,8 +430,11 @@ def _pump(source: Iterator, q: _AdaptiveQueue) -> None:
 
 def _start_pump(source: Iterable, capacity: int):
     q = _AdaptiveQueue(capacity)
+    # the operators in front of `inference` (fetch, load, mask) run here:
+    # a span's `thread` field says so by name
     thread = threading.Thread(
-        target=_pump, args=(iter(source), q), daemon=True
+        target=_pump, args=(iter(source), q), daemon=True,
+        name="scheduler-pump",
     )
     thread.start()
     return q, thread
@@ -696,7 +699,8 @@ def scheduled_inference_stage(
         staged: deque = deque()     # (task, slot, owned, t0)
         pending: deque = deque()    # (task, device_out, t0)
         finishing: deque = deque()  # post-pool futures, input order
-        pool = ThreadPoolExecutor(max_workers=ctl.limits["post"])
+        pool = ThreadPoolExecutor(max_workers=ctl.limits["post"],
+                                  thread_name_prefix="scheduler-post")
 
         def finalize(task, out, t0):
             # runs in the pool: compute/drain attribution rides along

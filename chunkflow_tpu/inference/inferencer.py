@@ -730,10 +730,19 @@ class Inferencer:
         # queued behind the programs in flight, and its answer is waited
         # for: in a device-bound pipeline the host spends most of a task
         # here, and its own span says so
-        with telemetry.span("inference/blank_check"):
+        with telemetry.span("inference/blank_check") as check:
             blank = self.dry_run or chunk.all_zero()
+            # which tasks took the blank path, for a reader of the stream
+            check.annotate(blank=int(blank))
+        telemetry.inc("inference/tasks")
         if blank:
-            return self._blank_output(chunk)
+            out = self._blank_output(chunk)
+            # what a blank chunk costs downstream: its zeros are cropped,
+            # masked and written like any result
+            telemetry.inc("inference/blank_tasks")
+            telemetry.gauge("inference/blank_output_bytes",
+                            out.array.nbytes)
+            return out
 
         orig_zyx = tuple(chunk.shape[-3:])
         run_zyx = self._run_shape(orig_zyx)

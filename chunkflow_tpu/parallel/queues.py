@@ -447,6 +447,13 @@ class FileQueue(QueueBase):
     requeues, so retry accounting sees crashed attempts too) and
     ``<dir>/dead/<id>`` holds dead-lettered tasks as JSON
     ``{body, reason, receives, t}``.
+
+    An id starts with the time the task was sent (nanoseconds, zero
+    padded), so ``receive``, which claims the first pending name in
+    sorted order, hands out the oldest task first: tasks leave in the
+    order they were sent, as far as one host's clock tells; under a
+    random id a task could lie pending for any number of claims while
+    younger ones overtook it.
     """
 
     def __init__(self, directory: str, visibility_timeout: float = 1800.0):
@@ -462,7 +469,7 @@ class FileQueue(QueueBase):
 
     def send_messages(self, bodies: List[str]) -> None:
         for body in self._pack_bodies(bodies):
-            name = uuid.uuid4().hex
+            name = f"{time.time_ns():020d}-{uuid.uuid4().hex}"
             tmp = os.path.join(self.dir, f".tmp-{name}")
             with open(tmp, "w") as f:
                 f.write(body)

@@ -154,11 +154,17 @@ class PrecomputedVolume:
         if mip not in self._stores:
             import tensorstore as ts
 
+            # a written block of zeros is a block: under the driver's
+            # default one equal to the fill value is not stored, and a
+            # blank task would leave nothing for has_all_blocks (the
+            # resume rule) to find. Blocks nobody wrote still read as
+            # the fill value.
             self._stores[mip] = ts.open(
                 {
                     "driver": "neuroglancer_precomputed",
                     "kvstore": self.kvstore,
                     "scale_index": mip,
+                    "store_data_equal_to_fill_value": True,
                 }
             ).result()
         return self._stores[mip]

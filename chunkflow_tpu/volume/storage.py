@@ -1122,6 +1122,7 @@ def blockwise_save(backend: StorageBackend, lo: Sequence[int],
     with telemetry.span("storage/write",
                         mode="aligned" if aligned else "unaligned"):
         if aligned:
+            zero_blocks = 0
             for blo, bhi in _covering_blocks(lo, hi, block, goff,
                                              dlo, dhi):
                 sub = arr[tuple(
@@ -1129,17 +1130,22 @@ def blockwise_save(backend: StorageBackend, lo: Sequence[int],
                     for l, bl, bh in zip(lo, blo, bhi)
                 )]
                 futures.append(backend.write_async(blo, bhi, sub))
-                if cache is not None:
-                    block_copy = np.array(sub, copy=True)
-                    if block_copy.any():
-                        cache.put(
-                            (backend.cache_token, blo), block_copy
-                        )
-                    else:
-                        # stay consistent with the read path's
-                        # zeros-are-never-pinned rule
-                        cache.invalidate((backend.cache_token, blo))
+                if cache is None:
+                    zero_blocks += not sub.any()
+                    continue
+                block_copy = np.array(sub, copy=True)
+                if block_copy.any():
+                    cache.put((backend.cache_token, blo), block_copy)
+                else:
+                    # stay consistent with the read path's
+                    # zeros-are-never-pinned rule
+                    cache.invalidate((backend.cache_token, blo))
+                    zero_blocks += 1
             telemetry.inc("storage/aligned_writes")
+            # blocks written though all zero (a blank or masked task's):
+            # what a volume pays to keep its resume rule
+            # (PrecomputedVolume.has_all_blocks)
+            telemetry.inc("storage/zero_blocks_written", zero_blocks)
         else:
             futures.append(backend.write_async(lo, hi, arr))
             if cache is not None:

@@ -488,3 +488,28 @@ class TestMemoryQueueConcurrency:
         assert bodies == sorted(f"task-{i}" for i in range(n_tasks))
         handles = [h for h, _b in claimed]
         assert len(set(handles)) == len(handles)  # no double-claims
+
+
+def test_file_queue_hands_out_the_oldest_task_first(tmp_path):
+    """Tasks leave a file queue in the order they were sent, whatever is
+    pending at once (ISSUE 37: the benchmark's feeder offers a blank task
+    every sixth, and the worker has to meet them six apart)."""
+    from chunkflow_tpu.parallel.queues import open_queue
+
+    queue = open_queue(f"file://{tmp_path}/q")
+    bodies = [f"task-{i}" for i in range(40)]
+    queue.send_messages(bodies[:25])
+    got = []
+    for _ in range(10):
+        handle, body = queue.receive()
+        got.append(body)
+        queue.delete(handle)
+    for body in bodies[25:]:          # three at a time, as a feeder does
+        queue.send_messages([body])
+    while True:
+        claimed = queue.receive()
+        if claimed is None:
+            break
+        got.append(claimed[1])
+        queue.delete(claimed[0])
+    assert got == bodies
