@@ -49,6 +49,12 @@ def as_native_dtype(arr: np.ndarray) -> np.ndarray:
 class Chunk(np.lib.mixins.NDArrayOperatorsMixin):
     """An ndarray located in a global voxel coordinate system."""
 
+    #: the all-zero answer where it was taken beforehand, None where
+    #: nobody asked: ``Inferencer.stage`` sets it on the chunk it hands
+    #: the pipeline (asked of the host payload, before the upload). A
+    #: chunk derived from this one starts at None again.
+    blank: Optional[bool] = None
+
     def __init__(
         self,
         array,

@@ -29,6 +29,8 @@ from __future__ import annotations
 import itertools
 import sys
 import time
+import weakref
+from collections import deque
 from typing import Optional, Tuple
 
 import numpy as np
@@ -216,6 +218,10 @@ class Inferencer:
         self.precision = resolve_precision(precision)
         self._apply = wrap_apply(self.engine.apply, self.precision)
         self._device_params = None
+        # results of earlier infer_async calls that were not ready when
+        # last looked at, oldest first and held weakly (a result the
+        # caller dropped is freed): what the byte bound waits for
+        self._ahead: deque = deque()
 
     # ------------------------------------------------------------------
     def _scatter_key(self) -> tuple:
@@ -365,7 +371,6 @@ class Inferencer:
         device's own ``peak_bytes_in_use``."""
         from chunkflow_tpu.core import profiling
 
-        voxels = int(np.prod(chunk.shape[-3:]))
         grid = enumerate_patches(
             chunk.shape, self.input_patch_size, self.output_patch_size,
             self.output_patch_overlap,
@@ -376,17 +381,22 @@ class Inferencer:
             / float(np.prod(tuple(self.input_patch_size))),
         )
         profiling.trace_gauge("inference/patches_per_task", grid.num_patches)
-        # the float32 sums and the weight volume, chunk-sized (without
-        # the Pallas leg's aligned-window padding)
         profiling.trace_gauge(
-            "inference/accumulator_bytes",
-            4 * (self.num_output_channels + 1) * voxels,
-        )
-        # the chunk as it arrives; the XLA gather leg adds a float32 copy
+            "inference/accumulator_bytes", self._accumulator_bytes(chunk))
         profiling.trace_gauge(
-            "inference/chunk_bytes",
-            chunk.size * np.dtype(chunk.dtype).itemsize,
-        )
+            "inference/chunk_bytes", self._chunk_bytes(chunk))
+
+    def _accumulator_bytes(self, chunk) -> int:
+        """The float32 sums and the weight volume, chunk-sized (without
+        the Pallas leg's aligned-window padding)."""
+        voxels = int(np.prod(chunk.shape[-3:]))
+        return 4 * (self.num_output_channels + 1) * voxels
+
+    @staticmethod
+    def _chunk_bytes(chunk) -> int:
+        """The chunk as it arrives; the XLA gather leg adds a float32
+        copy."""
+        return int(chunk.size) * np.dtype(chunk.dtype).itemsize
 
     def _build_program(self):
         import jax
@@ -635,10 +645,21 @@ class Inferencer:
         program donates and invalidates it). ``jax.device_put`` is async,
         so staging chunk k+1 overlaps chunk k's compute; narrow int
         dtypes ride the wire narrow (float conversion happens on device
-        at infer time)."""
+        at infer time).
+
+        The blank answer is taken here, where the payload is on the host
+        (a pass over memory; on the device it would be a reduction queued
+        behind every program in flight, and the dispatch would wait for
+        it), and rides with the staged chunk (``Chunk.blank``). A blank
+        chunk is not uploaded: the chunk returned shares the host
+        payload. A chunk that arrives device-resident is returned as it
+        is, and ``_infer`` asks the device."""
         if chunk.is_on_device:
             return chunk
-        return chunk.device()
+        blank = self._blank_answer(chunk)
+        staged = type(chunk)(chunk) if blank else chunk.device()
+        staged.blank = blank
+        return staged
 
     def infer_async(self, chunk: Chunk, crop=None, consume: bool = False
                     ) -> Chunk:
@@ -650,14 +671,78 @@ class Inferencer:
         ride D2H. ``consume`` transfers ownership of a device-resident
         input buffer to the program (donation: the caller's array is
         dead after the call) — only pass it for buffers you staged
-        yourself and will not touch again."""
+        yourself and will not touch again.
+
+        Nothing here waits for the device while one more dispatch fits
+        its memory: callers may run as many tasks ahead as their own
+        count bounds allow (:meth:`_make_room` is the byte bound)."""
+        if not chunk.blank:
+            self._make_room(chunk)
         out = self._infer(chunk, block=False, consume=consume)
         if crop is not None:
             out = out.crop_margin(crop)
         arr = out.array
         if hasattr(arr, "copy_to_host_async"):
             arr.copy_to_host_async()
+            self._ahead.append(weakref.ref(arr))
         return out
+
+    # ------------------------------------------------------------------
+    def _dispatch_bytes(self, chunk) -> int:
+        """What one dispatch allocates on the device, by the gauges the
+        program's trace sets: the accumulators, the chunk and the
+        result."""
+        result = (self._result_dtype().itemsize * self.num_output_channels
+                  * int(np.prod(chunk.shape[-3:])))
+        return (self._accumulator_bytes(chunk) + self._chunk_bytes(chunk)
+                + result)
+
+    @staticmethod
+    def _device_room() -> Optional[int]:
+        """Bytes neither in use nor reserved for the loaded programs'
+        scratch on the fullest local device, or None where the backend
+        states no limit (the CPU)."""
+        import jax
+
+        rooms = []
+        for device in jax.local_devices():
+            stats = device.memory_stats() or {}
+            limit = int(stats.get("bytes_limit", 0) or 0)
+            if limit > 0:
+                rooms.append(
+                    limit - int(stats.get("bytes_in_use", 0) or 0)
+                    - int(stats.get("bytes_reserved", 0) or 0))
+        return min(rooms) if rooms else None
+
+    def _make_room(self, chunk: Chunk) -> None:
+        """The byte bound on dispatching ahead. While results of earlier
+        dispatches are not ready their programs' buffers stand on the
+        device; one more dispatch goes beside them only if it fits what
+        the device has left, and otherwise waits for the oldest of them
+        (interpreter lock released). Callers hold this inside their
+        ``pipeline/dispatch`` span, so the wait is named there. A task
+        that fills the device (the production deployment) so stays one
+        program ahead, a small one runs as far ahead as the caller's
+        count bounds let it."""
+        ahead = deque()
+        for ref in self._ahead:
+            result = ref()
+            if result is not None and not result.is_ready():
+                ahead.append(ref)
+        self._ahead = ahead
+        # how deep the device's queue is as this task joins it
+        telemetry.gauge("pipeline/ahead_outputs", len(ahead))
+        if not ahead:
+            return
+        need = self._dispatch_bytes(chunk)
+        while ahead:
+            room = self._device_room()
+            if room is None or need <= room:
+                return
+            oldest = ahead.popleft()()
+            if oldest is not None:
+                telemetry.inc("pipeline/ahead_waits")
+                oldest.block_until_ready()
 
     @property
     def _out_layer(self):
@@ -666,6 +751,15 @@ class Inferencer:
             if self.num_output_channels == 3
             else LayerType.PROBABILITY_MAP
         )
+
+    def _result_dtype(self) -> np.dtype:
+        import ml_dtypes
+
+        return np.dtype({
+            "float32": np.float32,
+            "bfloat16": ml_dtypes.bfloat16,
+            "uint8": np.uint8,
+        }[self.output_dtype])
 
     def _blank_output(self, chunk: Chunk) -> Chunk:
         """The dry-run / all-zero-input result: a zero chunk with the
@@ -677,18 +771,11 @@ class Inferencer:
         nchan = self.num_output_channels
         if self.mask_myelin_threshold is not None:
             nchan -= 1
-        import ml_dtypes
-
-        blank_dtype = {
-            "float32": np.float32,
-            "bfloat16": ml_dtypes.bfloat16,
-            "uint8": np.uint8,
-        }[self.output_dtype]
         out = Chunk.from_bbox(
             chunk.bbox,
             # match the real path's result dtype so a volume mixing
             # blank and real chunks stays dtype-consistent
-            dtype=blank_dtype,
+            dtype=self._result_dtype(),
             nchannels=nchan,
             voxel_size=chunk.voxel_size,
         )
@@ -721,20 +808,32 @@ class Inferencer:
             out = out.crop_margin(self.crop_margin)
         return out
 
+    def _blank_answer(self, chunk: Chunk) -> bool:
+        """Whether the chunk takes the blank path, once a task: asked of
+        the host payload where the chunk is on the host (``stage``,
+        ``__call__``). Only a chunk that arrives device-resident (an
+        upstream operator left it there) is asked on the device: a
+        reduction queued behind the programs in flight, whose answer the
+        host waits for."""
+        with telemetry.span("inference/blank_check") as check:
+            asks_device = chunk.is_on_device and not self.dry_run
+            if asks_device:
+                telemetry.inc("inference/device_blank_checks")
+            blank = self.dry_run or chunk.all_zero()
+            # which tasks took the blank path, for a reader of the stream
+            check.annotate(blank=int(blank),
+                           where="device" if asks_device else "host")
+        telemetry.inc("inference/tasks")
+        return blank
+
     @contract(chunk=Spec(ndim=(3, 4)))
     def _infer(self, chunk: Chunk, block: bool, consume: bool = False) -> Chunk:
         import jax
         import jax.numpy as jnp
 
-        # on a staged (device-resident) chunk the check is a reduction
-        # queued behind the programs in flight, and its answer is waited
-        # for: in a device-bound pipeline the host spends most of a task
-        # here, and its own span says so
-        with telemetry.span("inference/blank_check") as check:
-            blank = self.dry_run or chunk.all_zero()
-            # which tasks took the blank path, for a reader of the stream
-            check.annotate(blank=int(blank))
-        telemetry.inc("inference/tasks")
+        blank = chunk.blank
+        if blank is None:
+            blank = self._blank_answer(chunk)
         if blank:
             out = self._blank_output(chunk)
             # what a blank chunk costs downstream: its zeros are cropped,

@@ -234,7 +234,8 @@ def test_worker_stream_rebuilds_one_span_tree_per_task(world):
                   for e in events if e["parent_id"] is not None}
         assert nested["storage/read"] == "op/load-precomputed"
         assert nested["storage/write"] == "op/save-precomputed"
-        assert nested["inference/blank_check"] == "pipeline/dispatch"
+        # the answer is taken where the chunk is on the host: at staging
+        assert nested["inference/blank_check"] == "pipeline/stage"
         assert nested["queue/ack"] == "op/delete-task-in-queue"
     # what carries no task: the wait that ended with the stream's end
     assert {e["name"] for e in spans if not e.get("trace_id")} <= {
