@@ -16,11 +16,15 @@ no ``/profile`` route — nothing):
    best-effort *without compiling twice* (``Lowered.cost_analysis``
    runs on the unoptimized HLO). Results land in ``program/*``
    counters, one ``compile``-kind telemetry event per program, and a
-   per-run ``programs.json`` catalog written at flush time. With a
-   sink configured the entry also carries ``op_scopes``: which of the
-   program's compiled ops lie under which :data:`DEVICE_SCOPES` name,
-   so a device trace (whose events keep only the op's name) can be
-   split by the program's own parts.
+   per-run ``programs.json`` catalog written at flush time
+   (:func:`catalog` lists an entry's keys, with and without a sink).
+   With a sink configured the entry also says where in the program each
+   compiled op lies, so that a device trace (whose events keep only the
+   op's name) can be split by the program's own names: ``op_scopes``
+   (which of the six :data:`DEVICE_SCOPES` steps), ``op_parts`` (below a
+   scope, which flax module or model scope: ``enc0``, ``pool1``; a
+   fusion by its widest convolution, not by its root) and
+   ``op_convolutions`` (the convolutions an op holds).
 
 2. **Roofline accounting.** At catalog time each program's cost is
    scored against a small peak-FLOPs/HBM-bandwidth table keyed on
@@ -61,17 +65,19 @@ See docs/observability.md "Device program view".
 from __future__ import annotations
 
 import json
+import math
 import os
 import re
 import threading
 import time
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 from chunkflow_tpu.core import telemetry
 
 __all__ = [
     "instrument_program", "stamp_cost", "catalog", "write_catalog",
-    "device_peaks", "DEVICE_SCOPES", "op_scopes", "trace_gauge", "note_h2d",
+    "device_peaks", "DEVICE_SCOPES", "OP_MAPS", "op_scopes", "op_parts",
+    "trace_gauge", "note_h2d",
     "h2d_by_family",
     "note_hbm_intermediate", "hbm_intermediate_by_family",
     "note_collective", "collective_by_family",
@@ -133,15 +139,20 @@ def device_peaks(device_kind: str) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# device scopes: the program's own parts, readable from a device trace
+# device scopes and parts: the program's own names, readable from a device trace
 # ---------------------------------------------------------------------------
-#: The ``jax.named_scope`` names every patch program traces its parts
-#: under (ops/blend.py, ops/pallas_gather.py, ops/fold_blend.py,
-#: parallel/engine.py, serve/packer.py): patch gather, model forward,
-#: bump-weighted accumulation, weight normalization, and the mesh
-#: engine's cross-chip exchanges; and, in the program ops/mask.py builds
-#: for a device-resident chunk, ``mask``. Scopes are metadata: the
-#: compiled code is the same with and without them.
+#: The ``jax.named_scope`` names every patch program traces its steps
+#: under: patch gather, model forward, bump-weighted accumulation, weight
+#: normalization, the mesh engine's cross-chip exchanges and, in the
+#: program ops/mask.py builds for a device-resident chunk, ``mask``
+#: (``grep -rn named_scope chunkflow_tpu`` lists the files). Below a scope
+#: the path goes on with the names of whoever emitted the op: a flax
+#: module's (``forward/RSUNet/enc0/conv2/conv_general_dilated``) or a
+#: scope the model opens around what its own ``__call__`` emits
+#: (models/rsunet.py: ``in``, ``pool{i}``, ``crop{i}``, ``skip{i}``,
+#: ``post``). The first name below the scope's root module is the op's
+#: *part* (:func:`op_parts`). Scopes and parts are metadata: the compiled
+#: code is the same with and without them.
 DEVICE_SCOPES = ("gather", "forward", "accumulate", "normalize",
                  "collective", "mask")
 
@@ -152,36 +163,57 @@ _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
 _HLO_CALLED = re.compile(
     r"(?:body|condition|to_apply|calls|true_computation|false_computation)"
     r"=(%?[\w.\-]+)|branch_computations=\{([^}]*)\}")
-# a scope on an op's path in a lowered module's locations:
-# loc("jit(program)/while/body/forward/conv_general_dilated")
-_LOWERED_SCOPE = re.compile(
-    r'loc\("[^"]*/(?:%s)/' % "|".join(DEVICE_SCOPES))
+_HLO_OPERAND = re.compile(r"%([\w.\-]+)")
+_HLO_WINDOW = re.compile(r"window=\{size=([0-9x]+)")
+# an op's path in a lowered module's locations, which name it as
+# loc("jit(program)/while/body/forward/RSUNet/enc0/conv1/conv_general_dilated"(#loc7))
+# and a source file as loc("/a/path/forward/model.py":12:3): not a path
+_LOWERED_PATH = re.compile(r'loc\("([^"]+)"(?=[()])')
 # never a device-trace event of their own
 _HLO_FREE = frozenset(("parameter", "get-tuple-element", "tuple", "bitcast",
                        "constant"))
 
 
-def _scope_of(op_name: str) -> Optional[str]:
-    """The outermost :data:`DEVICE_SCOPES` name on a ``/``-separated
-    ``op_name`` path (``jit(program)/while/body/forward/RSUNet/conv``).
-    The last component is the primitive, not a scope: a ``lax.gather``
-    outside every scope ends in ``/gather`` and is under none."""
-    for part in op_name.split("/")[:-1]:
-        if part in DEVICE_SCOPES:
-            return part
-    return None
+def _split(op_name: str) -> Tuple[Optional[str], list]:
+    """``(scope, names below the scope's root module)`` of a
+    ``/``-separated ``op_name`` path, the primitive at its end left off:
+    ``jit(program)/while/body/forward/RSUNet/enc0/conv2/
+    conv_general_dilated`` -> ``("forward", ["enc0", "conv2"])``. The
+    scope is the outermost :data:`DEVICE_SCOPES` name on the path; the
+    last component is the primitive and never a scope (a ``lax.gather``
+    outside every scope ends in ``/gather`` and is under none). With no
+    scope on it: ``(None, the whole path but for the primitive)``."""
+    path = op_name.split("/")[:-1]
+    for i, name in enumerate(path):
+        if name in DEVICE_SCOPES:
+            return name, path[i + 2:]
+    return None, path
 
 
-def op_scopes(hlo_text: str) -> dict:
-    """``{scope: [op names]}`` of one compiled module's text
-    (``Compiled.as_text()``), the ops under none of
-    :data:`DEVICE_SCOPES` listed under ``""``. An op's scope is the
-    outermost scope name in its own ``op_name`` metadata; an op that has
-    none takes the scope of the instruction that calls its computation
-    (XLA expands a ``scatter-add`` into a ``while`` whose body ops carry
-    no metadata, the ``while`` does). Ops inside fusions and ops that
-    never run as an event of their own are left out."""
-    computations: dict = {}   # name -> [(op, opcode, scope, called)]
+def _place(op_name: str) -> Tuple[Optional[str], str]:
+    """``(scope, part)`` of an ``op_name`` path, the part being the first
+    name below the scope's root module: ``("forward", "enc0")`` for the
+    path above, ``("forward", "")`` for ``forward/RSUNet/add``, and
+    ``(None, "")`` under no scope."""
+    scope, below = _split(op_name)
+    return scope, (below[0] if scope and below else "")
+
+
+class _HloOp(NamedTuple):
+    """One instruction of a compiled module's text."""
+    name: str
+    opcode: str
+    op_name: Optional[str]   # the metadata's, None where it has none
+    called: tuple            # computations it calls; a fusion's are in fused
+    fused: tuple             # the computations a fusion's ``calls=`` names
+    operands: tuple          # instruction names, where the text has them
+    window: Optional[str]    # a convolution's window size, "3x3x3"
+
+
+def _parse_hlo(hlo_text: str) -> Tuple[dict, Optional[str]]:
+    """``({computation: [_HloOp, ...]}, entry computation)`` of one
+    compiled module's text (``Compiled.as_text()``)."""
+    computations: dict = {}
     current = None
     entry = None
     for line in hlo_text.splitlines():
@@ -196,17 +228,35 @@ def op_scopes(hlo_text: str) -> dict:
             continue
         name, rest = match.group(1), match.group(2)
         opcode = _HLO_OPCODE.search(" " + rest)
-        opcode = opcode.group(1) if opcode else ""
         op_name = _HLO_OP_NAME.search(rest)
         called = []
-        if opcode != "fusion":
-            for one, several in _HLO_CALLED.findall(rest):
-                called += [c.strip().lstrip("%")
-                           for c in (one or several).split(",")]
-        current.append((name, opcode,
-                        _scope_of(op_name.group(1)) if op_name else None,
-                        called))
-    out: dict = {}
+        for one, several in _HLO_CALLED.findall(rest):
+            called += [c.strip().lstrip("%")
+                       for c in (one or several).split(",")]
+        # the operand list ends at the parenthesis that closes the opcode's
+        depth, stop = 0, len(rest)
+        for at in range(opcode.end() - 2 if opcode else stop, len(rest)):
+            depth += (rest[at] == "(") - (rest[at] == ")")
+            if depth == 0:
+                stop = at
+                break
+        opcode = opcode.group(1) if opcode else ""
+        window = _HLO_WINDOW.search(rest) if opcode == "convolution" else None
+        fusion = opcode == "fusion"
+        current.append(_HloOp(
+            name, opcode, op_name.group(1) if op_name else None,
+            () if fusion else tuple(called), tuple(called) if fusion else (),
+            tuple(_HLO_OPERAND.findall(rest[:stop])),
+            window.group(1) if window else None))
+    return computations, entry
+
+
+def _walk(computations: dict, entry: Optional[str], own):
+    """Every op that runs as an event of its own, with what it inherits:
+    yields ``(computation, op, value)`` from the entry computation down
+    through ``while``, ``call`` and ``conditional``, ``value`` being
+    ``own(op)`` or, where that is None, the value of the instruction that
+    calls the op's computation."""
     seen = set()
     stack = [(entry, None)]
     while stack:
@@ -214,12 +264,149 @@ def op_scopes(hlo_text: str) -> dict:
         if computation in seen or computation not in computations:
             continue
         seen.add(computation)
-        for name, opcode, scope, called in computations[computation]:
-            scope = scope or inherited
-            if opcode not in _HLO_FREE:
-                out.setdefault(scope or "", []).append(name)
-            stack += [(c, scope) for c in called]
+        for op in computations[computation]:
+            value = own(op)
+            value = inherited if value is None else value
+            yield computation, op, value
+            stack += [(c, value) for c in op.called]
+
+
+def op_scopes(hlo_text: str) -> dict:
+    """``{scope: [op names]}`` of one compiled module's text
+    (``Compiled.as_text()``), the ops under none of
+    :data:`DEVICE_SCOPES` listed under ``""``. An op's scope is the
+    outermost scope name in its own ``op_name`` metadata; an op that has
+    none takes the scope of the instruction that calls its computation
+    (XLA expands a ``scatter-add`` into a ``while`` whose body ops carry
+    no metadata, the ``while`` does). Ops inside fusions and ops that
+    never run as an event of their own are left out."""
+    return _op_scopes(*_parse_hlo(hlo_text))
+
+
+def _op_scopes(computations: dict, entry: Optional[str]) -> dict:
+    out: dict = {}
+    for _, op, scope in _walk(
+            computations, entry,
+            lambda op: _place(op.op_name)[0] if op.op_name else None):
+        if op.opcode not in _HLO_FREE:
+            out.setdefault(scope or "", []).append(op.name)
     return out
+
+
+def _taps(window: str) -> int:
+    return math.prod(map(int, window.split("x")))
+
+
+def _convolutions(computations: dict, op: _HloOp) -> list:
+    """``[(op_name, window)]`` of the convolutions ``op`` holds: its own,
+    or those of a fusion's computations, nested fusions included; the one
+    with the most taps first (of equals, the first in program order)."""
+    found = []
+    pending, seen = [op], set()
+    for one in pending:   # grows while it is walked
+        if one.opcode == "convolution" and one.window:
+            found.append((one.op_name or "", one.window))
+        for computation in one.fused:
+            if computation not in seen:
+                seen.add(computation)
+                pending += computations.get(computation, [])
+    return sorted(found, key=lambda conv: -_taps(conv[1]))
+
+
+def op_parts(hlo_text: str) -> Tuple[dict, dict]:
+    """``(op_parts, op_convolutions)`` of one compiled module's text.
+
+    ``op_parts`` is ``{scope: {part: [op names]}}`` over the ops
+    :func:`op_scopes` lists: the scope as there, the part the first name
+    on the op's path below the scope's root module (:func:`_place`), ``""``
+    where there is none. XLA names, shapes and annotates a fusion after
+    its *root*, which is the consumer fused in last and not where the
+    time goes (``fusion.1066 bf16[20,256,32,9,12]`` is ``dec0/conv3``
+    with the 1x1x1 head as its root: PERF.md, PR 38), so, in this order:
+
+    (a) a fusion that holds convolutions (its computations searched,
+        nested fusions too) takes the path of the one with the largest
+        ``window``;
+    (b) any other op takes its own ``op_name``'s;
+    (c) an op with no metadata takes what the instruction that calls its
+        computation has, as in :func:`op_scopes`;
+    (d) an op that is still under no scope and has no metadata (XLA's own
+        ``copy``, a weight's ``copy-start``) takes the part of the ops
+        that read it, where they all have the same. Its scope stays
+        ``""``: every scope holds the ops :func:`op_scopes` lists there.
+
+    ``op_convolutions`` is ``{op name: [[module path, window], ...]}``
+    for every listed op that holds a convolution, the one that names the
+    part first: ``{"fusion.1066": [["dec0/conv3", "3x3x3"], ["out",
+    "1x1x1"]]}``. A name in a trace or in the ledger's ``device_ops`` is
+    looked up here."""
+    return _op_parts(*_parse_hlo(hlo_text))
+
+
+def _op_parts(computations: dict, entry: Optional[str]) -> Tuple[dict, dict]:
+    convolutions: dict = {}
+
+    def own(op):
+        held = _convolutions(computations, op)
+        if held:
+            convolutions[op.name] = held
+        path = held[0][0] if held and held[0][0] else op.op_name
+        place = _place(path) if path else (None, "")
+        return place if place[0] else None
+
+    placed, walked = {}, set()   # op name -> (scope or None, part)
+    for computation, op, place in _walk(computations, entry, own):
+        walked.add(computation)
+        if op.opcode not in _HLO_FREE:
+            placed[op.name] = place or (None, "")
+    # (d): from the readers back, so that copy-start -> copy-done -> a
+    # fusion resolves in one pass (a computation lists an op before its
+    # readers); an op that is no event hands its readers' parts through
+    for computation in walked:
+        readers: dict = {}   # op name -> the parts of the ops that read it
+        for op in reversed(computations[computation]):
+            parts = readers.get(op.name, set())
+            if op.name in placed:
+                scope, part = placed[op.name]
+                if scope is None and op.op_name is None \
+                        and len(parts) == 1 and "" not in parts:
+                    part = next(iter(parts))
+                    placed[op.name] = (None, part)
+                parts = {part}
+            for operand in op.operands:
+                readers.setdefault(operand, set()).update(parts)
+    by_scope: dict = {}
+    for name, (scope, part) in placed.items():
+        by_scope.setdefault(scope or "", {}).setdefault(part, []).append(name)
+    return by_scope, {
+        name: [["/".join(_split(op_name)[1]), window]
+               for op_name, window in held]
+        for name, held in convolutions.items() if name in placed}
+
+
+# primitives that only move data: XLA folds such an op into a neighbour and
+# the neighbour's metadata stays, so a name that only they carry in the
+# lowered module (``crop1``: one slice) may be missing from a fresh executable
+_MOVES_DATA = frozenset((
+    "slice", "dynamic_slice", "reshape", "squeeze", "expand_dims",
+    "transpose", "broadcast_in_dim", "convert_element_type", "copy",
+    "concatenate", "pad"))
+
+
+def _names_of(paths, computing: bool = False) -> set:
+    """The scopes and ``scope/part`` pairs that ``op_name`` paths name;
+    with ``computing``, those of paths that end in a primitive which
+    computes something (not one of :data:`_MOVES_DATA`)."""
+    names = set()
+    for path in paths:
+        if computing and path.rsplit("/", 1)[-1] in _MOVES_DATA:
+            continue
+        scope, part = _place(path)
+        if scope:
+            names.add(scope)
+            if part:
+                names.add(f"{scope}/{part}")
+    return names
 
 
 # ---------------------------------------------------------------------------
@@ -232,7 +419,7 @@ class _ProgramRecord:
     __slots__ = (
         "family", "key", "label", "build_s", "compile_s", "flops",
         "bytes_accessed", "vmem_bytes", "hbm_intermediate", "optimal_s",
-        "calls", "dispatch_s", "platform", "device_kind", "op_scopes",
+        "calls", "dispatch_s", "platform", "device_kind", "op_maps",
         "traced", "lock",
     )
 
@@ -251,7 +438,7 @@ class _ProgramRecord:
         self.dispatch_s = 0.0  # post-compile dispatch wall, cumulative
         self.platform = ""
         self.device_kind = ""
-        self.op_scopes: Optional[dict] = None
+        self.op_maps: Optional[dict] = None  # _op_maps(), runs with a sink
         self.traced: dict = {}  # trace_gauge() values of the program's trace
         self.lock = threading.Lock()
 
@@ -338,29 +525,51 @@ def _compile_past_the_cache(lowered):
         compilation_cache.reset_cache()
 
 
-def _compiled_op_scopes(lowered, lower_again) -> Optional[dict]:
-    """:func:`op_scopes` of the compiled module. ``Lowered.compile()``
+#: The keys of a ``programs.json`` entry that :func:`_op_maps` fills
+OP_MAPS = ("op_scopes", "op_parts", "op_convolutions")
+
+
+def _op_maps(hlo_text: str) -> dict:
+    """The three maps a ``programs.json`` entry carries when the run has
+    a sink, from one pass over the compiled module's text."""
+    parsed = _parse_hlo(hlo_text)
+    parts, convolutions = _op_parts(*parsed)
+    return {"op_scopes": _op_scopes(*parsed), "op_parts": parts,
+            "op_convolutions": convolutions}
+
+
+def _compiled_op_maps(lowered, lower_again) -> Optional[dict]:
+    """:func:`_op_maps` of the compiled module. ``Lowered.compile()``
     after the program's first call finds the executable in the
     persistent compile cache where that is on; otherwise it compiles a
     second time, which is why only runs with a sink pay for it.
 
     JAX leaves metadata out of the cache key, so the cache may hand back
     an executable that was compiled from the same program before it had
-    scopes (another checkout's, an older release's): its module names no
-    scope although the lowered one does. The op names are the same, the
-    optimized code being the same but for metadata, so the map is then
-    read from one compile past the cache, of the program lowered again
-    (``lower_again()``), once per process as long as that entry lives."""
+    today's names (another checkout's, an older release's): its module
+    has ``forward`` and lacks ``pool0``, or names no scope at all,
+    although the lowered one does. So what the lowered module's locations
+    name (scopes, and parts below them; of ops that compute something,
+    :data:`_MOVES_DATA`) is held against what the executable's
+    ``op_name``s name, fused instructions included, and if the
+    executable lacks one the maps are read from one compile past the
+    cache, of the program lowered again (``lower_again()``): the op names
+    are the same, the optimized code being the same but for metadata.
+    Once per program and process as long as that entry lives
+    (``program/stale_cache_entries``)."""
+    t0 = time.perf_counter()
     try:
-        scopes = op_scopes(lowered.compile().as_text())
-        if not set(scopes) & set(DEVICE_SCOPES) and _LOWERED_SCOPE.search(
-                lowered.as_text(debug_info=True)):
+        text = lowered.compile().as_text()
+        wanted = _names_of(_LOWERED_PATH.findall(
+            lowered.as_text(debug_info=True)), computing=True)
+        if wanted - _names_of(_HLO_OP_NAME.findall(text)):
             telemetry.inc("program/stale_cache_entries")
-            scopes = op_scopes(
-                _compile_past_the_cache(lower_again()).as_text())
-        return scopes
+            text = _compile_past_the_cache(lower_again()).as_text()
+        return _op_maps(text)
     except Exception:
         return None
+    finally:
+        telemetry.inc("program/op_map_seconds", time.perf_counter() - t0)
 
 
 class _InstrumentedProgram:
@@ -414,9 +623,9 @@ class _InstrumentedProgram:
         t0 = time.perf_counter()
         out = self._fn(*args, **kwargs)
         dt = time.perf_counter() - t0
-        scopes = None
+        maps = None
         if want_scopes and lowered is not None:
-            scopes = _compiled_op_scopes(
+            maps = _compiled_op_maps(
                 lowered, lambda: self._fn.lower(*specs[0], **specs[1]))
         first = False
         with rec.lock:
@@ -440,7 +649,7 @@ class _InstrumentedProgram:
                 rec.optimal_s = (
                     float(optimal) if optimal is not None else None
                 )
-                rec.op_scopes = scopes
+                rec.op_maps = maps
                 rec.traced = traced
             else:  # raced: the other thread's call was the compile
                 rec.calls += 1
@@ -650,7 +859,22 @@ def catalog() -> list:
     post-compile dispatch stats, and — against :func:`device_peaks` —
     ``roofline_s`` (the cost-model floor per call) and
     ``roofline_util`` (floor / mean dispatch wall; an *upper bound*
-    under async dispatch, see module docstring)."""
+    under async dispatch, see module docstring).
+
+    The keys of an entry, which is also one of ``programs.json``'s
+    ``programs``. Always: ``family``, ``key``, ``label``, ``build_s``,
+    ``compile_s``, ``flops``, ``bytes_accessed``, ``vmem_bytes``,
+    ``optimal_s``, ``calls``, ``dispatch_total_s``, ``platform``,
+    ``device_kind``, ``peak_flops_per_s``, ``peak_bytes_per_s``,
+    ``peak_source``, ``roofline_s``, ``exec_mean_s``, ``roofline_util``,
+    ``lost_s``, ``achieved_flops_per_s``, ``h2d_bytes``,
+    ``hbm_intermediate_bytes``, ``collective_bytes``; ``x_fold`` and the
+    :data:`GEOMETRY_GAUGES` (None where the program's trace set none) and
+    whatever else its trace said under ``forward/<name>``. With a sink
+    configured when the program first ran, the three :data:`OP_MAPS`
+    (``op_scopes``, ``op_parts``, ``op_convolutions``: :func:`op_scopes`,
+    :func:`op_parts`); without one they are None, and nothing was
+    compiled or parsed to fill them."""
     with _LEDGER_LOCK:
         records = list(_LEDGER.values())
     h2d = h2d_by_family()
@@ -676,7 +900,7 @@ def catalog() -> list:
                 "dispatch_total_s": round(rec.dispatch_s, 4),
                 "platform": rec.platform,
                 "device_kind": rec.device_kind,
-                "op_scopes": rec.op_scopes,
+                **{name: (rec.op_maps or {}).get(name) for name in OP_MAPS},
                 "x_fold": rec.traced.get("forward/x_fold"),
                 # what else the model's trace said of its lowering
                 # (models/rsunet.py: dec{i}_voxel_share, flops_share)
@@ -758,10 +982,10 @@ def write_catalog(metrics_dir: Optional[str] = None) -> Optional[str]:
         metrics_dir = os.path.dirname(path) if path else None
     if metrics_dir is None:
         return None
-    # the JSONL stream gets the ledger without the per-op scope lists,
-    # which only a trace reducer needs and which programs.json keeps
+    # the JSONL stream gets the ledger without the per-op maps, which
+    # only a trace reducer needs and which programs.json keeps
     telemetry.event("programs", "program/catalog", programs=[
-        {k: v for k, v in e.items() if k != "op_scopes"} for e in entries])
+        {k: v for k, v in e.items() if k not in OP_MAPS} for e in entries])
     payload = {
         "worker": telemetry.worker_id(),
         "t": time.time(),

@@ -56,6 +56,7 @@ import math
 from typing import Sequence, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 import numpy as np
 from flax.linen.dtypes import promote_dtype
@@ -403,8 +404,12 @@ class RSUNet(nn.Module):
             return RSBlock(self.width[i], dtype=dt, fold=folds[i],
                            name=name)
 
+        # what this method emits itself goes under a name of its own, as a
+        # flax module's ops go under the module's: the device trace is split
+        # by these names (core/profiling.py ``op_parts``). Metadata only.
         orig_dtype = x.dtype
-        x = fold_x(x.astype(dt), fold)
+        with jax.named_scope("in"):
+            x = fold_x(x.astype(dt), fold)
         x = XFoldConv(self.width[0], EMBED_KERNEL, dtype=dt, fold=fold,
                       name="embed")(x)
         skips = []
@@ -412,18 +417,20 @@ class RSUNet(nn.Module):
             x = block(i, f"enc{i}")(x)
             skips.append(x)
             factor = self.down_factors[i]
-            if folds[i] % factor[2]:  # positions of one window, two blocks
-                x = nn.max_pool(unfold_x(x, folds[i]), window_shape=factor,
-                                strides=factor)
-            else:  # comes out folded by folds[i] // fx: level i+1's, or more
-                x = unfold_x(max_pool_folded(x, factor, folds[i]),
-                             folds[i] // factor[2] // folds[i + 1])
+            with jax.named_scope(f"pool{i}"):
+                if folds[i] % factor[2]:  # one window's positions, two blocks
+                    x = nn.max_pool(unfold_x(x, folds[i]),
+                                    window_shape=factor, strides=factor)
+                else:  # folded by folds[i] // fx: level i+1's fold, or more
+                    x = unfold_x(max_pool_folded(x, factor, folds[i]),
+                                 folds[i] // factor[2] // folds[i + 1])
         x = block(depth - 1, "bridge")(x)
         for i in reversed(range(depth - 1)):
             factor = self.down_factors[i]
             box, _ = cone[i]
             held, want = cone[i + 1]
-            x = _crop(x, want, held, folds[i + 1])
+            with jax.named_scope(f"crop{i + 1}"):  # of level i+1's result
+                x = _crop(x, want, held, folds[i + 1])
             if folds[i] % factor[2]:
                 x = fold_x(nn.ConvTranspose(
                     self.width[i], kernel_size=factor, strides=factor,
@@ -432,18 +439,21 @@ class RSUNet(nn.Module):
                 x = XFoldUp(self.width[i], factor=factor, dtype=dt,
                             fold=folds[i], in_fold=folds[i + 1],
                             name=f"up{i}")(x)
-            x = x + _crop(skips[i], box, _whole(shapes[i]), folds[i])
+            with jax.named_scope(f"skip{i}"):
+                x = x + _crop(skips[i], box, _whole(shapes[i]), folds[i])
             x = block(i, f"dec{i}")(x)
         held, want = cone[0]
-        x = _crop(x, want, held, fold)
+        with jax.named_scope("crop0"):
+            x = _crop(x, want, held, fold)
         x = XFoldConv(self.out_channels, (1, 1, 1), dtype=dt, fold=fold,
                       name="out")(x)
         # the activation in the output's dtype: what the chip computed all
         # along while head, sigmoid and cast were one fusion (XLA keeps
         # excess precision inside one), now that a copy lies between them
-        x = _crop(unfold_x(x, fold), region, want).astype(orig_dtype)
-        if self.final_activation == "sigmoid":
-            x = nn.sigmoid(x)
+        with jax.named_scope("post"):
+            x = _crop(unfold_x(x, fold), region, want).astype(orig_dtype)
+            if self.final_activation == "sigmoid":
+                x = nn.sigmoid(x)
         return x
 
     def _trace_cone_gauges(self, shapes, cone) -> None:

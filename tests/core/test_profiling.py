@@ -507,6 +507,116 @@ def test_op_scopes_outermost_scope_and_inheritance_through_calls():
     assert set(scopes) <= set(profiling.DEVICE_SCOPES) | {""}
 
 
+# What the TPU compiler makes of the RSUNet's last block (PERF.md, PR 38): an
+# output fusion named, shaped and annotated after its root, the 1x1x1 head,
+# with the 27-tap convolution that takes the time inside a nested fusion.
+_PARTS_HLO = """\
+HloModule jit_program, is_scheduled=true
+
+%fused_computation.7 (p0: bf16[4,8,112], p1: bf16[3,3,3,112,112]) -> bf16[4,8,112] {
+  %p0 = bf16[4,8,112]{2,1,0} parameter(0)
+  %p1 = bf16[3,3,3,112,112]{4,3,2,1,0} parameter(1)
+  %convolution.5 = bf16[4,8,112]{2,1,0} convolution(%p0, %p1), window={size=3x3x3 pad=1_1x1_1x0_0}, dim_labels=01b2f_012io->01b2f, metadata={op_name="jit(program)/forward/RSUNet/dec0/conv3/conv_general_dilated"}
+  ROOT %maximum.5 = bf16[4,8,112]{2,1,0} maximum(%convolution.5, %p0), metadata={op_name="jit(program)/forward/RSUNet/dec0/jit(relu)/max"}
+}
+
+%fused_computation.8 (q0: bf16[4,8,112], q1: bf16[3,3,3,112,112], q2: bf16[1,1,1,112,12]) -> bf16[4,8,12] {
+  %q0 = bf16[4,8,112]{2,1,0} parameter(0)
+  %q1 = bf16[3,3,3,112,112]{4,3,2,1,0} parameter(1)
+  %q2 = bf16[1,1,1,112,12]{4,3,2,1,0} parameter(2)
+  %fusion.7 = bf16[4,8,112]{2,1,0} fusion(%q0, %q1), kind=kOutput, calls=%fused_computation.7, metadata={op_name="jit(program)/forward/RSUNet/dec0/jit(relu)/max"}
+  ROOT %convolution.6 = bf16[4,8,12]{2,1,0} convolution(%fusion.7, %q2), window={size=1x1x1}, dim_labels=01b2f_012io->01b2f, metadata={op_name="jit(program)/forward/RSUNet/out/conv_general_dilated"}
+}
+
+%fused_computation.9 (r0: bf16[4,8,112]) -> bf16[4,8,112] {
+  %r0 = bf16[4,8,112]{2,1,0} parameter(0)
+  ROOT %add.9 = bf16[4,8,112]{2,1,0} add(%r0, %r0), metadata={op_name="jit(program)/forward/RSUNet/skip0/add"}
+}
+
+%body (arg: (s32[], bf16[4,8,12])) -> (s32[], bf16[4,8,12]) {
+  %arg = (s32[], bf16[4,8,12]{2,1,0}) parameter(0)
+  %get-tuple-element.2 = bf16[4,8,12]{2,1,0} get-tuple-element(%arg), index=1
+  %dynamic-update-slice.2 = bf16[4,8,12]{2,1,0} dynamic-update-slice(%get-tuple-element.2, %get-tuple-element.2, %c)
+  ROOT %tuple.2 = (s32[], bf16[4,8,12]{2,1,0}) tuple(%c, %dynamic-update-slice.2)
+}
+
+%cond (arg: (s32[], bf16[4,8,12])) -> pred[] {
+  ROOT %compare.2 = pred[] compare(%a, %b), direction=LT
+}
+
+ENTRY %main.1 (x: bf16[4,8,112], w: bf16[3,3,3,112,112], h: bf16[1,1,1,112,12]) -> bf16[4,8,12] {
+  %x = bf16[4,8,112]{2,1,0} parameter(0)
+  %w = bf16[3,3,3,112,112]{4,3,2,1,0} parameter(1)
+  %h = bf16[1,1,1,112,12]{4,3,2,1,0} parameter(2)
+  %copy-start.1 = (bf16[3,3,3,112,112]{4,3,2,1,0}, bf16[3,3,3,112,112]{4,3,2,1,0}, u32[]) copy-start(%w)
+  %copy-done.1 = bf16[3,3,3,112,112]{4,3,2,1,0} copy-done(%copy-start.1)
+  %fusion.9 = bf16[4,8,112]{2,1,0} fusion(%x), kind=kLoop, calls=%fused_computation.9, metadata={op_name="jit(program)/forward/RSUNet/skip0/add"}
+  %copy.3 = bf16[4,8,112]{1,2,0} copy(%fusion.9)
+  %bitcast.3 = bf16[4,8,112]{2,1,0} bitcast(%copy.3)
+  %copy.4 = bf16[4,8,112]{2,1,0} copy(%x)
+  %copy.6 = bf16[4,8,112]{1,2,0} copy(%x)
+  %fusion.10 = bf16[4,8,112]{2,1,0} fusion(%copy.6), kind=kLoop, calls=%fused_computation.9, metadata={op_name="jit(program)/forward/RSUNet/skip0/add"}
+  %fusion.1066 = bf16[4,8,12]{2,1,0} fusion(%bitcast.3, %copy-done.1, %h, %copy.6), kind=kOutput, calls=%fused_computation.8, metadata={op_name="jit(program)/forward/RSUNet/out/conv_general_dilated"}
+  %add.4 = bf16[4,8,112]{2,1,0} add(%copy.4, %fusion.9), metadata={op_name="jit(program)/forward/RSUNet/add"}
+  %reduce.4 = bf16[4,8,112]{2,1,0} reduce(%copy.4, %x), metadata={op_name="jit(program)/normalize/reduce_sum"}
+  %while.2 = (s32[], bf16[4,8,12]{2,1,0}) while(%t), condition=%cond, body=%body, metadata={op_name="jit(program)/accumulate/scatter-add"}
+  ROOT %copy.5 = bf16[4,8,12]{2,1,0} copy(%fusion.1066)
+}
+"""
+
+
+def test_op_parts_names_a_fusion_by_its_widest_convolution():
+    parts, convolutions = profiling.op_parts(_PARTS_HLO)
+    forward = parts["forward"]
+    # (a) the fusion is `dec0`, whatever its root, name and shape say: the
+    # 3x3x3 convolution sits in a nested fusion, the 1x1x1 head is the root
+    assert "fusion.1066" in forward["dec0"]
+    assert "out" not in forward
+    assert convolutions == {"fusion.1066": [["dec0/conv3", "3x3x3"],
+                                            ["out", "1x1x1"]]}
+    # (b) any other op by its own path; the model's own `add` has no part
+    assert forward["skip0"] == ["fusion.9", "fusion.10"]
+    assert forward[""] == ["add.4"]
+    # ops inside fusions are no events of their own
+    listed = {op for by_part in parts.values()
+              for ops in by_part.values() for op in ops}
+    assert not listed & {"convolution.5", "convolution.6", "fusion.7",
+                         "add.9", "maximum.5"}
+
+
+def test_op_parts_of_ops_without_metadata():
+    parts, _ = profiling.op_parts(_PARTS_HLO)
+    # (c) a while's body and condition take the while's scope (and part)
+    assert sorted(parts["accumulate"][""]) == [
+        "compare.2", "dynamic-update-slice.2", "while.2"]
+    # (d) XLA's own copies take the part of the ops that read them: one
+    # reader (through a bitcast, which is no event), and a weight's
+    # copy-start through its copy-done. Their scope stays "", as in
+    # op_scopes: a part under no scope
+    assert sorted(parts[""]["dec0"]) == ["copy-done.1", "copy-start.1",
+                                         "copy.3"]
+    # readers in two parts (`dec0` and `skip0`), readers with no part
+    # (the model's own add, `normalize`'s reduce), nobody reads it: no part
+    assert sorted(parts[""][""]) == ["copy.4", "copy.5", "copy.6"]
+    # every scope holds the ops op_scopes lists there
+    scopes = profiling.op_scopes(_PARTS_HLO)
+    assert {scope: sorted(op for ops in by_part.values() for op in ops)
+            for scope, by_part in parts.items()} \
+        == {scope: sorted(ops) for scope, ops in scopes.items()}
+
+
+def test_op_parts_on_the_scope_test_module():
+    """The module ``op_scopes`` is pinned on: no root module below the
+    scope but `RSUNet`, so the only part is that of the path that goes
+    on below it."""
+    parts, convolutions = profiling.op_parts(_HLO)
+    assert parts["forward"] == {"gather": ["fusion.1"]}
+    assert parts["normalize"] == {"": ["conditional.1", "copy.9"]}
+    # copy.1 has one reader, add.1, which is under no scope itself
+    assert sorted(parts[""][""]) == ["add.1", "copy.1", "gather.1"]
+    assert convolutions == {}
+
+
 def test_programs_json_carries_op_scopes_only_with_a_sink(clean_plane,
                                                           tmp_path):
     import jax
@@ -522,7 +632,9 @@ def test_programs_json_carries_op_scopes_only_with_a_sink(clean_plane,
 
     x = jnp.ones((8, 128), jnp.float32)
     ProgramCache(label="bare").get(("bare",), build)(x)
-    assert profiling.catalog()[0]["op_scopes"] is None   # nobody reads it
+    for name in profiling.OP_MAPS:   # nobody reads them
+        assert profiling.catalog()[0][name] is None
+    assert "program/op_map_seconds" not in telemetry.snapshot()["counters"]
     telemetry.reset()
 
     telemetry.configure(str(tmp_path))
@@ -532,11 +644,20 @@ def test_programs_json_carries_op_scopes_only_with_a_sink(clean_plane,
     telemetry.flush()
     payload = json.loads((tmp_path / "programs.json").read_text())
     assert payload["programs"][0]["op_scopes"] == entry["op_scopes"]
-    # the JSONL stream gets the ledger without the per-op lists
+    # the same ops again, by part (none below these bare scopes), and no
+    # convolution in this program
+    assert {scope: sorted(ops) for scope, ops in entry["op_scopes"].items()} \
+        == {scope: sorted(by_part[""])
+            for scope, by_part in entry["op_parts"].items()}
+    assert payload["programs"][0]["op_parts"] == entry["op_parts"]
+    assert payload["programs"][0]["op_convolutions"] == {}
+    assert telemetry.snapshot()["counters"]["program/op_map_seconds"] > 0
+    # the JSONL stream gets the ledger without the per-op maps
     with open(telemetry.configured_path()) as f:
         streamed = [json.loads(line) for line in f
                     if '"kind": "programs"' in line]
-    assert streamed and "op_scopes" not in streamed[0]["programs"][0]
+    assert streamed and not set(profiling.OP_MAPS) & set(
+        streamed[0]["programs"][0])
 
 
 def test_trace_gauge_lands_on_the_program_being_built(clean_plane, tmp_path):
@@ -634,13 +755,20 @@ def test_a_host_that_waits_for_the_device_is_no_anomaly(clean_plane, phase):
     assert captured == []
 
 
+@pytest.mark.parametrize("hidden, lacks", [
+    # a checkout from before the scopes: its executable names none
+    ("", "forward"),
+    # a checkout from before the model named what it emits itself: its
+    # executable has `forward` and `enc0` and lacks `pool0`
+    ("pool", "forward/pool0"),
+])
 def test_op_scopes_survive_a_cache_entry_from_before_the_scopes(
-        clean_plane, tmp_path):
+        clean_plane, tmp_path, hidden, lacks):
     """JAX leaves metadata out of the compile-cache key: a checkout
-    without the scopes fills the cache, and the scoped program is then
-    handed that executable. The ledger notices (the lowered module names
-    scopes, the executable's none) and reads the map from one compile
-    past the cache."""
+    without (some of) the names fills the cache, and today's program is
+    then handed that executable. The ledger notices (the lowered module
+    names what the executable's metadata lacks) and reads the maps from
+    one compile past the cache."""
     import contextlib
 
     import jax
@@ -649,12 +777,16 @@ def test_op_scopes_survive_a_cache_entry_from_before_the_scopes(
 
     def build():
         def program(x):
-            with jax.named_scope("forward"):
-                y = jnp.tanh(x) * 2.0
+            with jax.named_scope("forward"), jax.named_scope("Net"):
+                with jax.named_scope("enc0"):
+                    y = jnp.tanh(x) * 2.0
+                with jax.named_scope("pool0"):
+                    y = y.reshape(4, 2, 128).max(axis=1)
             with jax.named_scope("normalize"):
                 return y / jnp.maximum(y.sum(), 1.0)
         return jax.jit(program)
 
+    named_scope = jax.named_scope
     x = jnp.ones((8, 128), jnp.float32)
     saved = {name: getattr(jax.config, name) for name in (
         "jax_compilation_cache_dir",
@@ -665,21 +797,42 @@ def test_op_scopes_survive_a_cache_entry_from_before_the_scopes(
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     compilation_cache.reset_cache()
     try:
-        # the other checkout: the same program, no scopes, fills the cache
+        # the other checkout: the same program, fewer names, fills the cache
         with clean_plane.context() as patch:
-            patch.setattr(jax, "named_scope",
-                          lambda name: contextlib.nullcontext())
-            build()(x).block_until_ready()
+            patch.setattr(
+                jax, "named_scope",
+                lambda name: contextlib.nullcontext()
+                if name.startswith(hidden) else named_scope(name))
+            theirs = build()
+            theirs(x).block_until_ready()
+            assert lacks not in profiling._names_of(
+                profiling._HLO_OP_NAME.findall(
+                    theirs.lower(x).compile().as_text()))
         assert list((tmp_path / "cache").iterdir())
         telemetry.configure(str(tmp_path / "metrics"))
         ProgramCache(label="scoped").get(("scoped",), build)(x)
         (entry,) = profiling.catalog()
         assert entry["op_scopes"]["forward"]
         assert entry["op_scopes"]["normalize"]
+        assert entry["op_parts"]["forward"]["pool0"]
         counters = telemetry.snapshot()["counters"]
         assert counters["program/stale_cache_entries"] == 1
         # the cache is on again afterwards
         assert jax.config.jax_enable_compilation_cache
+        # the stale entry lives on (nothing past the cache is written to
+        # it); once it is gone, today's own entry is read as it is
+        for stale in (tmp_path / "cache").iterdir():
+            stale.unlink()
+        compilation_cache.reset_cache()
+        telemetry.reset()
+        telemetry.configure(str(tmp_path / "metrics2"))
+        for label in ("fills", "reads"):
+            ProgramCache(label=label).get((label,), build)(x)
+        assert list((tmp_path / "cache").iterdir())
+        assert all(e["op_parts"]["forward"]["pool0"]
+                   for e in profiling.catalog())
+        assert "program/stale_cache_entries" not in \
+            telemetry.snapshot()["counters"]
     finally:
         for name, value in saved.items():
             jax.config.update(name, value)

@@ -1,9 +1,13 @@
 """The named scopes every patch program traces its parts under (ISSUE
-23): they reach the compiled module's ``op_name`` metadata in every
-program family, where core/profiling.py reads them back for
+23), and those the RSUNet opens around what its own ``__call__`` emits
+(ISSUE 40): they reach the compiled module's ``op_name`` metadata in
+every program family, where core/profiling.py reads them back for
 ``programs.json``; and they are metadata only, so the optimized code is
 the same with and without them."""
 import contextlib
+import glob
+import json
+import os
 import re
 
 import numpy as np
@@ -26,9 +30,24 @@ def conv_engine():
         "", None, PIN, num_input_channels=1, num_output_channels=3)
 
 
-def make_inferencer(engine, **kw):
+# the RSUNet pools three times: a patch of its own, and an output patch
+# smaller than it, so that the decoder's cone cuts (`crop{i}`)
+RSUNET_PIN = (8, 32, 32)
+RSUNET_POUT = (4, 16, 16)
+RSUNET_OVERLAP = (2, 8, 8)
+
+
+@pytest.fixture(scope="module")
+def rsunet_engine():
+    return engines.create_flax_engine(
+        "", None, RSUNET_PIN, num_input_channels=1, num_output_channels=3,
+        dtype="bfloat16", model_variant="rsunet",
+        output_patch_size=RSUNET_POUT)
+
+
+def make_inferencer(engine, pin=PIN, overlap=OVERLAP, **kw):
     return Inferencer(
-        input_patch_size=PIN, output_patch_overlap=OVERLAP,
+        input_patch_size=pin, output_patch_overlap=overlap,
         num_output_channels=3, framework="prebuilt", batch_size=2,
         engine=engine, crop_output_margin=False, **kw)
 
@@ -103,7 +122,8 @@ def _stripped(hlo: str) -> str:
                   hlo)
 
 
-def test_scopes_are_metadata_only(conv_engine, monkeypatch):
+@pytest.mark.parametrize("which", ["conv", "rsunet"])
+def test_scopes_are_metadata_only(which, request, monkeypatch):
     import jax
     import jax.numpy as jnp
 
@@ -112,12 +132,20 @@ def test_scopes_are_metadata_only(conv_engine, monkeypatch):
         pad_to_batch,
     )
 
-    grid = enumerate_patches((8, 32, 32), PIN, PIN, OVERLAP)
+    if which == "conv":
+        inferencer = make_inferencer(request.getfixturevalue("conv_engine"))
+        chunk_shape, pin, pout, overlap = (8, 32, 32), PIN, PIN, OVERLAP
+    else:  # the model's own scopes too: in, pool, crop, skip, post
+        inferencer = make_inferencer(
+            request.getfixturevalue("rsunet_engine"), RSUNET_PIN,
+            RSUNET_OVERLAP, output_patch_size=RSUNET_POUT)
+        chunk_shape, pin, pout, overlap = (
+            (12, 48, 48), RSUNET_PIN, RSUNET_POUT, RSUNET_OVERLAP)
+    grid = enumerate_patches(chunk_shape, pin, pout, overlap)
     in_starts, out_starts, valid = pad_to_batch(grid, 2)
-    inferencer = make_inferencer(conv_engine)
-    args = (jnp.zeros((1, 8, 32, 32), jnp.float32), jnp.asarray(in_starts),
-            jnp.asarray(out_starts), jnp.asarray(valid),
-            inferencer.engine.params)
+    args = (jnp.zeros((1, *chunk_shape), jnp.float32),
+            jnp.asarray(in_starts), jnp.asarray(out_starts),
+            jnp.asarray(valid), inferencer.engine.params)
 
     def compiled_text():
         return inferencer._build_program().lower(*args).compile().as_text()
@@ -139,6 +167,102 @@ def test_scopes_are_metadata_only(conv_engine, monkeypatch):
     finally:
         jax.config.update("jax_enable_compilation_cache", was_on)
         compilation_cache.reset_cache()
-    assert FOUR <= set(profiling.op_scopes(scoped))
+    # (the RSUNet's cast fuses the gather's ops into its own on the CPU)
+    assert FOUR - {"gather"} <= set(profiling.op_scopes(scoped))
+    assert which == "rsunet" or "gather" in profiling.op_scopes(scoped)
     assert not set(profiling.op_scopes(bare)) & FOUR
+    if which == "rsunet":
+        # the model's own names are in the executable's metadata, fused
+        # instructions' too; bare there is none (flax names its modules
+        # through jax.named_scope as well)
+        for name in ("enc0", "in", "pool0", "skip1", "post"):
+            assert f"/forward/RSUNet/{name}/" in scoped
+        assert {"enc0", "pool0"} <= set(
+            profiling.op_parts(scoped)[0]["forward"])
+        assert not profiling.op_parts(bare)[0].get("forward")
+        assert "RSUNet/" not in bare
     assert _stripped(scoped) == _stripped(bare)
+
+
+# ---------------------------------------------------------------------------
+# the RSUNet's parts in its patch program (ISSUE 40)
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def rsunet_program(rsunet_engine, tmp_path_factory):
+    """The RSUNet's patch program's ledger entry and the run's counters,
+    with a sink configured."""
+    monkeypatch = pytest.MonkeyPatch()
+    monkeypatch.delenv("CHUNKFLOW_TELEMETRY", raising=False)
+    telemetry.reset()
+    telemetry.configure(str(tmp_path_factory.mktemp("metrics")))
+    try:
+        make_inferencer(rsunet_engine, RSUNET_PIN, RSUNET_OVERLAP,
+                        output_patch_size=RSUNET_POUT)(
+            Chunk(np.random.default_rng(0).random((12, 48, 48),
+                                                  dtype=np.float32)))
+        (entry,) = [e for e in profiling.catalog()
+                    if "forward" in (e["op_parts"] or {})]
+        return entry, telemetry.snapshot()["counters"]
+    finally:
+        telemetry.reset()
+        monkeypatch.undo()
+
+
+def part_patterns() -> dict:
+    """``{metric: regex}`` of the per-layer metrics that read
+    ``op_parts``: the part names live in their files' ``args``."""
+    found = {}
+    for path in glob.glob(os.path.join(
+            os.path.dirname(__file__), "..", "..", "benchmarks",
+            "layer_metrics", "*.json")):
+        with open(path) as f:
+            parts = json.load(f).get("args", {}).get("parts")
+        if parts:
+            found[os.path.basename(path)[:-len(".json")]] = parts
+    return found
+
+
+def test_every_part_of_the_rsunet_is_read_by_one_group_of_metrics(
+        rsunet_program):
+    entry, _ = rsunet_program
+    parts = entry["op_parts"]["forward"]
+    # every flax module and both branches of the pool leave ops of their
+    # own on the CPU too (what else the model names fuses into those here)
+    assert {"embed", "enc0", "enc1", "enc2", "bridge", "dec2", "dec1",
+            "dec0", "out", "up0", "up1", "up2", "pool0", "pool1",
+            "pool2"} <= set(parts)
+    # the groups the metric files name: level 0 (twice: its convolutions
+    # and the rest), level 1, the deep levels, the glue. Every part the
+    # model emits, and every name it gives (also where XLA fused the op
+    # away), lies in exactly one
+    groups = set(part_patterns().values())
+    assert len(groups) == 4
+    named = {"in", "post"} | {f"{kind}{i}" for i in range(3)
+                              for kind in ("pool", "skip")} \
+        | {f"crop{i}" for i in range(4)}
+    for part in (set(parts) - {""}) | named:
+        assert sum(bool(re.fullmatch(group, part)) for group in groups) \
+            == 1, part
+
+
+def test_no_convolution_of_the_rsunet_is_left_without_a_part(rsunet_program):
+    entry, counters = rsunet_program
+    convolutions = entry["op_convolutions"]
+    assert convolutions
+    for scope, by_part in entry["op_parts"].items():
+        held = [op for op in by_part.get("", []) if op in convolutions]
+        assert not held or scope != "forward", held
+    # every convolution of the program is the model's
+    assert set(convolutions) <= {
+        op for ops in entry["op_parts"]["forward"].values() for op in ops}
+    for held in convolutions.values():
+        assert all(re.fullmatch(r"\d+x\d+x\d+", window)
+                   for _, window in held)
+
+
+def test_a_fresh_executable_is_not_called_stale(rsunet_program):
+    """`crop{i}` is one slice each, which XLA folds into its reader: the
+    executable lacks the name and is still today's."""
+    _, counters = rsunet_program
+    assert "program/stale_cache_entries" not in counters
+    assert counters["program/op_map_seconds"] > 0

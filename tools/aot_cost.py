@@ -3,8 +3,10 @@
 Compiles the RSUNet of a benchmark configuration for a *described* TPU
 v5e (``jax.experimental.topologies``; needs libtpu, no device) and prints
 XLA's own ``estimated_cycles`` for every op of the entry computation with
-its flax module (``op_name`` metadata) and its shape and layout, the
-largest first, and the total.
+its part of the model (``core/profiling.py:op_parts``: the flax module or
+model scope on its ``op_name`` path, a fusion's by its widest convolution
+and not by its root), the convolutions inside it and its shape and layout,
+the largest first, and the total.
 
 This is the compiler's cost model and not a measurement. It ranks two
 lowerings of the same forward against each other and says which ops a
@@ -20,11 +22,16 @@ them apart.
     JAX_PLATFORMS=cpu python tools/aot_cost.py rsunet-superhuman --by-module
 """
 import argparse
-import functools
 import json
 import os
 import re
 import sys
+
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if CHECKOUT not in sys.path:  # run as a script: tools/ is on the path
+    sys.path.insert(0, CHECKOUT)
+
+from chunkflow_tpu.core import profiling  # noqa: E402
 
 CLOCK_HZ = 1.5e9  # the rate the totals are turned into ms with; a scale
 
@@ -62,25 +69,36 @@ def entry_ops(hlo_text: str) -> list:
 
 
 def module_of(op_name: str) -> str:
-    """``jit(apply)/RSUNet/enc0/conv2/conv_general_dilated`` ->
-    ``enc0/conv2/conv_general_dilated``."""
+    """``jit(forward)/forward/RSUNet/enc0/conv2/conv_general_dilated`` ->
+    ``enc0/conv2/conv_general_dilated``: the path below the model."""
     parts = op_name.split("/")
-    return "/".join(parts[2:]) if len(parts) > 2 else op_name
+    return "/".join(parts[3:]) if len(parts) > 3 else op_name
 
 
-def by_module(ops) -> dict:
-    """``{flax module: [cycles of its convolutions, cycles of the rest]}``
-    of :func:`entry_ops`' list. The module is the first name under the
-    model (``enc1``, ``up0``; ``-`` for what the model's own ``__call__``
-    emits, the pools and skip adds, and for XLA's unnamed copies). A
-    convolution is what the chip's trace reduction takes for one
-    (``benchmarks/cfbench/trace.py`` ``parse_op``): a ``kOutput`` fusion,
-    the convolution with its epilogue, unless its name says it is rooted
-    elsewhere (a reduce-window: the pool), or a bare ``convolution``."""
+def part_of_ops(hlo_text: str):
+    """``({op: part}, {op: [[module path, window], ...]})`` by the
+    program's own rule (``core/profiling.py:op_parts``: a fusion by its
+    widest convolution, not by its root; XLA's unnamed copies by their
+    readers), and the convolutions each op holds."""
+    parts, convolutions = profiling.op_parts(hlo_text)
+    return {op: part for by_part in parts.values()
+            for part, ops in by_part.items() for op in ops}, convolutions
+
+
+def by_module(hlo_text: str) -> dict:
+    """``{part: [cycles of its convolutions, cycles of the rest]}`` of the
+    entry computation's ops. The part is the first name under the model
+    (:func:`part_of_ops`: a flax module, ``enc1``, ``up0``, or a scope of
+    the model's own ``__call__``, ``pool0``, ``skip1``; ``-`` for what has
+    neither). A convolution is what the chip's trace reduction takes for
+    one (``benchmarks/cfbench/trace.py`` ``parse_op``): a ``kOutput``
+    fusion, the convolution with its epilogue, unless its name says it is
+    rooted elsewhere (a reduce-window: the pool), or a bare
+    ``convolution``."""
+    part_of, _ = part_of_ops(hlo_text)
     table: dict = {}
-    for cycles, op, opcode, _, op_name, kind in ops:
-        path = module_of(op_name).split("/")
-        row = table.setdefault(path[0] if len(path) > 1 else "-", [0, 0])
+    for cycles, op, opcode, _, _, kind in entry_ops(hlo_text):
+        row = table.setdefault(part_of.get(op) or "-", [0, 0])
         conv = opcode == "convolution" or (
             kind == "kOutput" and not _NOT_CONV_ROOT.search(op))
         row[0 if conv else 1] += cycles
@@ -115,8 +133,14 @@ def compile_forward(config: dict, batch: int):
     params, x = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
         (params, jax.ShapeDtypeStruct(shape, jnp.float32)))
-    forward = functools.partial(
-        model.apply, output_patch_size=config.get("output_patch"))
+
+    def forward(params, x):
+        # under the scope the engine's programs give the model: the paths
+        # are then the ones ``op_parts`` reads in a run's programs.json
+        with jax.named_scope("forward"):
+            return model.apply(params, x,
+                               output_patch_size=config.get("output_patch"))
+
     return jax.jit(forward).lower(params, x).compile()
 
 
@@ -128,16 +152,13 @@ def main(argv=None) -> int:
                         help="patches a program (default: the config's)")
     parser.add_argument("--top", type=int, default=25)
     parser.add_argument("--by-module", action="store_true",
-                        help="cycles summed by flax module, convolutions "
+                        help="cycles summed by part of the model, convolutions "
                         "apart from the rest, in place of the op list")
     parser.add_argument("--hlo", help="also write the optimized HLO here")
     args = parser.parse_args(argv)
-    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if checkout not in sys.path:  # run as a script: tools/ is on the path
-        sys.path.insert(0, checkout)
     path = args.config
     if not os.path.exists(path):
-        path = os.path.join(checkout, "benchmarks", "configs",
+        path = os.path.join(CHECKOUT, "benchmarks", "configs",
                             args.config + ".json")
     with open(path) as f:
         config = json.load(f)
@@ -162,15 +183,22 @@ def main(argv=None) -> int:
     if args.by_module:
         print(f"{'module':<10} {'conv M':>8} {'rest M':>8} {'%':>5}")
         for module, (conv, rest) in sorted(
-                by_module(ops).items(), key=lambda kv: -sum(kv[1])):
+                by_module(text).items(), key=lambda kv: -sum(kv[1])):
             print(f"{module:<10} {conv / 1e6:8.1f} {rest / 1e6:8.1f} "
                   f"{100.0 * (conv + rest) / total:5.1f}")
         return 0
-    print(f"{'Mcycles':>8} {'%':>5}  op / shape{{layout}} / flax module")
+    # a fusion is named, shaped and annotated after its root: beside the
+    # root's path, the part the op counts under and the convolutions inside
+    part_of, convolutions = part_of_ops(text)
+    print(f"{'Mcycles':>8} {'%':>5}  op / shape{{layout}} / part / "
+          f"convolutions inside, or the op's own path")
     for cycles, op, _, shape, op_name, _ in sorted(
             ops, reverse=True)[:args.top]:
+        inside = " + ".join(f"{path} {window}"
+                            for path, window in convolutions.get(op, []))
         print(f"{cycles / 1e6:8.2f} {100.0 * cycles / total:5.1f}  {op} "
-              f"{shape} {module_of(op_name) or '-'}")
+              f"{shape} {part_of.get(op) or '-'} / "
+              f"{inside or module_of(op_name) or '-'}")
     return 0
 
 
