@@ -33,6 +33,14 @@ PR 29).  A fold is inherited through a pool and never made by a reshape
 that splits the lanes, so levels 2 and 3 run unfolded.  The transitions
 are emitted folded as well (:class:`XFoldUp`, :func:`max_pool_folded`):
 behind a plain reshape the pool alone took a quarter of the chip's time.
+An up-sampling emits its rows from a convolution of the input dilated
+with zeros in y, one a z plane of its factor (``up0``: one, ``up1``: two,
+stacked on the major axis; gauge ``forward/up{i}_convolutions``), with the
+cone's cut below as the convolution's padding and, by XLA's own fusion,
+the bias and the skip sum in its epilogue; stacked from one 1x1x1
+convolution a row and reshaped, the interleave, the skip sum and the
+copies between them were nine full-size passes around ``up0`` for an
+array that is written once (PERF.md, PR 41).
 Norm is folded to a per-channel affine (no
 batch statistics at inference), compute is optionally bfloat16 with
 float32 params; the final activation is computed in the output's dtype.
@@ -53,7 +61,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import flax.linen as nn
 import jax
@@ -65,6 +73,7 @@ from jax import lax
 from chunkflow_tpu.core import profiling
 
 Triple = Tuple[int, int, int]
+Box = Tuple[Tuple[int, int], ...]  # (lo, hi) on z, y, x, in a level's voxels
 
 LANES = 128  # the minor dimension of a TPU vreg, VMEM tile and MXU pass
 EMBED_KERNEL = (1, 5, 5)
@@ -179,9 +188,29 @@ class XFoldUp(nn.Module):
     ``in_fold`` times the x factor) with no full-resolution array in
     between. With kernel == stride every input position emits its own
     (fz, fy, fx, features) block, so the input folded by ``fold // fx``
-    needs one 1x1x1 convolution with a block-diagonal kernel: the x part
-    of each block is already the folded channel order, and only z and y
-    are interleaved afterwards, above the lanes."""
+    needs a block-diagonal kernel and no tap in x: the x part of each
+    block is already the folded channel order. Only y and z are
+    interleaved, above the lanes:
+
+    - y by the convolution itself: the input is dilated with zeros by fy
+      and the kernel holds the fy blocks (output row ``fy*y + j`` is
+      ``x[y] . k[j]``; every other product is an exact zero), so each row
+      is written where it belongs, XLA fuses the bias and the skip sum
+      that follows into the convolution's epilogue, and ``dec{i}/conv1``
+      reads the result in the layout it was written in. Emitted as one
+      1x1x1 convolution a row, stacked and reshaped, each left XLA's
+      convolution layout through a copy of its own, the interleave was a
+      pad-and-maximum fusion and two more copies, and the skip sum a
+      pass of its own with a copy behind it: nine full-size passes around
+      ``up0`` where one does (PERF.md, PR 41);
+    - z, the major axis, by a stack of fz such convolutions
+      (:attr:`convolutions`), one a plane of the factor.
+
+    ``want`` inside ``held`` (the box of its level that the input holds):
+    the part of the input to up-sample. The convolutions cut it by their
+    padding, negative where rows are dropped, and no slice is emitted: a
+    slice between ``dec{i+1}`` and a dilated convolution cost what the
+    interleave saved."""
 
     features: int
     factor: Triple
@@ -189,11 +218,28 @@ class XFoldUp(nn.Module):
     fold: int = 2
     in_fold: int = 1
 
+    @property
+    def convolutions(self) -> int:
+        """How many convolutions emit the rows: one a z plane of the
+        factor, and not one dilated in z as well. One ``engine.apply`` a
+        program on the v5e, ms (PERF.md, PR 41; batch 4 / 4 / 6 at widths
+        28-36-48-64, 16-32-64-128 and the first with a 16x192x192 output
+        patch), ``up0`` (1,2,2) one dilated convolution in all and
+        ``up1`` (2,2,2) as four 1x1x1 convolutions stacked in z and y
+        69.46 / 44.23 / 97.82, as one convolution dilated in z and y
+        72.22 / 42.88 / 95.58, as two dilated in y and stacked in z
+        68.07 / 43.86 / 96.13: the only one of the three that loses in
+        no program. Behind a convolution dilated in z XLA keeps the x
+        tiles of the unfolded level below, 8 + 2 blocks wide where levels
+        1 and 0 have 8 + 1, all the way up the decoder, and pads and
+        copies both skips into them."""
+        return self.factor[0]
+
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, want: Optional[Box] = None,
+                 held: Optional[Box] = None):
         fz, fy, fx = self.factor
-        b, z, y, _, lanes = x.shape
-        cin = lanes // self.in_fold
+        cin = x.shape[-1] // self.in_fold
         kernel = self.param("kernel", nn.initializers.lecun_normal(),
                             (fz, fy, fx, cin, self.features))
         bias = self.param("bias", nn.initializers.zeros_init(),
@@ -204,14 +250,24 @@ class XFoldUp(nn.Module):
         k = kernel[::-1, ::-1, ::-1].transpose(0, 1, 3, 2, 4)  # i,j,c,k,f
         same = np.eye(g, dtype=bool)[:, None, :, None, None]
         k = jnp.where(same, k[:, :, None, :, None], 0)  # i,j,g,c,g,k,f
-        k = k.reshape(fz, fy, 1, 1, 1, g * cin, self.fold * self.features)
+        k = k.reshape(fz, fy, 1, g * cin, self.fold * self.features)
+        # what the box drops of the input at either end of each axis
+        (z0, z1), (y0, y1), (x0, x1) = [(0, 0)] * 3 if want is None \
+            else [(lo - origin, end - hi)
+                  for (lo, hi), (origin, end) in zip(want, held)]
         x = fold_x(x, g // self.in_fold)
-        rows = [[lax.conv_general_dilated(
-            x, k[i, j], (1, 1, 1), "VALID",
+        planes = [lax.conv_general_dilated(
+            x, k[i:i + 1, ::-1],  # the convolution flips the taps back
+            window_strides=(1, 1, 1),
+            padding=((-z0, -z1), (fy - 1 - fy * y0, fy - 1 - fy * y1),
+                     (-(x0 // g), -(x1 // g))),
+            lhs_dilation=(1, fy, 1),
             dimension_numbers=("NDHWC", "DHWIO", "NDHWC"))
-            for j in range(fy)] for i in range(fz)]
-        y_ = jnp.stack([jnp.stack(row, axis=3) for row in rows], axis=2)
-        y_ = y_.reshape(b, z * fz, y * fy, x.shape[3], -1)
+            for i in range(fz)]
+        y_ = planes[0]
+        if fz > 1:
+            b, z, *rest = y_.shape
+            y_ = jnp.stack(planes, axis=2).reshape(b, z * fz, *rest)
         return y_ + jnp.tile(bias, self.fold)
 
 
@@ -273,9 +329,6 @@ class RSBlock(nn.Module):
         x = nn.relu(self.bn2(self.conv2(x)))
         x = nn.relu(self.bn3(self.conv3(x)) + residual)
         return x
-
-
-Box = Tuple[Tuple[int, int], ...]  # (lo, hi) on z, y, x, in a level's voxels
 
 
 def _voxels(box: Box) -> int:
@@ -428,17 +481,21 @@ class RSUNet(nn.Module):
         for i in reversed(range(depth - 1)):
             factor = self.down_factors[i]
             box, _ = cone[i]
-            held, want = cone[i + 1]
-            with jax.named_scope(f"crop{i + 1}"):  # of level i+1's result
-                x = _crop(x, want, held, folds[i + 1])
+            held, want = cone[i + 1]  # of level i+1's result
             if folds[i] % factor[2]:
+                emitted = 1
+                with jax.named_scope(f"crop{i + 1}"):
+                    x = _crop(x, want, held, folds[i + 1])
                 x = fold_x(nn.ConvTranspose(
                     self.width[i], kernel_size=factor, strides=factor,
                     dtype=dt, name=f"up{i}")(x), folds[i])
-            else:
-                x = XFoldUp(self.width[i], factor=factor, dtype=dt,
-                            fold=folds[i], in_fold=folds[i + 1],
-                            name=f"up{i}")(x)
+            else:  # the cut rides in the convolutions' padding
+                up = XFoldUp(self.width[i], factor=factor, dtype=dt,
+                             fold=folds[i], in_fold=folds[i + 1],
+                             name=f"up{i}")
+                emitted = up.convolutions
+                x = up(x, want, held)
+            profiling.trace_gauge(f"forward/up{i}_convolutions", emitted)
             with jax.named_scope(f"skip{i}"):
                 x = x + _crop(skips[i], box, _whole(shapes[i]), folds[i])
             x = block(i, f"dec{i}")(x)

@@ -605,6 +605,53 @@ def test_op_parts_of_ops_without_metadata():
         == {scope: sorted(ops) for scope, ops in scopes.items()}
 
 
+# What the TPU compiler makes of a dilated up-sampling (PERF.md, PR 41;
+# cut from the v5e's text for `rsunet-superhuman`): one output fusion that
+# holds the zero-dilated convolution, the bias and the skip sum, rooted in
+# a bitcast that carries the skip sum's name.
+_UP_HLO = """\
+HloModule jit_program, is_scheduled=true
+
+%fused_computation.133 (p0: bf16[20,256,32,9,112], p1: bf16[20,128,32,9,72], p2: bf16[112], p3: bf16[1,2,1,72,112]) -> bf16[20,256,4,8,9,112] {
+  %p1 = bf16[20,128,32,9,72]{4,2,3,1,0} parameter(1)
+  %p3 = bf16[1,2,1,72,112]{3,4,1,2,0} parameter(3)
+  %convolution-base-dilated.3 = bf16[20,256,32,9,112]{4,2,3,1,0} convolution(%p1, %p3), window={size=1x2x1 pad=0_0x1_1x0_0 lhs_dilate=1x2x1 rhs_reversal=1x1x0}, dim_labels=01b2f_012io->01b2f, metadata={op_name="jit(program)/forward/RSUNet/up0/conv_general_dilated"}
+  %convert.54 = f32[20,256,32,9,112]{4,2,3,1,0} convert(%convolution-base-dilated.3)
+  %p2 = bf16[112]{0} parameter(2)
+  %broadcast.860 = bf16[20,256,32,9,112]{4,2,3,1,0} broadcast(%p2), dimensions={4}, metadata={op_name="jit(program)/forward/RSUNet/up0/add"}
+  %convert.55 = f32[20,256,32,9,112]{4,2,3,1,0} convert(%broadcast.860)
+  %add.396 = f32[20,256,32,9,112]{4,2,3,1,0} add(%convert.54, %convert.55), metadata={op_name="jit(program)/forward/RSUNet/up0/add"}
+  %p0 = bf16[20,256,32,9,112]{4,2,3,1,0} parameter(0)
+  %convert.56 = f32[20,256,32,9,112]{4,2,3,1,0} convert(%p0)
+  %add.397 = f32[20,256,32,9,112]{4,2,3,1,0} add(%add.396, %convert.56), metadata={op_name="jit(program)/forward/RSUNet/skip0/add"}
+  %convert.57 = bf16[20,256,32,9,112]{4,2,3,1,0} convert(%add.397)
+  ROOT %bitcast.250 = bf16[20,256,4,8,9,112]{5,3,2,4,1,0} bitcast(%convert.57), metadata={op_name="jit(program)/forward/RSUNet/skip0/add"}
+}
+
+ENTRY %main.2 (skip: bf16[20,256,32,9,112], below: bf16[20,128,32,9,72], b: bf16[112], k: bf16[1,2,1,72,112]) -> bf16[20,256,4,8,9,112] {
+  %skip = bf16[20,256,32,9,112]{4,2,3,1,0} parameter(0)
+  %below = bf16[20,128,32,9,72]{4,2,3,1,0} parameter(1)
+  %b = bf16[112]{0} parameter(2)
+  %k = bf16[1,2,1,72,112]{3,4,1,2,0} parameter(3)
+  %copy.9 = bf16[1,2,1,72,112]{3,4,1,2,0} copy(%k)
+  ROOT %select_bitcast_fusion.1 = bf16[20,256,4,8,9,112]{5,3,2,4,1,0} fusion(%skip, %below, %b, %copy.9), kind=kOutput, calls=%fused_computation.133, metadata={op_name="jit(program)/forward/RSUNet/skip0/add"}
+}
+"""
+
+
+def test_op_parts_puts_a_dilated_upsampling_with_its_skip_sum_under_up():
+    """The up-sampling's one convolution names the fusion, whatever its
+    root says: the bias and the skip sum ride in its epilogue, so `skip0`
+    has no op of its own and nothing is left without a part."""
+    parts, convolutions = profiling.op_parts(_UP_HLO)
+    assert parts["forward"] == {"up0": ["select_bitcast_fusion.1"]}
+    assert convolutions == {"select_bitcast_fusion.1": [["up0", "1x2x1"]]}
+    # XLA's copy of the kernel takes its reader's part, under no scope
+    assert parts[""] == {"up0": ["copy.9"]}
+    assert profiling.op_scopes(_UP_HLO)["forward"] == [
+        "select_bitcast_fusion.1"]
+
+
 def test_op_parts_on_the_scope_test_module():
     """The module ``op_scopes`` is pinned on: no root module below the
     scope but `RSUNet`, so the only part is that of the path that goes

@@ -375,6 +375,119 @@ def test_upsampling_of_a_folded_input_is_the_transposed_convolution(
     assert np.abs(np.asarray(got) - np.asarray(want)).max() <= 1e-6
 
 
+def _equations(jaxpr, found=None):
+    """Primitive names of a jaxpr's equations, inner jaxprs included."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        found.append(eqn.primitive.name)
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            _equations(inner, found)
+    return found
+
+
+# factor -> the convolutions that emit its rows: one a z plane of the
+# factor, each of the input dilated with zeros in y
+EMITTED = {(1, 2, 2): 1, (2, 2, 2): 2}
+# the part of a (3, 4, 16) input to up-sample, as the cone cuts it: none,
+# y and x blocks, every axis, one side only
+CUTS = {
+    "whole": ((0, 3), (0, 4), (0, 16)),
+    "yx": ((0, 3), (1, 3), (4, 12)),
+    "zyx": ((1, 3), (1, 4), (8, 16)),
+    "low-side": ((0, 2), (0, 3), (0, 8)),
+}
+
+
+@pytest.mark.parametrize("in_fold,fold", [(1, 2), (2, 4), (4, 8)])
+@pytest.mark.parametrize("factor", sorted(EMITTED))
+def test_upsampling_emits_the_convolutions_it_says(factor, in_fold, fold):
+    """``XFoldUp.convolutions`` (the gauge ``forward/up{i}_convolutions``)
+    against the jaxpr: that many convolutions, each dilated by the
+    factor's y, stacked where there is more than one and not otherwise."""
+    up = rsunet.XFoldUp(7, factor=factor, fold=fold, in_fold=in_fold)
+    assert up.convolutions == EMITTED[factor]
+    x = jnp.zeros((2, 3, 4, 16 // in_fold, in_fold * 5))
+    params = jax.eval_shape(lambda: up.init(jax.random.PRNGKey(0), x))
+    jaxpr = jax.make_jaxpr(up.apply)(params, x).jaxpr
+    names = _equations(jaxpr)
+    assert names.count("conv_general_dilated") == up.convolutions
+    assert ("concatenate" in names) == (up.convolutions > 1)
+    assert {eqn.params["lhs_dilation"] for eqn in jaxpr.eqns
+            if eqn.primitive.name == "conv_general_dilated"} \
+        == {(1, factor[1], 1)}
+
+
+@pytest.mark.parametrize("cut", sorted(CUTS))
+@pytest.mark.parametrize("factor", sorted(EMITTED))
+def test_upsampling_cut_by_its_box_is_the_upsampling_of_the_slice(factor,
+                                                                  cut):
+    """``want`` inside ``held``: the values are those of the sliced input
+    up-sampled, and the convolutions take the cut as (negative) padding,
+    so no activation is sliced; with ``want == held`` the jaxpr is the
+    one without a box."""
+    in_fold, fold, cin = 2, 4, 5
+    held = ((4, 7), (2, 6), (8, 24))   # a box of some level, not at 0
+    want = tuple((lo + h0, hi + h0)
+                 for (lo, hi), (h0, _) in zip(CUTS[cut], held))
+    x = jax.random.uniform(jax.random.PRNGKey(8), (2, 3, 4, 16, cin))
+    folded = rsunet.fold_x(x, in_fold)
+    up = rsunet.XFoldUp(7, factor=factor, fold=fold, in_fold=in_fold)
+    params = _perturbed(up.init(jax.random.PRNGKey(0), folded))
+    (z0, z1), (y0, y1), (x0, x1) = CUTS[cut]
+    with jax.default_matmul_precision("highest"):
+        expected = up.apply(params, rsunet.fold_x(
+            x[:, z0:z1, y0:y1, x0:x1], in_fold))
+        got = up.apply(params, folded, want, held)
+    assert got.shape == expected.shape == (
+        2, (z1 - z0) * factor[0], (y1 - y0) * 2, (x1 - x0) * 2 // fold,
+        fold * 7)
+    assert np.abs(np.asarray(got) - np.asarray(expected)).max() <= 1e-6
+    boxed = jax.make_jaxpr(
+        lambda p, v: up.apply(p, v, want, held))(params, folded).jaxpr
+    # the only slices take a z plane of the kernel
+    assert all(eqn.invars[0].aval.shape[-1] == fold * 7
+               for eqn in boxed.eqns if eqn.primitive.name == "slice")
+    if cut == "whole":
+        assert _equations(boxed) == _equations(
+            jax.make_jaxpr(up.apply)(params, folded).jaxpr)
+
+
+@pytest.mark.parametrize("config", ["rsunet-superhuman", "rsunet-deepem",
+                                    "rsunet-superhuman-prod"])
+def test_every_decoder_level_says_how_its_upsampling_is_emitted(config):
+    """``forward/up{i}_convolutions``, one gauge a decoder level, at the
+    widths and the (rehearsal) geometry of each benchmark configuration:
+    ``up0`` (1,2,2) is one dilated convolution, ``up1`` (2,2,2) two of
+    them stacked in z, ``up2`` below the folded levels flax's own
+    ConvTranspose."""
+    import json
+
+    from chunkflow_tpu.core import telemetry
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "..",
+                           "benchmarks", "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    spec, geometry = cfg["model"], cfg["rehearse"]
+    model = rsunet.RSUNet(
+        in_channels=spec["in_channels"], out_channels=spec["out_channels"],
+        width=tuple(spec["width"]),
+        down_factors=tuple(map(tuple, spec["pooling"])),
+        dtype=jnp.dtype(spec["compute_dtype"]))
+    x = jnp.zeros((1, *geometry["patch"], spec["in_channels"]))
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), x))
+    telemetry.reset()
+    try:
+        jax.eval_shape(lambda p, v: model.apply(
+            p, v, output_patch_size=geometry.get("output_patch")), params, x)
+        gauges = telemetry.snapshot()["gauges"]
+    finally:
+        telemetry.reset()
+    assert [gauges[f"forward/up{i}_convolutions"]
+            for i in range(len(spec["width"]) - 1)] == [1, 2, 1]
+    assert rsunet.level_folds(spec["width"], x.shape[3],
+                              spec["pooling"])[2:] == [1, 1]
+
+
 @pytest.mark.parametrize("fold", [2, 4, 8])
 @pytest.mark.parametrize("factor", [(1, 2, 2), (2, 2, 2), (2, 2, 1)])
 def test_pool_of_a_folded_array_is_the_max_pool(factor, fold):

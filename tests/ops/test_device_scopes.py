@@ -260,6 +260,34 @@ def test_no_convolution_of_the_rsunet_is_left_without_a_part(rsunet_program):
                    for _, window in held)
 
 
+@pytest.mark.parametrize("part,windows", [
+    # `up0` (1,2,2): one convolution of the zero-dilated input, two taps
+    # in y; the bias and the skip sum ride behind it where XLA fuses them
+    ("up0", ["1x2x1"]),
+    # `up1` (2,2,2): one such convolution a z plane
+    ("up1", ["1x2x1"] * 2),
+    # `up2`, below the folded levels, is flax's own transposed convolution
+    ("up2", ["2x2x2"]),
+])
+def test_an_upsamplings_convolutions_lie_under_its_own_part(
+        rsunet_program, part, windows):
+    entry, _ = rsunet_program
+    by_part = entry["op_parts"]["forward"]
+    held = [tuple(conv) for op in by_part[part]
+            for conv in entry["op_convolutions"].get(op, [])]
+    assert sorted(held) == sorted((part, window) for window in windows)
+    # and under no other part: a fusion goes by its widest convolution,
+    # and no other convolution is as narrow as an up-sampling's
+    for other, ops in by_part.items():
+        if other != part:
+            assert not any(path == part for op in ops for path, _ in
+                           entry["op_convolutions"].get(op, []))
+    # the cone's cuts leave no op of their own: they are the padding of
+    # `up0`'s and `up1`'s convolutions (level 3 is never cut)
+    assert not {"crop1", "crop2", "crop3"} & set(by_part)
+    assert [entry[f"up{i}_convolutions"] for i in range(3)] == [1, 2, 1]
+
+
 def test_a_fresh_executable_is_not_called_stale(rsunet_program):
     """`crop{i}` is one slice each, which XLA folds into its reader: the
     executable lacks the name and is still today's."""
