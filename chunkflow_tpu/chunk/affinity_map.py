@@ -4,6 +4,10 @@ from __future__ import annotations
 import numpy as np
 
 from chunkflow_tpu.chunk.base import Chunk, LayerType
+from chunkflow_tpu.core.compile_cache import ProgramCache
+
+# one quantizing program a (chunk shape, dtype, mode)
+_PROGRAMS = ProgramCache(maxsize=16, label="thumbnail")
 
 
 class AffinityMap(Chunk):
@@ -64,19 +68,54 @@ class AffinityMap(Chunk):
     def quantize(self, mode: str = "xy") -> Chunk:
         """Compress to a uint8 grayscale thumbnail chunk.
 
-        ``xy``: mean of the y and x affinity channels; ``z``: z channel only.
+        ``xy``: mean of the y and x affinity channels; ``z``: z channel
+        only; times 255, clipped and truncated. Made where the chunk is:
+        a host chunk section by section in numpy (two buffers of a
+        section's size, no copy of the chunk's), a device chunk by one
+        cached program a shape under the scope ``thumbnail``.
         """
-        arr = np.asarray(self.array)
-        if mode == "xy":
-            gray = arr[1:3].mean(axis=0, dtype=np.float32)
-        elif mode == "z":
-            gray = arr[0]
-        else:
+        if mode not in ("xy", "z"):
             raise ValueError(f"unknown quantize mode {mode!r}")
-        gray = np.clip(gray * 255.0, 0, 255).astype(np.uint8)
+        if self.is_on_device:
+            key = ("quantize", tuple(self.array.shape),
+                   str(self.array.dtype), mode)
+            gray = _PROGRAMS.get(
+                key, lambda: _build_quantize(mode))(self.array)
+        else:
+            arr = np.asarray(self.array)
+            gray = np.empty(arr.shape[1:], np.uint8)
+            section = np.empty(arr.shape[2:], np.float32)
+            for z in range(arr.shape[1]):
+                if mode == "xy":
+                    np.add(arr[1, z], arr[2, z], out=section,
+                           dtype=np.float32)
+                    section *= np.float32(0.5)
+                else:
+                    section[...] = arr[0, z]
+                section *= np.float32(255.0)
+                np.clip(section, 0, 255, out=section)
+                gray[z] = section
         return Chunk(
             gray,
             voxel_offset=self.voxel_offset,
             voxel_size=self.voxel_size,
             layer_type=LayerType.IMAGE,
         )
+
+
+def _build_quantize(mode: str):
+    import jax
+    import jax.numpy as jnp
+
+    def program(arr):
+        with jax.named_scope("thumbnail"):
+            if mode == "xy":
+                gray = (arr[1].astype(jnp.float32)
+                        + arr[2].astype(jnp.float32)) * jnp.float32(0.5)
+            else:
+                gray = arr[0].astype(jnp.float32)
+            return jnp.clip(gray * jnp.float32(255.0), 0, 255).astype(
+                jnp.uint8)
+
+    # no donation: the chunk is saved after its thumbnail is made
+    return jax.jit(program)  # graftlint: disable=GL005

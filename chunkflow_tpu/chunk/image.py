@@ -1,11 +1,14 @@
 """Grayscale image chunk (parity: reference chunk/image/base.py).
 
-Contrast normalization is reimplemented as a vectorized per-section
-percentile stretch (jnp-friendly) rather than the reference's pre-computed
-lookup-table files; the lookup-table path can be added when histogram
-sidecar files are in play.
+Contrast normalization is the reference's with ``levels_path``: each
+z-section through a lookup table built from the section's precomputed
+histogram sidecar (ops/contrast.py). Without it, it is a vectorized
+per-section percentile stretch of the chunk's own voxels (jnp-friendly,
+any dtype): another operator, and many times the cost on a large chunk.
 """
 from __future__ import annotations
+
+from typing import Optional
 
 import numpy as np
 
@@ -53,13 +56,25 @@ class Image(Chunk):
         minval: int = 1,
         maxval: int = 255,
         per_section: bool = True,
+        levels_path: Optional[str] = None,
     ) -> "Image":
-        """Percentile contrast stretch, per z-section by default.
+        """Clip the darkest/brightest fractions and stretch the remainder
+        to [minval, maxval].
 
-        Mirrors the intent of the reference's histogram-lookup normalization
-        (image/base.py:93-133): clip the darkest/brightest fractions and
-        stretch the remainder to [minval, maxval].
+        With ``levels_path``: the reference's histogram-lookup
+        normalization (image/base.py:93-133) of a uint8 image, the
+        fractions taken from each section's sidecar ``levels_path/<z>``
+        (ops/contrast.py). Without: a percentile stretch of the chunk's
+        own voxels, per z-section by default.
         """
+        if levels_path is not None:
+            from chunkflow_tpu.ops.contrast import (
+                normalize_contrast_by_levels,
+            )
+
+            return normalize_contrast_by_levels(
+                self, levels_path, lower_clip_fraction, upper_clip_fraction,
+                minval, maxval)
         # stays on device when the payload is already HBM-resident
         if self.is_on_device:
             import jax.numpy as xp

@@ -144,7 +144,11 @@ def device_peaks(device_kind: str) -> dict:
 #: The ``jax.named_scope`` names every patch program traces its steps
 #: under: patch gather, model forward, bump-weighted accumulation, weight
 #: normalization, the mesh engine's cross-chip exchanges and, in the
-#: program ops/mask.py builds for a device-resident chunk, ``mask``
+#: programs the chunk operators build for a device-resident chunk,
+#: ``mask`` (ops/mask.py), ``normalize_contrast`` (ops/contrast.py: the
+#: sections through their lookup tables) and ``thumbnail`` (the grey
+#: quantization of chunk/affinity_map.py and the average pooling of
+#: ops/downsample.py)
 #: (``grep -rn named_scope chunkflow_tpu`` lists the files). Below a scope
 #: the path goes on with the names of whoever emitted the op: a flax
 #: module's (``forward/RSUNet/enc0/conv2/conv_general_dilated``) or a
@@ -154,7 +158,7 @@ def device_peaks(device_kind: str) -> dict:
 #: *part* (:func:`op_parts`). Scopes and parts are metadata: the compiled
 #: code is the same with and without them.
 DEVICE_SCOPES = ("gather", "forward", "accumulate", "normalize",
-                 "collective", "mask")
+                 "collective", "mask", "normalize_contrast", "thumbnail")
 
 _HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
 _HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = (.*)$")

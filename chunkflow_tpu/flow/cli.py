@@ -1686,13 +1686,58 @@ def _validate_cutout(vol, chunk, mip, validate_mip, tolerance=0.01):
 def save_precomputed_cmd(op_name, volume_path, mip, upload_log, create_thumbnail,
                          intensity_threshold, parallel, async_write,
                          input_chunk_name):
-    """Write the chunk to a precomputed volume (+ timing log sidecar)."""
+    """Write the chunk to a precomputed volume (+ thumbnail pyramid in
+    the sibling ``thumbnail`` layer, + timing log sidecar)."""
+    import contextvars
     import json
-    import os
+    from concurrent.futures import ThreadPoolExecutor
 
-    from chunkflow_tpu.volume.precomputed import PrecomputedVolume, _local_root
+    from chunkflow_tpu.core import telemetry
+    from chunkflow_tpu.volume.precomputed import PrecomputedVolume
 
     vol = PrecomputedVolume(volume_path)
+    # an output volume without the layer is found now, not a task in
+    thumbnails = vol.thumbnail_layer() if create_thumbnail else None
+    thumbnail_maker = ThreadPoolExecutor(
+        1, thread_name_prefix="thumbnail") if create_thumbnail else None
+
+    def make_thumbnail(chunk, base):
+        """The chunk (at mip ``base``) as grey, pooled by (1, 2, 2) level
+        after level up to the thumbnail layer's last mip. Made where the
+        chunk is: nothing of it goes to the device."""
+        from chunkflow_tpu.chunk import AffinityMap
+        from chunkflow_tpu.ops.downsample import average_pyramid
+
+        on_device = int(chunk.is_on_device)
+        telemetry.inc("thumbnail/h2d_bytes", 0)
+        if chunk.ndim == 4:
+            with telemetry.span("thumbnail/quantize", device=on_device):
+                chunk = AffinityMap(
+                    chunk.array,
+                    voxel_offset=chunk.voxel_offset,
+                    voxel_size=chunk.voxel_size,
+                ).quantize(mode="xy")
+        n_levels = thumbnails.num_mips - 1 - base
+        with telemetry.span("thumbnail/downsample", levels=n_levels,
+                            device=on_device):
+            return average_pyramid(chunk, (1, 2, 2), n_levels)
+
+    def write_thumbnail(levels, base, writes):
+        """The levels into the mips above ``base`` of the thumbnail layer,
+        each at the chunk's box divided by its level's factor (reference
+        save_precomputed.py:104-139)."""
+        with telemetry.span("thumbnail/write") as sp:
+            blocks = nbytes = 0
+            for level, down in enumerate(levels, start=base + 1):
+                # a level is up to a thousand blocks of a few KB: one
+                # driver write, not a future and a cache copy a block
+                writes.append(thumbnails.save(
+                    down, mip=level, wait=not async_write,
+                    per_block=False))
+                blocks += thumbnails.block_count(down.bbox, level)
+                nbytes += int(down.array.nbytes)
+            sp.annotate(blocks=blocks, bytes=nbytes)
+        telemetry.inc("thumbnail/blocks_written", blocks)
 
     @write_operator
     def stage(task):
@@ -1714,46 +1759,63 @@ def save_precomputed_cmd(op_name, volume_path, mip, upload_log, create_thumbnail
                 and float(chunk.array.max()) < thr):
             print(f"skip save: max intensity below {thr}")
             return task
-        future = vol.save(
-            chunk,
-            mip=mip if mip is not None else state.mip,
-            wait=not async_write,
-        )
-        if future is not None:
-            task.setdefault("pending_writes", []).append(future)
+        # the result, then its thumbnail, then the log: each is waited
+        # for here, or rides the task to the barrier in front of the ack
+        # (--async-write), where they are drained in this order
+        base = mip if mip is not None else state.mip
+        making = None
+        if create_thumbnail and not chunk.is_on_device:
+            # a host chunk's thumbnail is made beside its write, on a
+            # thread of its own under the task's context: numpy and the
+            # store's copy of the chunk both leave the interpreter's
+            # lock, and in series the two outlast a task's device time
+            making = thumbnail_maker.submit(
+                contextvars.copy_context().run, make_thumbnail, chunk, base)
+        writes = [vol.save(chunk, mip=base, wait=not async_write)]
         if create_thumbnail:
-            from chunkflow_tpu.ops.downsample import pyramid
-
-            thumb = chunk
-            if thumb.ndim == 4:
-                from chunkflow_tpu.chunk import AffinityMap
-
-                thumb = AffinityMap(
-                    thumb.array,
-                    voxel_offset=thumb.voxel_offset,
-                    voxel_size=thumb.voxel_size,
-                ).quantize()
-            for level, down in enumerate(
-                pyramid(thumb, num_mips=vol.num_mips - 1), start=1
-            ):
-                vol.save(down, mip=level)
+            levels = (making.result() if making is not None
+                      else make_thumbnail(chunk, base))
+            write_thumbnail(levels, base, writes)
+        writes = [w for w in writes if w is not None]
         if upload_log:
-            local = _local_root(volume_path)
-            if local is not None:
-                log_dir = os.path.join(local, "log")
-                os.makedirs(log_dir, exist_ok=True)
-                record = {
-                    "timer": task["log"]["timer"],
-                    "compute_device": task["log"].get("compute_device", ""),
-                    "bbox": chunk.bbox.string,
-                }
-                with open(
-                    os.path.join(log_dir, f"{chunk.bbox.string}.json"), "w"
-                ) as f:
-                    json.dump(record, f)
+            log = _LogWrite(vol, f"log/{chunk.bbox.string}.json", json.dumps({
+                "timer": task["log"]["timer"],
+                "compute_device": task["log"].get("compute_device", ""),
+                "bbox": chunk.bbox.string,
+            }).encode(), after=writes)
+            if async_write:
+                writes.append(log)
+            else:
+                log.result()
+        if writes:
+            task.setdefault("pending_writes", []).extend(writes)
         return task
 
     return stage(_name=op_name)
+
+
+class _LogWrite:
+    """The task's timing log, ``<volume>/log/<bbox>.json`` (reference
+    save_precomputed.py:141-150), as a write of the task: made once the
+    writes before it are durable, so a log beside a volume says that the
+    task's blocks are there. Made at ``result()``: at once after waited
+    writes, else by the barrier in front of the ack (--async-write)."""
+
+    def __init__(self, vol, name: str, data: bytes, after: list):
+        self._vol, self._name, self._data = vol, name, data
+        self._after = list(after)
+
+    def result(self):
+        if self._data is None:
+            return None
+        for write in self._after:
+            write.result()
+        from chunkflow_tpu.core import telemetry
+
+        with telemetry.span("storage/log_write", bytes=len(self._data)):
+            self._vol.kv.write_bytes(self._name, self._data)
+        self._data = None
+        return None
 
 
 @main.command("log-summary")
@@ -2660,11 +2722,18 @@ def channel_voting_cmd(op_name, input_chunk_name, output_chunk_name):
               help="maximum intensity of the transformed chunk")
 @click.option("--per-section/--whole", default=True,
               help="normalize each z-section independently or the whole chunk")
+@click.option("--levels-path", type=str, default=None,
+              help="directory of the sections' histogram sidecars "
+                   "(<image>/levels/<mip>, one JSON file a z with 256 "
+                   "'levels'): each section of a uint8 image goes through "
+                   "a lookup table built from its file (reference "
+                   "image/base.py:93-133); a missing file is an error. "
+                   "Without it: a percentile stretch of the chunk itself")
 @click.option("--input-chunk-name", "-i", type=str, default=DEFAULT_CHUNK_NAME)
 @click.option("--output-chunk-name", "-o", type=str, default=DEFAULT_CHUNK_NAME)
 def normalize_contrast_cmd(op_name, lower_clip_fraction, upper_clip_fraction,
-                           minval, maxval, per_section, input_chunk_name,
-                           output_chunk_name):
+                           minval, maxval, per_section, levels_path,
+                           input_chunk_name, output_chunk_name):
     @operator
     def stage(task):
         img = task[input_chunk_name]
@@ -2676,6 +2745,7 @@ def normalize_contrast_cmd(op_name, lower_clip_fraction, upper_clip_fraction,
             minval=minval,
             maxval=maxval,
             per_section=per_section,
+            levels_path=levels_path,
         )
         return task
 

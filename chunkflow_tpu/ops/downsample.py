@@ -2,8 +2,9 @@
 
 Two pooling modes, matching the reference's use of tinybrain
 (flow/downsample_upload.py:73-79):
-- images / probability maps: average pooling via lax.reduce_window (fuses
-  on TPU; one pass per mip level);
+- images / probability maps: average pooling where the chunk is, by
+  numpy on the host and by one program for every level on the device
+  (``average_pyramid``; the thumbnail of ``save-precomputed``);
 - segmentations: mode pooling ("countless" semantics — the most frequent
   label in each 2x2x... block, implemented by exact bincount over the
   gathered block corners, vectorized in jnp for factor (1,2,2)/(2,2,2)).
@@ -16,34 +17,105 @@ import numpy as np
 
 from chunkflow_tpu.chunk.base import Chunk, LayerType
 from chunkflow_tpu.core.cartesian import Cartesian, to_cartesian
+from chunkflow_tpu.core.compile_cache import ProgramCache
+
+# one averaging program a (chunk shape, dtype, factor, levels)
+_PROGRAMS = ProgramCache(maxsize=16, label="thumbnail")
+
+
+def _pool(xp, arr, factor):
+    """Average pooling of ``arr`` (``[c, z, y, x]``, every extent a
+    multiple of its factor) in ``xp``, numpy or ``jax.numpy``: the same
+    arithmetic on either, an axis at a time. Integers of 8 and 16 bits
+    are summed exactly, in the narrowest type that holds the sum, and
+    divided with ties to even, so the host's and the device's result
+    agree bit for bit; wider integers and floats go through a float32
+    sum."""
+    dtype = np.dtype(arr.dtype)
+    n = int(np.prod(factor))
+    exact = dtype.kind in "iu" and dtype.itemsize <= 2
+    # static choices: a dtype and the caller's Python factor
+    if not exact:
+        wide = np.float32
+    elif dtype.itemsize == 1 and n <= 256:  # graftlint: disable=GL003
+        wide = np.uint16 if dtype.kind == "u" else np.int16
+    else:
+        wide = np.int32
+    total = arr
+    for axis, f in zip((1, 2, 3), factor):
+        parts = [total[(slice(None),) * axis + (slice(k, None, f),)]
+                 for k in range(f)]
+        total = parts[0].astype(wide)
+        for part in parts[1:]:
+            total = total + part.astype(wide)
+    if not exact:
+        mean = total / np.float32(n)
+        return (xp.round(mean) if dtype.kind in "iu" else mean).astype(dtype)
+    # 2 * rest < 2 * n fits the sum's type: n does, and a sum has room
+    quotient, rest = xp.divmod(total, xp.asarray(n, wide))
+    twice = rest * xp.asarray(2, wide)
+    up = (twice > n) | ((twice == n) & ((quotient & 1) == 1))
+    return (quotient + up).astype(dtype)
+
+
+def _average_levels(xp, arr, factor, num_mips: int):
+    levels = []
+    for _ in range(num_mips):
+        trimmed = [n - n % f for n, f in zip(arr.shape[1:], factor)]
+        arr = _pool(xp, arr[:, :trimmed[0], :trimmed[1], :trimmed[2]],
+                    factor)
+        levels.append(arr)
+    return levels
+
+
+def _build_program(factor, num_mips):
+    import jax
+    import jax.numpy as jnp
+
+    def program(arr):
+        with jax.named_scope("thumbnail"):
+            return tuple(_average_levels(jnp, arr, factor, num_mips))
+
+    # no donation: the caller keeps the chunk it gave
+    return jax.jit(program)  # graftlint: disable=GL005
+
+
+def average_pyramid(chunk: Chunk, factor=(1, 2, 2),
+                    num_mips: int = 1) -> List[Chunk]:
+    """``num_mips`` successive average poolings of ``chunk``, made where
+    the chunk is (``chunk.is_on_device``): on the host by numpy, with
+    nothing uploaded, or on the device by one jitted program a shape
+    that returns every level, built through
+    :class:`~chunkflow_tpu.core.compile_cache.ProgramCache` under the
+    named scope ``thumbnail`` (core/profiling.py ``DEVICE_SCOPES``).
+    Extents that the factor does not divide lose their remainder."""
+    by = to_cartesian(factor)
+    factor = tuple(int(f) for f in by)
+    arr = chunk.array
+    squeeze = arr.ndim == 3
+    if chunk.is_on_device:
+        if squeeze:
+            arr = arr[None]
+        key = ("thumbnail", tuple(arr.shape), str(arr.dtype), factor,
+               num_mips)
+        arrays = _PROGRAMS.get(
+            key, lambda: _build_program(factor, num_mips))(arr)
+    else:
+        arr = np.asarray(arr)
+        arrays = _average_levels(np, arr[None] if squeeze else arr, factor,
+                                 num_mips)
+    levels = []
+    offset, size = chunk.voxel_offset, chunk.voxel_size
+    for pooled in arrays:
+        offset, size = offset // by, size * by
+        levels.append(Chunk(
+            pooled[0] if squeeze else pooled, voxel_offset=offset,
+            voxel_size=size, layer_type=chunk.layer_type))
+    return levels
 
 
 def downsample_average(chunk: Chunk, factor=(1, 2, 2)) -> Chunk:
-    import jax.numpy as jnp
-    from jax import lax
-
-    factor = to_cartesian(factor)
-    arr = jnp.asarray(chunk.array, dtype=jnp.float32)
-    squeeze = arr.ndim == 3
-    if squeeze:
-        arr = arr[None]
-    window = (1,) + tuple(factor)
-    pooled = lax.reduce_window(
-        arr, 0.0, lax.add, window, window, padding="VALID"
-    ) / float(factor.prod())
-    if np.dtype(chunk.dtype).kind in "iu":
-        pooled = jnp.round(pooled).astype(chunk.dtype)
-    else:
-        pooled = pooled.astype(chunk.dtype)
-    if squeeze:
-        pooled = pooled[0]
-    out = np.asarray(pooled) if not chunk.is_on_device else pooled
-    return Chunk(
-        out,
-        voxel_offset=chunk.voxel_offset // factor,
-        voxel_size=chunk.voxel_size * factor,
-        layer_type=chunk.layer_type,
-    )
+    return average_pyramid(chunk, factor, 1)[0]
 
 
 def _stack_corners_numpy(arr: np.ndarray, factor) -> np.ndarray:
@@ -155,6 +227,8 @@ def downsample(chunk: Chunk, factor=(1, 2, 2)) -> Chunk:
 
 def pyramid(chunk: Chunk, factor=(1, 2, 2), num_mips: int = 3) -> List[Chunk]:
     """Successive downsamples: [mip+1, mip+2, ...]."""
+    if not chunk.is_segmentation:
+        return average_pyramid(chunk, factor, num_mips)
     levels = []
     current = chunk
     for _ in range(num_mips):

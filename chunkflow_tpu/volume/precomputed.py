@@ -233,7 +233,8 @@ class PrecomputedVolume:
             layer_type=self.layer_type,
         )
 
-    def save(self, chunk: Chunk, mip: int = 0, wait: bool = True):
+    def save(self, chunk: Chunk, mip: int = 0, wait: bool = True,
+             per_block: bool = True):
         """Write a chunk at its global offset (czyx -> xyzc).
 
         Dtype auto-conversion follows the reference
@@ -253,7 +254,8 @@ class PrecomputedVolume:
         read-modify-write) and update the hot-block cache write-through;
         unaligned saves fall back to one driver write and invalidate the
         covered blocks — read-after-write through the cache returns the
-        written bytes either way.
+        written bytes either way. ``per_block=False`` hands an aligned
+        box to the driver as one write too: for layers of small blocks.
         """
         arr = as_native_dtype(np.asarray(chunk.array))
         if arr.ndim == 3:
@@ -278,8 +280,24 @@ class PrecomputedVolume:
         # the storage COMMIT stays asynchronous until the drain barrier
         return blockwise_save(
             self._backend(mip), lo, arr_xyzc,
-            cache=shared_cache(), wait=wait,
+            cache=shared_cache(), wait=wait, per_block=per_block,
         )
+
+    def thumbnail_layer(self) -> "PrecomputedVolume":
+        """The sibling layer ``<volume>/thumbnail`` that ``setup-env``
+        creates beside an output volume (uint8, one channel, a scale a
+        mip by (1, 2, 2) up to ``--thumbnail-mip``): where
+        ``save-precomputed --create-thumbnail`` writes (reference
+        save_precomputed.py:104-139)."""
+        layer = PrecomputedVolume(self.path.rstrip("/") + "/thumbnail")
+        try:
+            layer.info
+        except FileNotFoundError:
+            raise FileNotFoundError(
+                f"--create-thumbnail: no thumbnail layer at {layer.path}; "
+                f"`setup-env --thumbnail` creates it beside the output "
+                f"volume") from None
+        return layer
 
     # ------------------------------------------------------------------
     def block_names(self, bbox: BoundingBox, mip: int = 0) -> List[str]:
@@ -299,6 +317,17 @@ class PrecomputedVolume:
             s, e = clamped.start, clamped.stop
             names.append(f"{key}/{s.x}-{e.x}_{s.y}-{e.y}_{s.z}-{e.z}")
         return names
+
+    def block_count(self, bbox: BoundingBox, mip: int = 0) -> int:
+        """How many blocks of the grid ``bbox`` touches, by arithmetic:
+        ``len(block_names(...))`` for a box inside the volume, without
+        the names."""
+        count = 1
+        for start, stop, offset, block in zip(
+                bbox.start, bbox.stop, self.voxel_offset(mip),
+                self.block_size(mip)):
+            count *= -(-(stop - offset) // block) - (start - offset) // block
+        return int(count)
 
     def has_all_blocks(self, bbox: BoundingBox, mip: int = 0) -> bool:
         """Existence check for skip logic (resume support).

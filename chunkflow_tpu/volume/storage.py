@@ -1092,7 +1092,7 @@ def _write_is_aligned(lo, hi, block, goff, dlo, dhi) -> bool:
 
 def blockwise_save(backend: StorageBackend, lo: Sequence[int],
                    arr: np.ndarray, cache: Optional[BlockCache] = None,
-                   wait: bool = True):
+                   wait: bool = True, per_block: bool = True):
     """Write ``arr`` at ``lo`` through the coalescing path.
 
     Block-aligned writes decompose into per-block futures issued
@@ -1101,7 +1101,12 @@ def blockwise_save(backend: StorageBackend, lo: Sequence[int],
     the written block replaces any cached version, so read-after-write
     through the cache returns the written bytes even before the commit
     is durable). Unaligned writes fall back to one whole-range driver
-    write and *invalidate* every covered block instead.
+    write and *invalidate* every covered block instead, and so does an
+    aligned write with ``per_block=False``: for a box of many small
+    blocks (a thumbnail level: a thousand blocks of 7 KB) a future and a
+    cache copy a block cost more than the blocks, and the driver, which
+    is handed whole blocks and reads none back, splits the range on its
+    own threads.
 
     ``wait=True`` blocks until every block is durable (every future
     drained even when one fails; first exception wins). ``wait=False``
@@ -1119,9 +1124,10 @@ def blockwise_save(backend: StorageBackend, lo: Sequence[int],
         and _write_is_aligned(lo, hi, block, goff, dlo, dhi)
     )
     futures = []
-    with telemetry.span("storage/write",
-                        mode="aligned" if aligned else "unaligned"):
-        if aligned:
+    mode = ("unaligned" if not aligned
+            else "aligned" if per_block else "whole")
+    with telemetry.span("storage/write", mode=mode):
+        if mode == "aligned":
             zero_blocks = 0
             for blo, bhi in _covering_blocks(lo, hi, block, goff,
                                              dlo, dhi):
@@ -1152,7 +1158,8 @@ def blockwise_save(backend: StorageBackend, lo: Sequence[int],
                 for blo, _bhi in _covering_blocks(lo, hi, block, goff,
                                                   dlo, dhi):
                     cache.invalidate((backend.cache_token, blo))
-            telemetry.inc("storage/unaligned_writes")
+            telemetry.inc("storage/aligned_writes" if aligned
+                          else "storage/unaligned_writes")
         telemetry.inc("storage/bytes_written", arr.nbytes)
         gathered = GatherFuture(futures)
         if wait:

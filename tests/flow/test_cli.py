@@ -430,11 +430,16 @@ def test_save_precomputed_with_thumbnail_and_log(runner, tmp_path):
         str(root), volume_size=(8, 16, 16), dtype="uint8",
         voxel_size=(40, 4, 4), block_size=(8, 8, 8),
     )
+    # the sibling layer, as setup-env creates it beside the volume
+    PrecomputedVolume.create(
+        str(root / "thumbnail"), volume_size=(8, 16, 16), dtype="uint8",
+        voxel_size=(40, 4, 4), block_size=(8, 4, 4), num_mips=3,
+    )
     result = runner.invoke(main, [
         "generate-tasks", "-c", "8", "16", "16",
         "--roi-stop", "8", "16", "16",
         "create-chunk", "--size", "8", "16", "16", "--pattern", "sin",
-        "save-precomputed", "-v", str(root),
+        "save-precomputed", "-v", str(root), "--create-thumbnail",
     ])
     assert result.exit_code == 0, result.output
     log_dir = root / "log"
@@ -443,6 +448,40 @@ def test_save_precomputed_with_thumbnail_and_log(runner, tmp_path):
 
     record = json.loads(next(log_dir.iterdir()).read_text())
     assert "timer" in record and "compute_device" in record
+    # mips 1 and 2 of the thumbnail layer, none in the volume itself
+    layer = PrecomputedVolume(str(root)).thumbnail_layer()
+    keys = [scale["key"] for scale in layer.info["scales"]]
+    assert not (root / "thumbnail" / keys[0]).exists()
+    assert len(list((root / "thumbnail" / keys[1]).iterdir())) == 4
+    assert len(list((root / "thumbnail" / keys[2]).iterdir())) == 1
+    saved = np.asarray(PrecomputedVolume(str(root)).cutout(
+        layer.bounds(0)).array)
+    from chunkflow_tpu.core.bbox import BoundingBox
+
+    top = np.asarray(layer.cutout(
+        BoundingBox((0, 0, 0), (8, 4, 4)), mip=2, fill_missing=False).array)
+    mean = saved.reshape(8, 4, 4, 4, 4).mean(axis=(2, 4))
+    assert top.shape == (8, 4, 4) and np.abs(top - mean).max() <= 1.0
+
+
+def test_save_precomputed_thumbnail_needs_its_layer(runner, tmp_path):
+    """--create-thumbnail on a volume without the sibling layer is an
+    error that names setup-env, raised before any task runs."""
+    from chunkflow_tpu.volume.precomputed import PrecomputedVolume
+
+    root = tmp_path / "outvol"
+    PrecomputedVolume.create(
+        str(root), volume_size=(8, 16, 16), dtype="uint8",
+        voxel_size=(40, 4, 4), block_size=(8, 8, 8), num_mips=3,
+    )
+    result = runner.invoke(main, [
+        "create-chunk", "--size", "8", "16", "16", "--pattern", "sin",
+        "save-precomputed", "-v", str(root), "--create-thumbnail",
+    ])
+    assert result.exit_code != 0
+    assert isinstance(result.exception, FileNotFoundError)
+    assert "setup-env" in str(result.exception)
+    assert not (root / "log").exists()
 
 
 def test_inference_reference_migration_options(runner, tmp_path):
