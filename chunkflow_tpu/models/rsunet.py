@@ -33,6 +33,13 @@ PR 29).  A fold is inherited through a pool and never made by a reshape
 that splits the lanes, so levels 2 and 3 run unfolded.  The transitions
 are emitted folded as well (:class:`XFoldUp`, :func:`max_pool_folded`):
 behind a plain reshape the pool alone took a quarter of the chip's time.
+A pool takes its z and y maximum as one ``reduce_window`` on the folded
+array, in the tiles ``enc{i}/conv3`` wrote (both axes lie above the
+tiles: an elementwise maximum of rows), then the maximum of neighbouring
+positions inside the lanes (gauge ``forward/pool{i}_folded``); as a
+reshape and a ``max`` over the window axes XLA transposed y into the lanes
+and back, seven full-size passes between an encoder block's last
+convolution and the next level's first (PERF.md, PR 43).
 An up-sampling emits its rows from a convolution of the input dilated
 with zeros in y, one a z plane of its factor (``up0``: one, ``up1``: two,
 stacked on the major axis; gauge ``forward/up{i}_convolutions``), with the
@@ -273,14 +280,27 @@ class XFoldUp(nn.Module):
 
 def max_pool_folded(x, factor: Triple, fold: int):
     """``nn.max_pool(x, factor, strides=factor)`` of an array x-folded by
-    ``fold`` (a multiple of the x factor): a maximum over z and y pairs
-    and over neighbouring positions inside the lanes. The result is
-    x-folded by ``fold // fx``."""
+    ``fold`` (a multiple of the x factor): a maximum over the z and y
+    windows and over neighbouring positions inside the lanes. The result
+    is x-folded by ``fold // fx``.
+
+    The z and y windows lie above the tiles, so their maximum is a
+    ``reduce_window`` on the folded array: an elementwise maximum of fz*fy
+    rows a tile, which XLA emits as one fusion that reads the result of
+    ``enc{i}/conv3`` in the tiles it was written in and writes the same
+    tiles. Taken as a reshape to ``[..., z/fz, fz, y/fy, fy, ...]`` and a
+    ``max`` over the two window axes, XLA's layout for that reduce put y
+    in the lanes: the block's result left the convolution's tiles, was
+    transposed, reduced and transposed back for ``enc{i+1}/conv1``, seven
+    full-size passes where one does (PERF.md, PR 43). This window is not
+    the one PR 24 met (``nn.max_pool`` of the unfolded array: 28 channels
+    in the lanes, the window over x in the sublanes)."""
     fz, fy, fx = factor
-    b, z, y, xs, c = x.shape
-    # z and y windows lie above the lanes: halve the array there first
-    x = x.reshape(b, z // fz, fz, y // fy, fy, xs, c).max(axis=(2, 4))
-    c //= fold
+    c = x.shape[-1] // fold
+    # from -inf, as nn.max_pool starts: the identity XLA knows a maximum by
+    x = lax.reduce_window(x, np.array(-np.inf, x.dtype), lax.max,
+                          window_dimensions=(1, fz, fy, 1, 1),
+                          window_strides=(1, fz, fy, 1, 1), padding="VALID")
     lanes = [x[..., p * c:(p + 1) * c] for p in range(fold)]
     return jnp.concatenate(
         [functools.reduce(jnp.maximum, lanes[g * fx:(g + 1) * fx])
@@ -470,13 +490,15 @@ class RSUNet(nn.Module):
             x = block(i, f"enc{i}")(x)
             skips.append(x)
             factor = self.down_factors[i]
+            folded = folds[i] % factor[2] == 0
+            profiling.trace_gauge(f"forward/pool{i}_folded", int(folded))
             with jax.named_scope(f"pool{i}"):
-                if folds[i] % factor[2]:  # one window's positions, two blocks
-                    x = nn.max_pool(unfold_x(x, folds[i]),
-                                    window_shape=factor, strides=factor)
-                else:  # folded by folds[i] // fx: level i+1's fold, or more
+                if folded:  # by folds[i] // fx: level i+1's fold, or more
                     x = unfold_x(max_pool_folded(x, factor, folds[i]),
                                  folds[i] // factor[2] // folds[i + 1])
+                else:  # one window's positions lie in two blocks
+                    x = nn.max_pool(unfold_x(x, folds[i]),
+                                    window_shape=factor, strides=factor)
         x = block(depth - 1, "bridge")(x)
         for i in reversed(range(depth - 1)):
             factor = self.down_factors[i]

@@ -452,14 +452,10 @@ def test_upsampling_cut_by_its_box_is_the_upsampling_of_the_slice(factor,
             jax.make_jaxpr(up.apply)(params, folded).jaxpr)
 
 
-@pytest.mark.parametrize("config", ["rsunet-superhuman", "rsunet-deepem",
-                                    "rsunet-superhuman-prod"])
-def test_every_decoder_level_says_how_its_upsampling_is_emitted(config):
-    """``forward/up{i}_convolutions``, one gauge a decoder level, at the
-    widths and the (rehearsal) geometry of each benchmark configuration:
-    ``up0`` (1,2,2) is one dilated convolution, ``up1`` (2,2,2) two of
-    them stacked in z, ``up2`` below the folded levels flax's own
-    ConvTranspose."""
+def _gauges_of_config(config: str):
+    """The gauges the model's trace sets at the widths and the (rehearsal)
+    geometry of a benchmark configuration, the configuration's model
+    block and the x extent."""
     import json
 
     from chunkflow_tpu.core import telemetry
@@ -479,27 +475,87 @@ def test_every_decoder_level_says_how_its_upsampling_is_emitted(config):
     try:
         jax.eval_shape(lambda p, v: model.apply(
             p, v, output_patch_size=geometry.get("output_patch")), params, x)
-        gauges = telemetry.snapshot()["gauges"]
+        return telemetry.snapshot()["gauges"], spec, x.shape[3]
     finally:
         telemetry.reset()
+
+
+@pytest.mark.parametrize("config", ["rsunet-superhuman", "rsunet-deepem",
+                                    "rsunet-superhuman-prod"])
+def test_every_decoder_level_says_how_its_upsampling_is_emitted(config):
+    """``forward/up{i}_convolutions``, one gauge a decoder level, at the
+    widths and the (rehearsal) geometry of each benchmark configuration:
+    ``up0`` (1,2,2) is one dilated convolution, ``up1`` (2,2,2) two of
+    them stacked in z, ``up2`` below the folded levels flax's own
+    ConvTranspose."""
+    gauges, spec, x_extent = _gauges_of_config(config)
     assert [gauges[f"forward/up{i}_convolutions"]
             for i in range(len(spec["width"]) - 1)] == [1, 2, 1]
-    assert rsunet.level_folds(spec["width"], x.shape[3],
+    assert rsunet.level_folds(spec["width"], x_extent,
                               spec["pooling"])[2:] == [1, 1]
 
 
+@pytest.mark.parametrize("config", ["rsunet-superhuman", "rsunet-deepem"])
+def test_every_pool_says_whether_it_runs_on_the_folded_array(config):
+    """``forward/pool{i}_folded``, one gauge a pool: ``pool0`` and
+    ``pool1`` take their maximum on the x-folded array
+    (:func:`rsunet.max_pool_folded`), ``pool2``, between two unfolded
+    levels, is ``nn.max_pool``."""
+    gauges, spec, _ = _gauges_of_config(config)
+    assert [gauges[f"forward/pool{i}_folded"]
+            for i in range(len(spec["width"]) - 1)] == [1, 1, 0]
+
+
+# every (factor, fold) a configuration runs: (1,2,2) x 4 and x 8 (pool0),
+# (2,2,2) x 2 and x 4 (pool1), fold == fx; (2,2,1) hands the fold down whole
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("fold", [2, 4, 8])
 @pytest.mark.parametrize("factor", [(1, 2, 2), (2, 2, 2), (2, 2, 1)])
-def test_pool_of_a_folded_array_is_the_max_pool(factor, fold):
+def test_pool_of_a_folded_array_is_the_max_pool(factor, fold, dtype):
     """``max_pool_folded`` hands down the fold over the x factor: the
-    values are ``nn.max_pool``'s of the unfolded array, bit for bit."""
+    values are ``nn.max_pool``'s of the unfolded array, bit for bit, in
+    either compute dtype, where a window holds negative values only (the
+    window starts from -inf and not from 0) and where it holds
+    none."""
     x = jax.random.normal(jax.random.PRNGKey(7), (2, 4, 6, 32, 5))
+    x = x.at[0, :2, :2, :16].set(-1.0 - jnp.abs(x[0, :2, :2, :16]))
+    x = x.at[1, 2:, 4:, 16:].set(jnp.abs(x[1, 2:, 4:, 16:]))
+    x = x.astype(dtype)
     want = nn.max_pool(x, window_shape=factor, strides=factor)
+    assert (np.asarray(want[0, 0, 0, :8], np.float32) <= -1.0).all()
     got = rsunet.max_pool_folded(rsunet.fold_x(x, fold), factor, fold)
     handed = fold // factor[2]
+    assert got.dtype == dtype
     assert got.shape == rsunet.fold_x(want, handed).shape
     np.testing.assert_array_equal(
-        np.asarray(rsunet.unfold_x(got, handed)), np.asarray(want))
+        np.asarray(rsunet.unfold_x(got, handed), np.float32),
+        np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("factor,fold", [((1, 2, 2), 4), ((1, 2, 2), 8),
+                                         ((2, 2, 2), 2), ((2, 2, 2), 4)])
+def test_the_pool_takes_z_and_y_as_a_window_on_the_folded_array(factor, fold):
+    """The form of ``max_pool_folded``: one ``reduce_window_max`` over z
+    and y on the x-folded array, then the maximum inside the lanes. No
+    array of rank 7 (``[b, z/fz, fz, y/fy, fy, x, c]`` and a ``max`` over
+    the window axes put y in the lanes on the chip and cost seven
+    full-size passes between ``enc{i}/conv3`` and ``enc{i+1}/conv1``:
+    PERF.md, PR 43), no reduce and no transpose."""
+    x = jnp.zeros((2, 4, 8, 16 // fold, fold * 5), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(
+        lambda v: rsunet.max_pool_folded(v, factor, fold))(x).jaxpr
+    windows = [eqn for eqn in jaxpr.eqns
+               if eqn.primitive.name == "reduce_window_max"]
+    assert len(windows) == 1
+    window, = windows
+    assert window.invars[0] is jaxpr.invars[0]   # the folded array itself
+    assert window.params["window_dimensions"] == (1, *factor[:2], 1, 1)
+    assert window.params["window_strides"] == (1, *factor[:2], 1, 1)
+    assert all(pad == (0, 0) for pad in window.params["padding"])
+    assert {eqn.primitive.name for eqn in jaxpr.eqns} <= {
+        "reduce_window_max", "slice", "max", "concatenate"}
+    assert all(len(var.aval.shape) == 5
+               for eqn in jaxpr.eqns for var in eqn.outvars)
 
 
 @pytest.mark.parametrize("config", ["rsunet-superhuman", "rsunet-deepem",

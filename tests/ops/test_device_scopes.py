@@ -288,6 +288,15 @@ def test_an_upsamplings_convolutions_lie_under_its_own_part(
     assert [entry[f"up{i}_convolutions"] for i in range(3)] == [1, 2, 1]
 
 
+def test_the_pools_say_on_the_programs_entry_where_they_run(rsunet_program):
+    """``forward/pool{i}_folded`` lands in ``programs.json`` like
+    ``x_fold``: the two pools of the folded levels take their maximum on
+    the folded array, under their own part; the third unfolds nothing."""
+    entry, _ = rsunet_program
+    assert [entry[f"pool{i}_folded"] for i in range(3)] == [1, 1, 0]
+    assert {"pool0", "pool1", "pool2"} <= set(entry["op_parts"]["forward"])
+
+
 def test_a_fresh_executable_is_not_called_stale(rsunet_program):
     """`crop{i}` is one slice each, which XLA folds into its reader: the
     executable lacks the name and is still today's."""

@@ -3,6 +3,8 @@ text: the entry computation's ops with their cycles, shapes and flax
 parts; ``--by-module`` sums them by part (``core/profiling.py:op_parts``:
 a fusion by its widest convolution, not by its root) with the convolutions
 apart from the rest."""
+import re
+
 import pytest
 
 from tools import aot_cost
@@ -73,3 +75,83 @@ def test_by_module_keeps_convolutions_apart_from_the_rest(module, conv, rest):
     assert table[module] == [conv, rest]
     assert sum(map(sum, table.values())) == sum(
         op[0] for op in aot_cost.entry_ops(HLO))
+
+
+def test_an_op_with_several_results_is_counted():
+    """A multi-output fusion's shape is a tuple, ``(bf16[..]{..}, bf16[..]
+    {..})``, with blanks and tiles' parentheses inside: its cycles count
+    like any op's (until ISSUE 43 the tool dropped such ops, 7.6 M of
+    ``rsunet-superhuman``'s 127.9 M cycles, the pool's lane slices among
+    them)."""
+    line = (
+        '  %slice_maximum_fusion.1 = (bf16[20,128,32,9,28]{4,2,3,1,0:'
+        'T(8,128)(2,1)}, bf16[20,128,32,9,28]{4,2,3,1,0:T(8,128)(2,1)}) '
+        'fusion(%fusion.218), kind=kLoop, calls=%fused_computation.9, '
+        'metadata={op_name="jit(forward)/forward/RSUNet/pool0/slice"}, '
+        'backend_config={"window_config":{"estimated_cycles":"3399444"}}')
+    text = HLO.replace("  %convolution.3 = ", line + "\n  %convolution.3 = ")
+    (op,) = [op for op in aot_cost.entry_ops(text)
+             if op[1] == "slice_maximum_fusion.1"]
+    assert op[0] == 3399444 and op[2] == "fusion" and op[5] == "kLoop"
+    assert op[3].startswith("(bf16[20,128,32,9,28]") and op[3].endswith(")")
+    assert len(aot_cost.entry_ops(text)) == len(aot_cost.entry_ops(HLO)) + 1
+
+
+# ---------------------------------------------------------------------------
+# the compiled text of the way down (ISSUE 43): the chip's compiler for a
+# described v5e, no chip; about a quarter of a minute at the cut patch
+# ---------------------------------------------------------------------------
+CUT_PATCH = (20, 64, 256)   # the configuration's z and x tiles, a quarter of y
+
+
+@pytest.fixture(scope="module")
+def superhuman_text():
+    """The optimized HLO of ``rsunet-superhuman``'s forward, one cut patch
+    a program, compiled for one chip of a described v5e."""
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    config = dict(aot_cost.load_config("rsunet-superhuman"),
+                  patch=list(CUT_PATCH))
+    # an executable for a described chip can be written to the persistent
+    # cache and not read back: keep it out, or the next run warns
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return aot_cost.compile_forward(config, batch=1).as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+
+
+def test_the_pools_window_reads_the_convolution_where_it_wrote(
+        superhuman_text):
+    """``pool0``'s z and y maximum is one fusion whose operand is
+    ``enc0/conv3``'s own fusion: no copy, transpose or reduce between an
+    encoder block's last convolution and its pool (seven full-size passes
+    before ISSUE 43, y transposed into the lanes and back)."""
+    entry = superhuman_text[superhuman_text.index("\nENTRY "):]
+    windows = [line for line in entry.splitlines()
+               if "/pool0/reduce_window_max" in line and " fusion(" in line]
+    assert len(windows) == 1, windows
+    operands = re.search(r" fusion\(([^)]*)\)", windows[0]).group(1)
+    _, convolutions = aot_cost.part_of_ops(superhuman_text)
+    held = [convolutions.get(operand.strip().lstrip("%"))
+            for operand in operands.split(",")]
+    assert [["enc0/conv3", "3x3x3"]] in held, (operands, held)
+    assert "/pool0/reduce_max" not in entry
+
+
+def test_the_encoders_way_out_costs_what_the_decoders_does(superhuman_text):
+    """By module, ``enc0``'s ops that are no convolution cost under 1.5
+    times ``dec0``'s, the same three convolutions on arrays of the same
+    size (2.4 times with the reshape-and-``max`` pool, 4.2 at the whole
+    patch), and ``pool0`` less than either block's."""
+    table = aot_cost.by_module(superhuman_text)
+    assert table["enc0"][1] < 1.5 * table["dec0"][1], table
+    assert sum(table["pool0"]) < table["dec0"][1], table

@@ -36,7 +36,10 @@ from chunkflow_tpu.core import profiling  # noqa: E402
 CLOCK_HZ = 1.5e9  # the rate the totals are turned into ms with; a scale
 
 _ENTRY = re.compile(r"^ENTRY ")
-_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\(")
+# the shape is one token, or for an op with several results a tuple of them
+_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT )?%?([\w.\-]+) = (\((?:[^()]|\([^()]*\))*\)|\S+) "
+    r"([\w\-]+)\(")
 _CYCLES = re.compile(r'"estimated_cycles":"(\d+)"')
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _KIND = re.compile(r", kind=(k\w+),")
@@ -105,6 +108,15 @@ def by_module(hlo_text: str) -> dict:
     return table
 
 
+def load_config(config: str) -> dict:
+    """A benchmark configuration by its name under ``benchmarks/configs/``
+    or by the path of such a file."""
+    path = config if os.path.exists(config) else os.path.join(
+        CHECKOUT, "benchmarks", "configs", config + ".json")
+    with open(path) as f:
+        return json.load(f)
+
+
 def compile_forward(config: dict, batch: int):
     """The configuration's forward (``RSUNet.apply`` on one batch of
     patches, returning the configuration's output patch) compiled for one
@@ -156,12 +168,7 @@ def main(argv=None) -> int:
                         "apart from the rest, in place of the op list")
     parser.add_argument("--hlo", help="also write the optimized HLO here")
     args = parser.parse_args(argv)
-    path = args.config
-    if not os.path.exists(path):
-        path = os.path.join(CHECKOUT, "benchmarks", "configs",
-                            args.config + ".json")
-    with open(path) as f:
-        config = json.load(f)
+    config = load_config(args.config)
     compiled = compile_forward(config, args.batch or config["batch"])
     text = compiled.as_text()
     if args.hlo:
