@@ -13,6 +13,12 @@ v5e trace under ``tests/data`` is kept (``tests/record_trace.py`` wrote
 it). Every reduction below works on the tables, so the test on the
 recorded trace pins the same code the chip runs use.
 
+An op's category is first read from the event's text (``parse_op``).
+Where the run's programs say what is inside their ops (``programs.json``
+``op_convolutions``), ``file_by_contents`` then decides once, for every
+reducer, which ops are convolutions: by what a program lists under the
+op's name, not by how XLA happened to name or wrap it.
+
 Which planes are devices, and how ops are named on a v5e, is written up
 in PERF.md ("Reading a v5e trace").
 """
@@ -33,7 +39,11 @@ HOST_MIN_NS = 1_000_000
 # (kOutput) is what the TPU compiler makes of a convolution or dot with its
 # epilogue fused in, unless its name says it is rooted elsewhere
 # (reduce-window: the max-pool). Where an event is a bare op name, the name
-# rule of tools/analyze_trace.py applies. First match wins.
+# rule of tools/analyze_trace.py applies. First match wins. This is the
+# *text rule*: all there is for a run whose programs do not say what their
+# ops hold, and the first guess that ``file_by_contents`` corrects.
+CONVOLUTION = "convolution"
+OUTPUT_FUSION = "output fusion"
 _HLO = re.compile(r"^%?(?P<name>[^\s=]+) = (?P<rest>.*)$", re.S)
 _OPCODE = re.compile(r"\s([a-z][a-z0-9\-]*)\(")
 _KIND = re.compile(r"kind=(k\w+)")
@@ -76,14 +86,111 @@ def parse_op(text: str):
         kind = kind.group(1) if kind else ""
         if kind == "kOutput":
             if _NOT_CONV_ROOT.search(name):
-                return short, "output fusion"
-            return short, "convolution"
+                return short, OUTPUT_FUSION
+            return short, CONVOLUTION
         return short, _FUSION_KINDS.get(kind, "fusion")
     return short, opcode
 
 
 def is_collective(category: str) -> bool:
     return bool(_COLLECTIVE.search(category))
+
+
+# ---------------------------------------------------------------------------
+# what the programs say of their ops
+# ---------------------------------------------------------------------------
+def places_of_ops(programs: list):
+    """``{op name: {(scope, part), ...}}`` over every program's
+    ``op_parts``: one place where the programs agree. None if no program
+    has the map."""
+    names: dict = {}
+    found = False
+    for program in programs:
+        for scope, by_part in (program.get("op_parts") or {}).items():
+            found = True
+            for part, ops in by_part.items():
+                for op in ops:
+                    names.setdefault(op, set()).add((scope, part))
+    return names if found else None
+
+
+def convolutions_of_ops(programs: list):
+    """``{op name: [one list of [module path, window] a program that
+    knows the op, empty where that program lists no convolution in it]}``
+    over every program that carries ``op_convolutions``. A program knows
+    the ops it places (``op_scopes``, ``op_parts``) and those it lists a
+    convolution for. None if no program has the map (a program from
+    before PR 40, a recorded trace without ``programs.json``)."""
+    names: dict = {}
+    found = False
+    for program in programs:
+        held = program.get("op_convolutions")
+        if held is None:
+            continue
+        found = True
+        known = set(held)
+        for ops in (program.get("op_scopes") or {}).values():
+            known.update(ops)
+        for by_part in (program.get("op_parts") or {}).values():
+            for ops in by_part.values():
+                known.update(ops)
+        for op in known:
+            names.setdefault(op, []).append(held.get(op) or [])
+    return names if found else None
+
+
+def file_by_contents(tables: dict, programs: list) -> dict:
+    """``tables`` with every op's category decided by what the run's
+    programs say is inside it, for every reducer at once.
+
+    Where some program carries ``op_convolutions``: an op is a
+    ``convolution`` if and only if a program lists at least one
+    convolution under its name, whatever its opcode (a fusion of any
+    kind, a bare ``convolution``, a ``custom-call`` whose kernel the
+    program names as one); a ``kind=kOutput`` fusion for which no program
+    does (the pools' ``reduce_window`` on the folded array, PR 43) is an
+    ``output fusion``. Every other op keeps the category of its text, a
+    bare ``convolution`` instruction too: its text is the program's. A
+    name that one program lists a convolution for and another knows
+    without one keeps the text rule's answer and is counted.
+
+    Where no program carries the map the tables come back as they are:
+    the text rule stands and every reading is what it was.
+
+    The result has one more key, ``filing``: ``{"by": "contents"`` or
+    ``"text"``, ``"moved": {short name: [text's category, category]}``,
+    ``"ambiguous": [op names]}``.
+    """
+    held = convolutions_of_ops(programs)
+    if held is None:
+        return {**tables, "filing": {"by": "text", "moved": {},
+                                     "ambiguous": []}}
+    # per op name: True where every program that knows it lists a
+    # convolution, False where none does, None where they disagree
+    lists = {name: (any(said) if any(said) == all(said) else None)
+             for name, said in held.items()}
+    moved, ambiguous, devices = {}, set(), []
+    for device in tables["devices"]:
+        ops = []
+        for short, category, start, dur in device["ops"]:
+            name = short.split(" ", 1)[0]
+            listed = lists.get(name, False)
+            filed = category
+            if listed is None:
+                ambiguous.add(name)
+            elif listed:
+                filed = CONVOLUTION
+            elif category == CONVOLUTION and "fusion" in name:
+                # the text rule's guess at a kOutput fusion; a bare
+                # `convolution.3` is one by its opcode and stays
+                filed = OUTPUT_FUSION
+            if filed != category:
+                moved[short] = [category, filed]
+            ops.append([short, filed, start, dur])
+        devices.append({**device, "ops": ops})
+    return {**tables, "devices": devices,
+            "filing": {"by": "contents", "moved": moved,
+                       "ambiguous": sorted(ambiguous)}}
 
 
 # ---------------------------------------------------------------------------
@@ -258,13 +365,51 @@ def collective_share(tables: dict):
     return sum(shares) / len(shares) if shares else None
 
 
-def top_ops(tables: dict, n: int = 10) -> list:
+def filing_note(filing: dict, n: int = 8) -> str:
+    """One line for the run's notes: how the categories were decided,
+    which ops that moved and which names stayed ambiguous."""
+    moved = [f"{short}: {was} -> {now}"
+             for short, (was, now) in sorted(filing["moved"].items())]
+    more = f"; and {len(moved) - n} more" if len(moved) > n else ""
+    return (f"op categories by {filing['by']}: {len(moved)} moved"
+            + (f" ({'; '.join(moved[:n])}{more})" if moved else "")
+            + f", {len(filing['ambiguous'])} ambiguous"
+            + (f" ({' '.join(filing['ambiguous'][:n])})"
+               if filing["ambiguous"] else ""))
+
+
+def describe_ops(programs: list) -> dict:
+    """``{op name: "part path window path window ..."}`` for the ops the
+    programs place or list a convolution for: what is inside an op that
+    XLA names and shapes after its root (``fusion.1068
+    bf16[20,256,32,9,12]`` is ``dec0/conv3`` with the head as its
+    consumer: PERF.md, PR 38). The part only where the programs agree on
+    one; the convolutions of the first program that lists any."""
+    places = places_of_ops(programs) or {}
+    held = convolutions_of_ops(programs) or {}
+    out = {}
+    for op in set(places) | set(held):
+        parts = {part for _, part in places.get(op, ())}
+        listed = next((some for some in held.get(op, ()) if some), [])
+        words = [parts.pop() if len(parts) == 1 else ""] + [
+            f"{path} {window}".strip() for path, window in listed]
+        if any(words):
+            out[op] = " ".join(filter(None, words))
+    return out
+
+
+def top_ops(tables: dict, n: int = 10, programs: list = ()) -> list:
     """[name, seconds] of innermost-op time, summed over devices and
-    divided by their number: the ops that took most time on a chip."""
+    divided by their number: the ops that took most time on a chip, each
+    with its category and, where ``programs`` say so, its part and the
+    convolutions inside it (``fusion.1068 bf16[20,256,32,9,12]
+    [convolution] dec0 dec0/conv3 3x3x3 out 1x1x1``)."""
+    inside = describe_ops(programs)
     totals: dict = {}
     for device in tables["devices"]:
         for (name, category), seconds in _leaf_seconds(device).items():
-            key = f"{name} [{category}]"
+            key = " ".join(filter(None, (
+                f"{name} [{category}]", inside.get(name.split(" ", 1)[0]))))
             totals[key] = totals.get(key, 0.0) + seconds
     count = max(1, len(tables["devices"]))
     ranked = sorted(totals.items(), key=lambda kv: -kv[1])[:n]
@@ -298,8 +443,9 @@ def idle_gaps(tables: dict, n: int = 10) -> list:
     return [[name, ns / 1e9] for name, ns in ranked]
 
 
-def breakdown(tables: dict) -> dict:
-    return {"device_ops": top_ops(tables), "idle_gaps": idle_gaps(tables)}
+def breakdown(tables: dict, programs: list = ()) -> dict:
+    return {"device_ops": top_ops(tables, programs=programs),
+            "idle_gaps": idle_gaps(tables)}
 
 
 def cut(tables: dict, start_s: float, seconds: float) -> dict:

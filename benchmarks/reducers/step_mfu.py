@@ -4,7 +4,9 @@ trace.
 The operations the forwards that ran in the traced window need (forwards
 counted on the device: every convolution of the patch program runs once
 a forward, so the mean number of events an op name of the
-``convolution`` category has in the trace is the number of forwards,
+``convolution`` category has in the trace (an op in which a program
+lists a convolution: ``cfbench.trace.file_by_contents``) is the number
+of forwards,
 with the part of a forward that a window's edge cuts off counted by the
 share of its convolutions that lie inside; x the configuration's batch x
 its FLOPs per patch, from shapes: the whole patch's, as the forward's

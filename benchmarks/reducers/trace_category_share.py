@@ -1,5 +1,9 @@
-"""Share of device busy time in ops whose category (read from the op's
-text by ``cfbench.trace.parse_op``) matches ``pattern``, in percent."""
+"""Share of device busy time in ops whose category matches ``pattern``,
+in percent. The category is read from the op's text
+(``cfbench.trace.parse_op``) and, where the run's programs say what
+their ops hold, decided by that (``cfbench.trace.file_by_contents``,
+applied once by ``run.py``): an op is a ``convolution`` where a program
+lists one inside it."""
 from cfbench import trace
 
 

@@ -12,7 +12,9 @@ XLA names and shapes it: ``chunkflow_tpu/core/profiling.py``).
 
 The number: innermost-op seconds of the ops under ``scope`` whose part
 matches ``parts`` (a regular expression, matched against the whole part
-name) and whose category matches ``category`` and not ``not_category``,
+name) and whose category (``convolution`` where a program lists one
+inside the op, whatever its opcode: ``cfbench.trace.file_by_contents``)
+matches ``category`` and not ``not_category``,
 summed over the cell's devices, over the forwards the trace holds
 (``step_mfu.forwards_in``, the mean over the devices) times the
 configuration's batch. A unit of time and not a share of busy time, so
@@ -33,27 +35,12 @@ import statistics
 from cfbench import catalog, trace
 
 
-def places_of_ops(programs: list):
-    """``{op name: {(scope, part), ...}}`` over every program's
-    ``op_parts``: one place where the programs agree. None if no program
-    has the map."""
-    names: dict = {}
-    found = False
-    for program in programs:
-        for scope, by_part in (program.get("op_parts") or {}).items():
-            found = True
-            for part, ops in by_part.items():
-                for op in ops:
-                    names.setdefault(op, set()).add((scope, part))
-    return names if found else None
-
-
 def scope_seconds(record, scope: str):
     """``{(part, category): seconds}`` of innermost-op time under
     ``scope``, summed over the devices; the part is None for an op
     without one and for one the programs place differently. None if no
     program carries ``op_parts``."""
-    places = places_of_ops(record.programs)
+    places = trace.places_of_ops(record.programs)
     if places is None:
         return None
     out: dict = {}

@@ -239,8 +239,13 @@ def main() -> int:
             record.spans = program.read_spans(events)
             record.counters = program.read_counters(events)
             record.programs = program.read_programs(ctx.metrics_dir)
-            record.trace = trace.load_run_trace(ctx.trace_dir, chips)
-            breakdown = trace.breakdown(record.trace)
+            # one answer to "is this op a convolution" for every reducer:
+            # what the programs list inside it, where they say
+            record.trace = trace.file_by_contents(
+                trace.load_run_trace(ctx.trace_dir, chips), record.programs)
+            print(f"note: {trace.filing_note(record.trace['filing'])}",
+                  file=sys.stderr)
+            breakdown = trace.breakdown(record.trace, record.programs)
         group, directory = (("per_layer", "layer_metrics") if ctx.trace
                             else ("end_to_end", "end_to_end"))
         metrics = reduce_metrics(

@@ -74,6 +74,9 @@ def test_cell_rehearses(cell, is_parked, trace, request):
     assert done.returncode == 0, done.stderr[-2000:]
     line = json.loads(done.stdout.strip().splitlines()[-1])
     check_line(merged_bench(), cell, trace, line)
+    # a traced run says once how its ops' categories were decided: by
+    # what the programs list inside them (a CPU's programs carry the map)
+    assert ("note: op categories by contents" in done.stderr) == bool(trace)
     # the run's work directory is gone; only the rehearsals' cache stays
     assert set(os.listdir(work)) - before <= {"rehearsal-cache"}
 
