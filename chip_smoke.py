@@ -6,7 +6,9 @@ command line a user types (``chunkflow_tpu.flow.cli.main``), at the full
 width of the RSUNet (28, 36, 48, 64), and checks what comes out:
 
 * kernels    both Pallas kernels compiled by Mosaic at the production
-             patch, bitwise against their XLA legs
+             patch, bitwise against their XLA legs; the convolution
+             kernel (ops/pallas_conv.py) as one ``RSBlock`` of level 0 at
+             the anchor's and the production batch against XLA's block
 * host       ``native.build()`` from source on this machine
 * volume     a seeded uint8 volume as precomputed, ``generate-tasks``
              into a ``file://`` queue, then the README worker chain over
@@ -66,9 +68,15 @@ BF16_VS_F32_BOUND = 1.5e-2
 IDENTITY_BOUND = 2e-6
 
 FULL = dict(patch=(20, 256, 256), overlap=(4, 64, 64), margin=(2, 32, 32),
-            block=(16, 64, 64), serve_many=(36, 448, 256), serve_batch=2)
+            block=(16, 64, 64), serve_many=(36, 448, 256), serve_batch=2,
+            conv_batches=(4, 6), conv_patch=(20, 256, 256))
 TINY = dict(patch=(8, 32, 32), overlap=(2, 8, 8), margin=(1, 4, 4),
-            block=(6, 24, 24), serve_many=(14, 56, 32), serve_batch=2)
+            block=(6, 24, 24), serve_many=(14, 56, 32), serve_batch=2,
+            conv_batches=(2,), conv_patch=(3, 8, 64))
+# one RSBlock through the convolution kernel against XLA's, bfloat16,
+# activations of order one: a few steps of the result's rounding (the
+# kernel rounds its epilogue once where XLA's fusions may round more)
+BOUND_CONV_BLOCK = 0.125
 
 SUMMARY: dict = {"phases": {}}
 
@@ -277,6 +285,50 @@ def phase_kernels(cfg) -> None:
               f"XLA gather leg")
         print(f"gather_patches {patch} {np.dtype(dtype).name} "
               f"[{kernel_mode}]: bitwise equal to the XLA gather leg")
+
+
+def phase_convolution_kernel(cfg) -> None:
+    """The convolution kernel that builds the x halo in VMEM, compiled
+    (chip) or interpreted (rehearsal): one level-0 ``RSBlock`` of the
+    RSUNet's full width (28 channels x-folded by 4, bfloat16) at the
+    anchor's and the production batch, against the same block through
+    XLA on the same parameters."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from chunkflow_tpu.models import rsunet
+
+    rehearse = cfg["rehearse"]
+    width, fold = 28, 4
+    for batch in cfg["conv_batches"]:
+        z, y, x = cfg["conv_patch"]
+        shape = (batch, z, y, x // fold, fold * width)
+        check(rsunet.kernel_takes(fold, width, width, jnp.bfloat16,
+                                  shape[1:4], "tpu"),
+              f"the rule declines the level-0 block at {shape}")
+        blocks = {kernel: rsunet.RSBlock(
+            width, dtype=jnp.bfloat16, fold=fold, kernel=kernel,
+            interpret=rehearse) for kernel in (False, True)}
+        data = jax.random.normal(
+            jax.random.PRNGKey(batch), shape, jnp.float32).astype(jnp.bfloat16)
+        params = jax.jit(blocks[False].init)(
+            jax.random.PRNGKey(0), data[:1, :2, :4])
+        run = {kernel: jax.jit(block.apply)
+               for kernel, block in blocks.items()}
+        if not rehearse:
+            text = run[True].lower(params, data).as_text()
+            check(text.count("tpu_custom_call") >= 3,
+                  "the block's convolutions lowered without Mosaic calls")
+        want, got = (np.asarray(run[kernel](params, data), np.float32)
+                     for kernel in (False, True))
+        diff = float(np.abs(got - want).max())
+        check(np.isfinite(got).all() and diff <= BOUND_CONV_BLOCK,
+              f"RSBlock through the kernel differs from XLA's by {diff}")
+        print(f"folded_conv RSBlock {shape} bfloat16 "
+              f"[{'interpret' if rehearse else 'on'}]: max-abs-diff "
+              f"{diff:.4g} from XLA's block (values to "
+              f"{float(np.abs(want).max()):.3g})")
 
 
 def phase_host_build() -> None:
@@ -769,6 +821,7 @@ def main() -> int:
     try:
         with phase("kernels: Pallas, compiled, bitwise vs XLA"):
             phase_kernels(cfg)
+            phase_convolution_kernel(cfg)
         with phase("host: native.build() from source"):
             phase_host_build()
         with phase("volume: seeded image as precomputed"):

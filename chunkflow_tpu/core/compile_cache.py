@@ -21,6 +21,15 @@ Two tiers, attacking two different retrace costs:
    never retrace. ``builds``/``hits`` counters make trace counts a
    testable invariant (tests/inference/test_compile_cache.py).
 
+3. **Lowered kernels** (:func:`lowered_once`): a Pallas kernel is traced
+   to a jaxpr and lowered to Mosaic's MLIR in Python by every process
+   whose program holds it, before jax can even ask the persistent cache
+   for the executable (tens of ms a distinct kernel; 4 s of a warm start
+   for the RSUNet's, PERF.md, PR 47). The lowered function is kept beside
+   the executables (``<cache dir>/lowered/``) as ``jax.export`` writes
+   it, keyed on everything it was lowered from, and a later process
+   reads it back in a millisecond.
+
 Donation note: programs cached here donate their chunk buffer
 (``donate_argnums=(0,)``, GL005) — see docs/performance.md for the
 buffer-lifetime contract. When XLA cannot alias the donated input to the
@@ -93,6 +102,84 @@ def enable_persistent_cache() -> Optional[str]:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
         _PERSISTENT_DIR = jax.config.jax_compilation_cache_dir
     return _PERSISTENT_DIR
+
+
+_LOWERED: dict = {}  # key -> the lowered function's call, this process
+
+
+def _source_digest(fn) -> str:
+    """Of the file that defines ``fn``: a kernel edited is another key."""
+    import hashlib
+    import inspect
+
+    with open(inspect.getsourcefile(fn), "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def lowered_once(fn, static: dict, *args, platform: Optional[str] = "tpu"):
+    """``fn(*args, **static)``, lowered for ``platform`` once per cache
+    directory and not once per process: the calls of one program that
+    agree in key are one function of it (a ``jit`` of their own), and the
+    function's lowering is read from ``<cache dir>/lowered/<key>`` where
+    an earlier process left it (``jax.export``; written through a
+    temporary name behind its own digest: half a file, or another's, is
+    no entry and is lowered again). The key is what
+    the lowering is made from: the function's name and its file's digest,
+    jax's and jaxlib's versions, the platform, ``static`` (everything the
+    function reads that is no array belongs there) and the arrays' tree,
+    shapes and dtypes. Without a cache directory (jax's own,
+    ``jax_compilation_cache_dir``) or a ``platform`` (an interpreted
+    kernel is the process's own backend's) the function is lowered in
+    place as any other."""
+    import hashlib
+
+    import jax
+    import jaxlib
+    from jax import export
+
+    leaves, tree = jax.tree_util.tree_flatten(args)
+    key = hashlib.sha256(repr((
+        fn.__module__, fn.__qualname__, _source_digest(fn), jax.__version__,
+        jaxlib.__version__, platform, sorted(static.items()), str(tree),
+        [(leaf.shape, str(leaf.dtype)) for leaf in leaves])).encode()
+    ).hexdigest()
+    with _LOCK:
+        call = _LOWERED.get(key)
+    if call is None:
+        directory = jax.config.jax_compilation_cache_dir
+        def lowered(*arrays):
+            return fn(*arrays, **static)
+
+        # the kernel's instruction is named after the innermost jit
+        lowered.__name__ = lowered.__qualname__ = fn.__name__.lstrip("_")
+        one = jax.jit(lowered)
+        if directory and platform:
+            path = os.path.join(directory, "lowered", key)
+            try:
+                with open(path, "rb") as f:
+                    digest, kept = f.read(32), f.read()
+                if hashlib.sha256(kept).digest() != digest:
+                    raise ValueError(f"{path}: not what was written")
+                exported = export.deserialize(bytearray(kept))
+                telemetry.inc("compile_cache/lowered_hits")
+            except (OSError, ValueError):
+                exported = export.export(one, platforms=(platform,))(
+                    *jax.tree_util.tree_map(
+                        lambda leaf: jax.ShapeDtypeStruct(leaf.shape,
+                                                          leaf.dtype), args))
+                telemetry.inc("compile_cache/lowered_builds")
+                try:
+                    os.makedirs(os.path.dirname(path), exist_ok=True)
+                    kept = bytes(exported.serialize())
+                    with open(f"{path}.{os.getpid()}", "wb") as f:
+                        f.write(hashlib.sha256(kept).digest() + kept)
+                    os.replace(f.name, path)
+                except OSError:
+                    pass  # a cache that cannot be written is no cache
+            one = jax.jit(exported.call)
+        with _LOCK:
+            call = _LOWERED.setdefault(key, one)
+    return call(*args)
 
 
 class ProgramCache:

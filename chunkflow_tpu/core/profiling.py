@@ -297,15 +297,63 @@ def _op_scopes(computations: dict, entry: Optional[str]) -> dict:
     return out
 
 
+KERNEL_MARKER = "kernel_convolution_"
+# the marker scope on a kernel's path: /kernel_convolution_3x3x3/, with
+# behind two dots the name of a module below the scope's root for one
+# more convolution the kernel holds
+_KERNEL_SCOPE = re.compile(
+    "/" + KERNEL_MARKER + r"([0-9]+(?:x[0-9]+)*)(?:\.\.(\w+))?(?=/)")
+
+
+def kernel_convolution(window, module: str = ""):
+    """The scope a kernel's wrapper opens around its ``pallas_call``,
+    inside the module's own, to say that the custom call is a convolution
+    of that window (``kernel_convolution((3, 3, 3))`` ->
+    ``kernel_convolution_3x3x3``): :func:`op_parts` then lists the call in
+    ``op_convolutions`` under the module's path, and the benchmark files
+    its time as a convolution's (``benchmarks/cfbench/trace.py``
+    ``file_by_contents``). A kernel that holds one more convolution opens
+    one more scope, with the name of that convolution's ``module`` below
+    the scope's root module (``kernel_convolution((1, 1, 1), "out")`` ->
+    ``kernel_convolution_1x1x1..out``: ``RSUNet/out``, from inside
+    ``RSUNet/dec0/conv3``). Metadata only."""
+    import jax
+
+    mark = KERNEL_MARKER + "x".join(str(int(k)) for k in window)
+    return jax.named_scope(f"{mark}..{module}" if module else mark)
+
+
+def _kernel_convolutions(op_name: str) -> list:
+    """``[(op_name, window)]`` that the marker scopes on a custom call's
+    path name (:func:`kernel_convolution`): the path down to the
+    innermost marker without the markers, then the primitive (what lies
+    between them is the wrapper's own: ``jit(folded_conv)``); none where
+    the path has no marker."""
+    marks = list(_KERNEL_SCOPE.finditer(op_name))
+    if not marks:
+        return []
+    primitive = op_name.rpartition("/")[2]
+    above = _KERNEL_SCOPE.sub("", op_name[:marks[-1].end()] + "/")[:-1]
+    path = above.split("/")
+    root = next((i + 2 for i, name in enumerate(path)
+                 if name in DEVICE_SCOPES), 0)
+    return [("/".join(path[:root] + [mark[2], primitive]) if mark[2]
+             else f"{above}/{primitive}", mark[1]) for mark in marks]
+
+
 def _taps(window: str) -> int:
     return math.prod(map(int, window.split("x")))
 
 
 def _convolutions(computations: dict, op: _HloOp) -> list:
     """``[(op_name, window)]`` of the convolutions ``op`` holds: its own,
-    or those of a fusion's computations, nested fusions included; the one
+    or those of a fusion's computations, nested fusions included, or
+    those a kernel's ``custom-call`` names by its marker scopes
+    (:func:`kernel_convolution`; one without a marker holds none); the one
     with the most taps first (of equals, the first in program order)."""
     found = []
+    if op.opcode == "custom-call" and op.op_name:
+        found = _kernel_convolutions(op.op_name)
     pending, seen = [op], set()
     for one in pending:   # grows while it is walked
         if one.opcode == "convolution" and one.window:

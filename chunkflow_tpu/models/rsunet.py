@@ -48,6 +48,17 @@ the bias and the skip sum in its epilogue; stacked from one 1x1x1
 convolution a row and reshaped, the interleave, the skip sum and the
 copies between them were nine full-size passes around ``up0`` for an
 array that is written once (PERF.md, PR 41).
+On a TPU the folded levels' blocks do not run XLA's block-banded
+convolution at all: of its three block taps in x the two outer ones hold
+one position's weights each, and XLA issues a weight tile for each
+(27 MXU passes a row for 3x3x3, three quarters of the products zeros).
+Where :func:`kernel_takes` says so from the block's shapes, dtype and
+the backend, an ``RSBlock`` is three calls of a kernel that builds the
+x halo in VMEM (ops/pallas_conv.py: 18 passes), each with its batch
+norm, ReLU and residual as the epilogue; the embedding in front of
+``enc0`` and the head behind ``dec0`` ride in the same kernel, because
+XLA's own convolutions beside a custom call pay a relayout (gauge
+``forward/kernel_convolutions``; PERF.md, PR 47).
 Norm is folded to a per-channel affine (no
 batch statistics at inference), compute is optionally bfloat16 with
 float32 params; the final activation is computed in the output's dtype.
@@ -66,9 +77,10 @@ one it was.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
-from typing import Optional, Sequence, Tuple
+from typing import NamedTuple, Optional, Sequence, Tuple
 
 import flax.linen as nn
 import jax
@@ -78,11 +90,12 @@ from flax.linen.dtypes import promote_dtype
 from jax import lax
 
 from chunkflow_tpu.core import profiling
+from chunkflow_tpu.ops import pallas_conv
 
 Triple = Tuple[int, int, int]
 Box = Tuple[Tuple[int, int], ...]  # (lo, hi) on z, y, x, in a level's voxels
 
-LANES = 128  # the minor dimension of a TPU vreg, VMEM tile and MXU pass
+LANES = pallas_conv.LANES  # of a TPU vreg, a VMEM tile and an MXU pass
 EMBED_KERNEL = (1, 5, 5)
 BLOCK_KERNELS = ((1, 3, 3), (3, 3, 3), (3, 3, 3))  # RSBlock's conv1-3
 # how far a voxel of an RSBlock's result reads into its input, per axis
@@ -157,19 +170,95 @@ def fold_kernel(kernel, fold: int):
     return folded.reshape(kz, ky, taps, fold * cin, fold * cout), (t_lo, t_hi)
 
 
+def fold_head(kernel, bias, fold: int, dtype):
+    """A 1x1x1 convolution's parameters (``[1,1,1,Cin,Cout]``, ``[Cout]``)
+    as the folded array's: ``([F*Cin, F*Cout], [F*Cout])`` in the compute
+    dtype, for the kernel of the block whose result the convolution alone
+    reads."""
+    folded, _ = fold_kernel(kernel.astype(dtype), fold)
+    return folded[0, 0, 0], jnp.tile(bias.astype(dtype), fold)
+
+
+def kernel_takes(fold: int, channels: int, features: int, dtype,
+                 extents: Triple, backend: str) -> bool:
+    """Whether an ``RSBlock`` whose input is ``[B, *extents, fold*channels]``
+    (z, y and x blocks) runs its three convolutions as the kernel that
+    builds the x halo in VMEM (ops/pallas_conv.py): a rule on what the
+    code sees, with no option beside it.
+
+    - a TPU ``backend`` (the platform the program is lowered for): the
+      kernel is Mosaic's; anywhere else the block is XLA's as it was;
+    - a fold of at least 2 (a neighbour's position lies in another block)
+      and the folded lanes of input and result within one MXU tile: one
+      centre pass and one halo pass a (kz, ky);
+    - x blocks a multiple of the operands' sublane tile (16: they are
+      bfloat16 whatever the activations are, two x blocks to a packed
+      word), so that a plane's rows are matmul rows with no relayout (the
+      production cone's ``dec0`` has 50, its ``dec1`` 54: XLA's);
+    - the planes a call holds at once within what it may take of the
+      core's VMEM (``pallas_conv.vmem_bytes``: 45 of 100 MiB at
+      256x256 on the v5e; a 512x512 patch would ask for 165: XLA's);
+    - bfloat16 or float32 activations: both levels of both published
+      widths read faster through the kernel on the v5e, one
+      ``engine.apply`` a program 64.0 -> 52.2 ms (28/36/48/64, batch 4),
+      41.9 -> 36.3 (16/32/64/128, float32), 93.4 -> 80.3 (batch 6 with a
+      16x192x192 output patch); with level 0's blocks alone and XLA's
+      embedding 59.7 / 37.5 / 83.0 against 53.8 / 37.1 / 79.3 with both
+      levels' (PERF.md, PR 47). The whole block or none of it: with ``conv1``
+      left to XLA the first program read 61.9, a relayout either side of
+      it."""
+    _, ys, blocks = extents
+    lanes = fold * max(channels, features)
+    return (backend == "tpu" and fold >= 2 and lanes <= LANES
+            and blocks % pallas_conv.OPERAND_SUBLANES == 0
+            and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
+                                     jnp.dtype(jnp.float32))
+            and pallas_conv.vmem_bytes(BLOCK_KERNELS[-1][:2], (ys, blocks),
+                                       lanes, dtype)
+            <= pallas_conv.vmem_limit_bytes())
+
+
+class Head(NamedTuple):
+    """A 1x1x1 convolution that reads a block's result and nothing else
+    does (:func:`fold_head`), and its module's name below the model
+    (``out``)."""
+    kernel: jax.Array
+    bias: jax.Array
+    name: str
+
+
+class Epilogue(NamedTuple):
+    """What follows a convolution inside an ``RSBlock``, for the kernel
+    that takes it along: the folded batch norm's terms (tiled over the
+    fold, in the compute dtype), the residual or None, then the ReLU, then
+    the :class:`Head` or None."""
+    scale: Optional[jax.Array] = None
+    shift: Optional[jax.Array] = None
+    residual: Optional[jax.Array] = None
+    head: Optional[Head] = None
+    relu: bool = True
+
+
 class XFoldConv(nn.Module):
     """``nn.Conv(features, kernel_size, padding='SAME')`` with the same
     parameter tree, on an array whose channels hold ``fold`` adjacent x
     positions. Rounds where nn.Conv does: the convolution's result in the
-    compute dtype, then the bias."""
+    compute dtype, then the bias.
+
+    With an ``epilogue`` (``RSBlock``, where :func:`kernel_takes` says so)
+    the convolution, the bias and the epilogue are one call of the kernel
+    that builds the x halo in VMEM (ops/pallas_conv.py), and the result is
+    the block's next array (``interpret``: the kernel interpreted, for
+    tests)."""
 
     features: int
     kernel_size: Triple
     dtype: jnp.dtype = jnp.float32
     fold: int = 1
+    interpret: bool = False
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, epilogue: Optional[Epilogue] = None):
         kz, ky, _ = self.kernel_size
         kernel = self.param(
             "kernel", nn.initializers.lecun_normal(),
@@ -179,6 +268,20 @@ class XFoldConv(nn.Module):
                           (self.features,))
         x, kernel, bias = promote_dtype(x, kernel, bias, dtype=self.dtype)
         kernel, x_pad = fold_kernel(kernel, self.fold)
+        if epilogue is not None:
+            # the lanes of a neighbouring block that the x taps reach
+            reach = self.kernel_size[2] // 2 * (x.shape[-1] // self.fold)
+            centre, halo = pallas_conv.halo_kernels(kernel, reach)
+            head = epilogue.head
+            # this convolution's marker innermost: Mosaic names the call
+            with profiling.kernel_convolution((1, 1, 1), head.name) \
+                    if head else contextlib.nullcontext(), \
+                    profiling.kernel_convolution(self.kernel_size):
+                return pallas_conv.folded_conv(
+                    x, centre, halo, jnp.tile(bias, self.fold),
+                    epilogue.scale, epilogue.shift, epilogue.residual,
+                    relu=epilogue.relu, head=head and head[:2],
+                    channels=reach, interpret=self.interpret)
         y = lax.conv_general_dilated(
             x, kernel, window_strides=(1, 1, 1),
             padding=(((kz - 1) // 2, kz // 2), ((ky - 1) // 2, ky // 2),
@@ -315,35 +418,60 @@ class Affine(nn.Module):
     dtype: jnp.dtype = jnp.float32
     fold: int = 1
 
-    @nn.compact
+    def setup(self):
+        self.scale = self.param("scale", nn.initializers.ones,
+                                (self.features,))
+        self.bias = self.param("bias", nn.initializers.zeros,
+                               (self.features,))
+
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (self.features,))
-        bias = self.param("bias", nn.initializers.zeros, (self.features,))
-        scale = jnp.tile(scale.astype(self.dtype), self.fold)
-        bias = jnp.tile(bias.astype(self.dtype), self.fold)
+        scale, bias = affine_terms(self)
         return x * scale + bias
+
+
+def affine_terms(bn: Affine):
+    """``(scale, bias)`` of an :class:`Affine` as it applies them: in its
+    dtype, tiled over its fold."""
+    return (jnp.tile(bn.scale.astype(bn.dtype), bn.fold),
+            jnp.tile(bn.bias.astype(bn.dtype), bn.fold))
 
 
 class RSBlock(nn.Module):
     """Residual block: conv1(1,3,3) -> conv2(3,3,3) -> conv3(3,3,3), each
     conv -> bn -> relu, with the residual taken after conv1 (the
-    superhuman-RSUNet shape). ``fold``: the x-fold of its input."""
+    superhuman-RSUNet shape). ``fold``: the x-fold of its input.
+    ``kernel``: its convolutions take the halo-in-VMEM kernel with their
+    batch norm, ReLU and the residual as its epilogue
+    (:func:`kernel_takes`; ``interpret``: interpreted, for tests)."""
 
     features: int
     dtype: jnp.dtype = jnp.float32
     fold: int = 1
+    kernel: bool = False
+    interpret: bool = False
 
     def setup(self):
         f, dt, fold = self.features, self.dtype, self.fold
         k1, k2, k3 = BLOCK_KERNELS
-        self.conv1 = XFoldConv(f, k1, dtype=dt, fold=fold)
+        conv = functools.partial(XFoldConv, dtype=dt, fold=fold,
+                                 interpret=self.interpret)
+        self.conv1 = conv(f, k1)
         self.bn1 = Affine(f, dtype=dt, fold=fold)
-        self.conv2 = XFoldConv(f, k2, dtype=dt, fold=fold)
+        self.conv2 = conv(f, k2)
         self.bn2 = Affine(f, dtype=dt, fold=fold)
-        self.conv3 = XFoldConv(f, k3, dtype=dt, fold=fold)
+        self.conv3 = conv(f, k3)
         self.bn3 = Affine(f, dtype=dt, fold=fold)
 
-    def __call__(self, x):
+    def __call__(self, x, head: Optional[Head] = None):
+        """``head`` (with ``kernel`` only): the 1x1x1 convolution that
+        alone reads this block's result rides in ``conv3``'s kernel, and
+        what is returned is the head's result."""
+        if self.kernel:
+            def after(bn, residual=None, head=None):
+                return Epilogue(*affine_terms(bn), residual, head)
+            residual = self.conv1(x, after(self.bn1))
+            x = self.conv2(residual, after(self.bn2))
+            return self.conv3(x, after(self.bn3, residual, head))
         x = nn.relu(self.bn1(self.conv1(x)))
         residual = x
         x = nn.relu(self.bn2(self.conv2(x)))
@@ -442,6 +570,10 @@ class RSUNet(nn.Module):
     down_factors: Sequence[Triple] = ((1, 2, 2), (2, 2, 2), (2, 2, 2))
     dtype: jnp.dtype = jnp.float32
     final_activation: str = "sigmoid"
+    # what the program is lowered for, where that is not the process's
+    # default backend (tools/aot_cost.py: a described chip from a CPU host)
+    platform: Optional[str] = None
+    interpret: bool = False  # tests: the rule as on a TPU, the kernel interpreted
 
     @nn.compact
     def __call__(self, x, output_patch_size=None):
@@ -473,9 +605,29 @@ class RSUNet(nn.Module):
                             BLOCK_HALO)
         self._trace_cone_gauges(shapes, cone)
 
-        def block(i, name):
-            return RSBlock(self.width[i], dtype=dt, fold=folds[i],
-                           name=name)
+        kernels = []  # one an RSBlock: whether it took the kernel
+
+        # while the parameters are initialised the forward is traced for
+        # its shapes alone: no kernel to trace and lower (a second apiece
+        # for the fourteen of a program) for XLA to drop
+        backend = "none" if self.is_initializing() else self.platform or (
+            "tpu" if self.interpret else jax.default_backend())
+
+        def block(i, name, x, head: str = ""):
+            """``head``: the name of the 1x1x1 convolution that alone
+            reads the block's result; it rides in the block's kernel
+            where there is one (the second result says so)."""
+            kernels.append(i < FOLDED_LEVELS and kernel_takes(
+                folds[i], x.shape[-1] // folds[i], self.width[i], dt,
+                x.shape[1:4], backend))
+            run = RSBlock(self.width[i], dtype=dt, fold=folds[i],
+                          kernel=kernels[-1], interpret=self.interpret,
+                          name=name)
+            if head and kernels[-1]:
+                its = self.variables["params"][head]
+                return run(x, Head(*fold_head(
+                    its["kernel"], its["bias"], folds[i], dt), head)), True
+            return run(x), False
 
         # what this method emits itself goes under a name of its own, as a
         # flax module's ops go under the module's: the device trace is split
@@ -483,11 +635,20 @@ class RSUNet(nn.Module):
         orig_dtype = x.dtype
         with jax.named_scope("in"):
             x = fold_x(x.astype(dt), fold)
-        x = XFoldConv(self.width[0], EMBED_KERNEL, dtype=dt, fold=fold,
-                      name="embed")(x)
+        embed = XFoldConv(self.width[0], EMBED_KERNEL, dtype=dt, fold=fold,
+                          interpret=self.interpret, name="embed")
+        if kernel_takes(
+                fold, self.width[0], self.width[0], dt,
+                (*x.shape[1:3], x.shape[3]), backend):
+            # enc0 takes the kernel: what feeds it comes in plain tiles
+            x = embed(x, Epilogue(relu=False))
+            embedded = True
+        else:
+            x = embed(x)
+            embedded = False
         skips = []
         for i in range(depth - 1):
-            x = block(i, f"enc{i}")(x)
+            x, _ = block(i, f"enc{i}", x)
             skips.append(x)
             factor = self.down_factors[i]
             folded = folds[i] % factor[2] == 0
@@ -499,7 +660,8 @@ class RSUNet(nn.Module):
                 else:  # one window's positions lie in two blocks
                     x = nn.max_pool(unfold_x(x, folds[i]),
                                     window_shape=factor, strides=factor)
-        x = block(depth - 1, "bridge")(x)
+        x, _ = block(depth - 1, "bridge", x)
+        headed = False
         for i in reversed(range(depth - 1)):
             factor = self.down_factors[i]
             box, _ = cone[i]
@@ -520,12 +682,18 @@ class RSUNet(nn.Module):
             profiling.trace_gauge(f"forward/up{i}_convolutions", emitted)
             with jax.named_scope(f"skip{i}"):
                 x = x + _crop(skips[i], box, _whole(shapes[i]), folds[i])
-            x = block(i, f"dec{i}")(x)
+            # the head reads dec0's result and nothing else does: where
+            # dec0 takes the kernel it rides in conv3's, before the crop
+            x, headed = block(i, f"dec{i}", x, "" if i else "out")
+        profiling.trace_gauge("forward/kernel_convolutions",
+                              len(BLOCK_KERNELS) * sum(kernels) + headed
+                              + embedded)
         held, want = cone[0]
         with jax.named_scope("crop0"):
             x = _crop(x, want, held, fold)
-        x = XFoldConv(self.out_channels, (1, 1, 1), dtype=dt, fold=fold,
-                      name="out")(x)
+        if not headed:
+            x = XFoldConv(self.out_channels, (1, 1, 1), dtype=dt, fold=fold,
+                          name="out")(x)
         # the activation in the output's dtype: what the chip computed all
         # along while head, sigmoid and cast were one fusion (XLA keeps
         # excess precision inside one), now that a copy lies between them

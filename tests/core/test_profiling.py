@@ -652,6 +652,90 @@ def test_op_parts_puts_a_dilated_upsampling_with_its_skip_sum_under_up():
         "select_bitcast_fusion.1"]
 
 
+# What the TPU compiler makes of a Pallas kernel (PERF.md, PR 47; cut from
+# the v5e's text for `rsunet-superhuman`): a `custom-call` named after the
+# innermost scope on its path, the kernel's own `jit`, whose path carries
+# above that the marker scope its wrapper opened (core/profiling.py
+# `kernel_convolution`).
+_KERNEL_HLO = """\
+HloModule jit_program, is_scheduled=true
+
+ENTRY %main.3 (x: bf16[4,20,256,64,112], w: bf16[3,3,112,112]) -> bf16[4,20,256,64,112] {
+  %x = bf16[4,20,256,64,112]{4,3,2,1,0} parameter(0)
+  %w = bf16[3,3,112,112]{3,2,1,0} parameter(1)
+  %copy.7 = bf16[3,3,112,112]{3,2,1,0} copy(%w)
+  %folded_conv.8 = bf16[4,20,256,64,112]{4,3,2,1,0:T(8,128)(2,1)} custom-call(%x, %x, %x, %copy.7), custom_call_target="tpu_custom_call", operand_layout_constraints={bf16[4,20,256,64,112]{4,3,2,1,0}}, backend_config={"custom_call_config": {"body": "TUxJUg=="}, "metadata={}"}, metadata={op_name="jit(program)/forward/RSUNet/enc0/conv2/kernel_convolution_3x3x3/jit(folded_conv)/pallas_call" stack_frame_id=87}
+  %pool0.1 = bf16[4,20,256,64,112]{4,3,2,1,0:T(8,128)(2,1)} custom-call(%folded_conv.8), custom_call_target="tpu_custom_call", metadata={op_name="jit(program)/forward/RSUNet/pool0/pallas_call"}
+  %folded_conv.2 = bf16[4,20,256,64,12]{4,3,2,1,0:T(8,128)(2,1)} custom-call(%pool0.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(program)/forward/RSUNet/dec0/conv3/kernel_convolution_1x1x1..out/kernel_convolution_3x3x3/jit(folded_conv)/pallas_call"}
+  %custom-call.4 = bf16[4,20,256,64,112]{4,3,2,1,0} custom-call(%folded_conv.2), custom_call_target="ConcatBitcast"
+  ROOT %custom-call.5 = s32[1024]{0} custom-call(%custom-call.4), custom_call_target="AssumeGatherIndicesInBound", metadata={op_name="jit(program)/forward/RSUNet/embed/jit(_take)/gather"}
+}
+"""
+
+
+def test_op_parts_lists_a_kernel_its_marker_scope_names():
+    """A `custom-call` holds no convolution instruction: what its marker
+    scope says is listed under its instruction name with the module's
+    path (the kernel's own `jit` below the marker left off) and the
+    window, and its part comes from its own path (rule (b))."""
+    parts, convolutions = profiling.op_parts(_KERNEL_HLO)
+    assert convolutions["folded_conv.8"] == [["enc0/conv2", "3x3x3"]]
+    assert "folded_conv.8" in parts["forward"]["enc0"]
+    # the weights' copy and XLA's own call have no metadata: the part of
+    # their reader (rule (d)), under no scope
+    assert parts[""] == {"enc0": ["copy.7"], "embed": ["custom-call.4"]}
+    assert profiling.op_scopes(_KERNEL_HLO)["forward"] == [
+        "folded_conv.8", "pool0.1", "folded_conv.2", "custom-call.5"]
+
+
+def test_op_parts_lists_an_unmarked_kernel_nowhere():
+    """A kernel without the marker, and XLA's own custom calls with and
+    without metadata, hold no convolution; each has the part its own
+    ``op_name`` gives it."""
+    parts, convolutions = profiling.op_parts(_KERNEL_HLO)
+    assert not {"pool0.1", "custom-call.4", "custom-call.5"} \
+        & set(convolutions)
+    assert parts["forward"]["pool0"] == ["pool0.1"]
+    assert parts["forward"]["embed"] == ["custom-call.5"]
+
+
+def test_op_parts_lists_every_convolution_a_kernel_holds():
+    """One scope a convolution: the module's own, and behind two dots
+    the name of the module below the scope's root that the kernel takes
+    along, as the head's from inside ``dec0/conv3``; the widest first, and
+    the part is the widest's."""
+    parts, convolutions = profiling.op_parts(_KERNEL_HLO)
+    assert convolutions["folded_conv.2"] == [
+        ["dec0/conv3", "3x3x3"], ["out", "1x1x1"]]
+    assert parts["forward"]["dec0"] == ["folded_conv.2"]
+
+
+@pytest.mark.parametrize("window, name, scope, module", [
+    ((3, 3, 3), "", "kernel_convolution_3x3x3", "enc0"),
+    ((1, 5, 5), "", "kernel_convolution_1x5x5", "enc0"),
+    ((1, 1, 1), "out", "kernel_convolution_1x1x1..out", "out"),
+])
+def test_kernel_convolution_scope_is_what_the_parser_reads(
+        window, name, scope, module):
+    import jax
+    import jax.numpy as jnp
+
+    def program(x):
+        with jax.named_scope("forward"), jax.named_scope("Net"), \
+                jax.named_scope("enc0"), \
+                profiling.kernel_convolution(window, name):
+            return jnp.tanh(x)
+
+    text = jax.jit(program).lower(jnp.ones((8,))).as_text(debug_info=True)
+    (path,) = {p for p in profiling._LOWERED_PATH.findall(text)
+               if p.endswith("/tanh")}
+    assert f"/enc0/{scope}/tanh" in path
+    ((listed, size),) = profiling._kernel_convolutions(path)
+    assert size == "x".join(map(str, window))
+    assert listed.startswith("jit(program)/forward/Net/")
+    assert "/".join(profiling._split(listed)[1]) == module
+
+
 def test_op_parts_on_the_scope_test_module():
     """The module ``op_scopes`` is pinned on: no root module below the
     scope but `RSUNet`, so the only part is that of the path that goes
@@ -705,6 +789,41 @@ def test_programs_json_carries_op_scopes_only_with_a_sink(clean_plane,
                     if '"kind": "programs"' in line]
     assert streamed and not set(profiling.OP_MAPS) & set(
         streamed[0]["programs"][0])
+
+
+def test_a_fresh_executable_that_holds_the_kernel_is_not_stale(clean_plane,
+                                                               tmp_path):
+    """The names the kernel's block puts on its path (its module, the
+    marker scope) are on the executable it compiles to, so the ledger reads
+    the op maps from that executable and compiles nothing past the cache;
+    the gauge of the traced forward is a key of the entry."""
+    import jax
+    import jax.numpy as jnp
+
+    from chunkflow_tpu.models import rsunet
+
+    block = rsunet.RSBlock(28, dtype=jnp.bfloat16, fold=4, kernel=True,
+                           interpret=True, name="enc0")
+    x = jnp.ones((1, 2, 4, 16, 112), jnp.bfloat16)
+    params = block.init(jax.random.PRNGKey(0), x)
+
+    def build():
+        def program(params, x):
+            with jax.named_scope("forward"), jax.named_scope("RSUNet"):
+                profiling.trace_gauge("forward/kernel_convolutions", 3)
+                return block.apply(params, x)
+        return jax.jit(program)
+
+    telemetry.configure(str(tmp_path))
+    ProgramCache(label="kernel").get(("kernel",), build)(params, x)
+    (entry,) = profiling.catalog()
+    assert entry["op_parts"]["forward"]["enc0"]
+    assert entry["kernel_convolutions"] == 3
+    lowered = build().lower(params, x).as_text(debug_info=True)
+    assert "/enc0/conv2/kernel_convolution_3x3x3/" in lowered
+    counters = telemetry.snapshot()["counters"]
+    assert "program/stale_cache_entries" not in counters
+    assert counters["program/op_map_seconds"] > 0
 
 
 def test_trace_gauge_lands_on_the_program_being_built(clean_plane, tmp_path):

@@ -212,3 +212,135 @@ def test_cache_enable_failure_is_not_swallowed(fresh_cache_state,
     with pytest.raises(RuntimeError, match="no cache for you"):
         fresh_cache_state.enable_persistent_cache()
     assert fresh_cache_state.persistent_cache_dir() is None
+
+
+# ---------------------------------------------------------------------------
+# a kernel's lowering is kept beside the executables (ISSUE 47): a process
+# that starts traces and lowers no kernel an earlier one has lowered
+# ---------------------------------------------------------------------------
+_TRACES = []  # the static argument of each trace of _scaled
+
+
+def _scaled(x, y, *, factor: int):
+    """A stand-in for a kernel: what it is lowered from is its arrays'
+    shapes and its static arguments."""
+    _TRACES.append(factor)
+    return (x * factor + y,)
+
+
+@pytest.fixture()
+def lowered_dir(tmp_path, monkeypatch):
+    """jax's cache directory at an empty place, this process's lowered
+    functions forgotten, both restored afterwards."""
+    import jax
+
+    from chunkflow_tpu.core import compile_cache, telemetry
+
+    before = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    monkeypatch.setattr(compile_cache, "_LOWERED", {})
+    telemetry.reset()
+    del _TRACES[:]
+    yield tmp_path
+    telemetry.reset()
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def _call(factor=3, shape=(4,)):
+    import jax
+    import jax.numpy as jnp
+
+    from chunkflow_tpu.core import compile_cache
+
+    x, y = jnp.arange(shape[0], dtype=jnp.float32), jnp.ones(shape)
+    (out,) = compile_cache.lowered_once(
+        _scaled, dict(factor=factor), x, y, platform=jax.default_backend())
+    return np.asarray(out), np.asarray(x * factor + y)
+
+
+def test_a_lowering_is_written_once_and_read_by_the_next_process(
+        lowered_dir, monkeypatch):
+    from chunkflow_tpu.core import compile_cache, telemetry
+
+    got, want = _call()
+    assert np.array_equal(got, want) and _TRACES == [3]
+    (entry,) = (lowered_dir / "lowered").iterdir()
+    assert telemetry.snapshot()["counters"] == {
+        "compile_cache/lowered_builds": 1}
+    # the same process again: neither the file nor the function is read
+    got, _ = _call()
+    assert np.array_equal(got, want) and _TRACES == [3]
+    # a process that starts: the function is not traced at all
+    monkeypatch.setattr(compile_cache, "_LOWERED", {})
+    got, _ = _call()
+    assert np.array_equal(got, want) and _TRACES == [3]
+    assert telemetry.snapshot()["counters"][
+        "compile_cache/lowered_hits"] == 1
+    assert [p.name for p in (lowered_dir / "lowered").iterdir()] \
+        == [entry.name]
+
+
+@pytest.mark.parametrize("change", ["static", "shape", "source", "version"])
+def test_what_a_lowering_is_made_from_is_its_key(lowered_dir, monkeypatch,
+                                                 change):
+    """Another static argument, another shape, an edited file or another
+    jax: another entry, never a stale one."""
+    import jax
+
+    from chunkflow_tpu.core import compile_cache
+
+    _call()
+    monkeypatch.setattr(compile_cache, "_LOWERED", {})
+    kwargs = {}
+    if change == "static":
+        kwargs["factor"] = 5
+    elif change == "shape":
+        kwargs["shape"] = (8,)
+    elif change == "source":
+        monkeypatch.setattr(compile_cache, "_source_digest",
+                            lambda fn: "edited")
+    else:
+        monkeypatch.setattr(jax, "__version__", "0.0.0")
+    got, want = _call(**kwargs)
+    assert np.array_equal(got, want)
+    assert len(_TRACES) == 2
+    assert len(list((lowered_dir / "lowered").iterdir())) == 2
+
+
+def test_a_broken_entry_is_lowered_again(lowered_dir, monkeypatch):
+    from chunkflow_tpu.core import compile_cache
+
+    _call()
+    (entry,) = (lowered_dir / "lowered").iterdir()
+    entry.write_bytes(b"half a file")
+    monkeypatch.setattr(compile_cache, "_LOWERED", {})
+    got, want = _call()
+    assert np.array_equal(got, want) and len(_TRACES) == 2
+    assert entry.read_bytes() != b"half a file"
+
+
+@pytest.mark.parametrize("directory, platform", [
+    (None, "cpu"), ("placed", None)])
+def test_without_a_directory_or_a_platform_nothing_is_kept(
+        lowered_dir, directory, platform):
+    """No cache directory (a library user who enabled none), or an
+    interpreted kernel: the calls that agree are still one function of
+    the program, and no file is written."""
+    import jax
+    import jax.numpy as jnp
+
+    from chunkflow_tpu.core import compile_cache
+
+    if directory is None:
+        jax.config.update("jax_compilation_cache_dir", None)
+
+    def program(x):
+        for _ in range(3):
+            (x,) = compile_cache.lowered_once(
+                _scaled, dict(factor=2), x, x, platform=platform)
+        return x
+
+    x = jnp.ones((4,))
+    assert np.array_equal(np.asarray(jax.jit(program)(x)), np.full(4, 27.0))
+    assert _TRACES == [2]
+    assert not (lowered_dir / "lowered").exists()

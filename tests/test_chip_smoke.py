@@ -47,6 +47,21 @@ def test_rehearsal_passes_and_never_prints_the_ok_line():
         "rehearsal passed on platform: cpu")
 
 
+def test_the_convolution_kernel_phase_rehearses(capsys):
+    """The Mosaic smoke of the convolution kernel, at its tiny size with
+    the kernel interpreted: the same block, rule and bound as on the chip,
+    and the chip's shapes are ones the rule admits."""
+    import jax.numpy as jnp
+
+    from chunkflow_tpu.models import rsunet
+
+    chip_smoke.phase_convolution_kernel(dict(chip_smoke.TINY, rehearse=True))
+    assert "[interpret]: max-abs-diff" in capsys.readouterr().out
+    z, y, x = chip_smoke.FULL["conv_patch"]
+    assert chip_smoke.FULL["conv_batches"] == (4, 6)
+    assert rsunet.kernel_takes(4, 28, 28, jnp.bfloat16, (z, y, x // 4), "tpu")
+
+
 @pytest.mark.parametrize("patch", [(20, 256, 256), (8, 32, 32), (4, 16, 48)])
 def test_reference_bump_is_the_systems_bump(patch):
     """The script writes the weighting out again in float64; it must be

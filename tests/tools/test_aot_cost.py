@@ -104,10 +104,11 @@ def test_an_op_with_several_results_is_counted():
 CUT_PATCH = (20, 64, 256)   # the configuration's z and x tiles, a quarter of y
 
 
-@pytest.fixture(scope="module")
-def superhuman_text():
+def _forward_text(kernel: bool) -> str:
     """The optimized HLO of ``rsunet-superhuman``'s forward, one cut patch
-    a program, compiled for one chip of a described v5e."""
+    a program, compiled for one chip of a described v5e: the program a
+    chip runs (``kernel``), or the one it runs where the rule declines
+    every block, XLA's convolutions throughout."""
     import jax
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -122,19 +123,58 @@ def superhuman_text():
     cached = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
+    from chunkflow_tpu.models import rsunet
+    rule = rsunet.kernel_takes
+    if not kernel:
+        rsunet.kernel_takes = lambda *args: False
     try:
         return aot_cost.compile_forward(config, batch=1).as_text()
     finally:
+        rsunet.kernel_takes = rule
         jax.config.update("jax_enable_compilation_cache", cached)
         compilation_cache.reset_cache()
 
 
+@pytest.fixture(scope="module")
+def superhuman_text():
+    return _forward_text(kernel=False)
+
+
+@pytest.fixture(scope="module")
+def superhuman_kernel_text():
+    return _forward_text(kernel=True)
+
+
+def test_the_tool_sizes_the_program_a_chip_runs(superhuman_kernel_text):
+    """The tool's process runs on the CPU and lowers for a described
+    chip: the model is told so, and the folded levels' blocks, the
+    embedding and the head are the kernel's calls there as on the chip,
+    each listed as its module's convolution, none with a cycle count."""
+    parts, convolutions = aot_cost.part_of_ops(superhuman_kernel_text)
+    kernels = {op: held for op, held in convolutions.items()
+               if op.startswith("folded_conv")}
+    assert sorted(held[0][0] for held in kernels.values()) == sorted(
+        ["embed"] + [f"{block}/conv{i}" for block in
+                     ("enc0", "enc1", "dec1", "dec0") for i in (1, 2, 3)])
+    assert [["dec0/conv3", "3x3x3"], ["out", "1x1x1"]] in kernels.values()
+    assert {parts[op] for op in kernels} == {
+        "embed", "enc0", "enc1", "dec1", "dec0"}
+    assert not set(kernels) & {op[1] for op in aot_cost.entry_ops(
+        superhuman_kernel_text)}
+    # thirteen calls (the head rides in dec0/conv3's)
+    assert len(kernels) == 13
+
+
+@pytest.mark.parametrize("text", ["superhuman_text",
+                                  "superhuman_kernel_text"])
 def test_the_pools_window_reads_the_convolution_where_it_wrote(
-        superhuman_text):
+        text, request):
     """``pool0``'s z and y maximum is one fusion whose operand is
-    ``enc0/conv3``'s own fusion: no copy, transpose or reduce between an
-    encoder block's last convolution and its pool (seven full-size passes
-    before ISSUE 43, y transposed into the lanes and back)."""
+    ``enc0/conv3``'s own fusion, or the kernel's call: no copy, transpose
+    or reduce between an encoder block's last convolution and its pool
+    (seven full-size passes before ISSUE 43, y transposed into the lanes
+    and back)."""
+    superhuman_text = request.getfixturevalue(text)
     entry = superhuman_text[superhuman_text.index("\nENTRY "):]
     windows = [line for line in entry.splitlines()
                if "/pool0/reduce_window_max" in line and " fusion(" in line]
@@ -155,3 +195,74 @@ def test_the_encoders_way_out_costs_what_the_decoders_does(superhuman_text):
     table = aot_cost.by_module(superhuman_text)
     assert table["enc0"][1] < 1.5 * table["dec0"][1], table
     assert sum(table["pool0"]) < table["dec0"][1], table
+
+
+# ---------------------------------------------------------------------------
+# the convolution kernel (ISSUE 47) through the chip's compiler, Mosaic
+# included, for a described v5e: what the interpreter cannot refuse (a
+# rotate of 16-bit rows, a slice off the tiling, too much VMEM)
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("dtype, fold, channels, blocks, window", [
+    # level 0 of rsunet-superhuman: a quarter of y, the configuration's x
+    ("bfloat16", 4, 28, 64, (3, 3, 3)),
+    ("bfloat16", 4, 28, 64, (1, 3, 3)),
+    # level 0 of rsunet-deepem: float32 activations, 128 lanes
+    ("float32", 8, 16, 32, (3, 3, 3)),
+])
+def test_the_convolution_kernel_compiles_for_the_chip(
+        one_chip, dtype, fold, channels, blocks, window):
+    """One ``XFoldConv`` with its epilogue and residual through the
+    kernel, under the scopes the forward gives it: Mosaic takes it, and
+    the program lists the custom call as the module's convolution."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from chunkflow_tpu.core import profiling
+    from chunkflow_tpu.models import rsunet
+
+    conv = rsunet.XFoldConv(channels, window, dtype=jnp.dtype(dtype),
+                            fold=fold, name="conv2")
+    shape = (1, 3, 64, blocks, fold * channels)
+    x = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+    term = jax.ShapeDtypeStruct((fold * channels,), jnp.dtype(dtype),
+                                sharding=one_chip)
+    params = jax.eval_shape(
+        lambda: conv.init(jax.random.PRNGKey(0),
+                          jnp.zeros(shape, jnp.dtype(dtype))))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        params)
+
+    def forward(params, x, scale, shift):
+        with jax.named_scope("forward"), jax.named_scope("RSUNet"), \
+                jax.named_scope("enc0"):
+            return conv.apply(params, x, rsunet.Epilogue(scale, shift, x))
+
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(forward).lower(params, x, term, term) \
+            .compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    parts, convolutions = profiling.op_parts(text)
+    window_text = "x".join(map(str, window))
+    (name,) = [op for op in convolutions if op.startswith("folded_conv")]
+    assert convolutions[name] == [["enc0/conv2", window_text]]
+    assert name in parts["forward"]["enc0"]

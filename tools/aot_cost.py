@@ -120,7 +120,10 @@ def load_config(config: str) -> dict:
 def compile_forward(config: dict, batch: int):
     """The configuration's forward (``RSUNet.apply`` on one batch of
     patches, returning the configuration's output patch) compiled for one
-    chip of a described v5e."""
+    chip of a described v5e: the program a chip runs, the model told what
+    it is lowered for (``RSUNet.platform``; the process's own backend is
+    the CPU), so the blocks that take the convolution kernel there take
+    it here (``rsunet.kernel_takes``)."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
     import jax.numpy as jnp
@@ -138,7 +141,8 @@ def compile_forward(config: dict, batch: int):
         width=tuple(spec["width"]),
         down_factors=tuple(map(tuple, spec["pooling"])),
         dtype=jnp.dtype(spec["compute_dtype"]),
-        final_activation=spec["final_activation"])
+        final_activation=spec["final_activation"],
+        platform=topo.devices[0].platform)
     shape = (batch, *config["patch"], spec["in_channels"])
     params = jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0), jnp.zeros(shape)))
@@ -187,6 +191,14 @@ def main(argv=None) -> int:
     for opcode, cycles in sorted(by_opcode.items(), key=lambda kv: -kv[1]):
         print(f"  {opcode:<24} {cycles / 1e6:8.1f} M "
               f"{100.0 * cycles / total:5.1f}%")
+    part_of, convolutions = part_of_ops(text)
+    kernels = [op for op in convolutions if op not in {o[1] for o in ops}]
+    if kernels:
+        print(f"  {len(kernels)} kernels carry no estimated cycles and are "
+              f"in no total (time them on the chip):")
+        for op in kernels:
+            print(f"    {op} {part_of.get(op) or '-'} / " + " + ".join(
+                f"{path} {window}" for path, window in convolutions[op]))
     if args.by_module:
         print(f"{'module':<10} {'conv M':>8} {'rest M':>8} {'%':>5}")
         for module, (conv, rest) in sorted(
@@ -196,7 +208,6 @@ def main(argv=None) -> int:
         return 0
     # a fusion is named, shaped and annotated after its root: beside the
     # root's path, the part the op counts under and the convolutions inside
-    part_of, convolutions = part_of_ops(text)
     print(f"{'Mcycles':>8} {'%':>5}  op / shape{{layout}} / part / "
           f"convolutions inside, or the op's own path")
     for cycles, op, _, shape, op_name, _ in sorted(
